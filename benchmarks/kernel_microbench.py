@@ -6,8 +6,9 @@
 Four comparisons, one JSON record each (plus structural facts the
 acceptance checks assert on):
 
-  radix        radix-2 vs radix-4 Stockham (same op, half the passes);
-               records stage counts from ``stockham_stage_count``.
+  rowfft       XLA's library FFT vs the Pallas row kernel on the same
+               rows; records the kernel's four-step split
+               (``split_length``).
   fused        unfused (fft_rows_op + transpose_op, intermediate matrix)
                vs fused ``fft_rows_transpose_op`` (one dispatch).
   segments     looped per-segment ``segment_row_ffts`` vs the batched
@@ -103,7 +104,7 @@ from benchmarks.common import signal, time_fn
 from repro.core.fpm import FPMSet, SpeedFunction
 from repro.core.pfft import _pfft_limb, plan_segment_batches, segment_row_ffts
 from repro.core.partition import lb_partition
-from repro.kernels.fft.kernel import stockham_stage_count
+from repro.kernels.fft.kernel import split_length
 from repro.kernels.fft.ops import fft_rows_op
 from repro.kernels.fused.ops import fft_rows_transpose_op
 from repro.kernels.transpose.ops import transpose_op
@@ -116,6 +117,7 @@ from repro.plan import (CostParams, PlanConfig, SegmentSchedule,
                         partition_digest, record_wisdom, topology_digest,
                         tune_config, tune_dist_config, tune_schedule,
                         wisdom_key)
+from repro.plan.cost import V5E_KIND
 
 DEFAULT_OUT = os.path.join(os.path.dirname(__file__), "BENCH_kernels.json")
 
@@ -127,19 +129,21 @@ def _rows_signal(rows: int, n: int, seed: int = 1) -> jnp.ndarray:
                         ).astype(np.complex64))
 
 
-def bench_radix(sizes, rows: int) -> list[dict]:
+def bench_rowfft(sizes, rows: int) -> list[dict]:
     recs = []
     for n in sizes:
         x = _rows_signal(rows, n)
-        for radix in (2, 4):
-            t = time_fn(lambda x=x, r=radix: fft_rows_op(x, radix=r))
+        n1, n2 = split_length(n)
+        for name, fn in (("xla", lambda x: jnp.fft.fft(x, axis=-1)),
+                         ("pallas", fft_rows_op)):
             recs.append({
-                "bench": "radix",
+                "bench": "rowfft",
                 "n": int(n),
                 "rows": int(rows),
-                "radix": radix,
-                "stages": stockham_stage_count(n, radix),
-                "time_s": t,
+                "impl": name,
+                "n1": n1,
+                "n2": n2,
+                "time_s": time_fn(fn, x),
             })
     return recs
 
@@ -256,7 +260,7 @@ def bench_schedule(n: int, p: int, wisdom_path: str | None = None
     part = partition_rows(n, fpms, 0.05)
     d = part.d
     pads = fpm_pad_lengths(fpms, d, n)
-    params = CostParams.for_backend("tpu")
+    params = CostParams.for_backend("tpu", device_kind=V5E_KIND)
 
     sched, info = tune_schedule(n, d=d, pad_lengths=pads, fpms=fpms,
                                 mode="estimate", pad="fpm", params=params)
@@ -743,7 +747,7 @@ def bench_multihost(sizes, wisdom_path: str | None = None) -> list[dict]:
 # Which record ``bench`` tags each sweep (re)writes — the unit of the
 # overwrite guard and of partial-sweep merging below.
 _SWEEP_BENCHES = {
-    "radix": ("radix",), "fused": ("fused",), "segments": ("segments",),
+    "rowfft": ("rowfft",), "fused": ("fused",), "segments": ("segments",),
     "planner": ("planner",), "schedule": ("schedule",),
     "dist": ("dist",), "hetero-dist": ("hetero-dist",),
     "rfft": ("rfft", "rfft-dist"), "pfft3": ("pfft3",),
@@ -798,11 +802,12 @@ def _merge_existing_records(out: str, rerun_benches: set, backend: str,
 def run(quick: bool = False, out: str = DEFAULT_OUT,
         wisdom: str | None = None, sweeps: str | None = None,
         force: bool = False) -> dict:
-    radix_sizes = [64, 256] if quick else [64, 256, 1024]
+    rowfft_sizes = [64, 256] if quick else [64, 256, 1024]
     fused_sizes = [64, 128] if quick else [64, 128, 256]
     planner_sizes = [128] if quick else [128, 256]
     all_sweeps = {
-        "radix": lambda: bench_radix(radix_sizes, rows=32 if quick else 64),
+        "rowfft": lambda: bench_rowfft(rowfft_sizes,
+                                       rows=32 if quick else 64),
         "fused": lambda: bench_fused(fused_sizes),
         "segments": lambda: bench_segments(n=128 if quick else 256, p=4,
                                            pad_to=160 if quick else 320),
@@ -864,12 +869,23 @@ def main() -> int:
                          "measured config (plan_pfft-compatible keys)")
     ap.add_argument("--sweeps", default=None,
                     help="comma-separated subset of "
-                         "radix,fused,segments,planner,schedule,dist,"
+                         "rowfft,fused,segments,planner,schedule,dist,"
                          "hetero-dist,rfft,pfft3,multihost (default: all)")
     ap.add_argument("--force", action="store_true",
                     help="overwrite an output file holding accelerator-"
                          "tagged records with interpret-mode timings")
+    ap.add_argument("--require-accelerator", action="store_true",
+                    help="exit non-zero, before any bench, when JAX finds "
+                         "no accelerator")
     args = ap.parse_args()
+    import jax
+    from repro.launch.compile_cache import use_compile_cache
+    platform = jax.devices()[0].platform
+    if args.require_accelerator and platform == "cpu":
+        print(f"kernel_microbench: no accelerator (JAX platform "
+              f"{platform!r}); refusing to bench", file=sys.stderr)
+        return 1
+    use_compile_cache()
     run(quick=args.quick, out=args.out, wisdom=args.wisdom,
         sweeps=args.sweeps, force=args.force)
     return 0

@@ -32,6 +32,9 @@ def main() -> int:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from benchmarks import (partition_quality, pfft_speedup, roofline_report,
                             speed_functions)
 

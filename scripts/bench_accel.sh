@@ -1,17 +1,15 @@
 #!/usr/bin/env bash
-# One-command accelerator-backend benchmark run.
+# One-command accelerator benchmark run.
 #
 #   bash scripts/bench_accel.sh                     # all sweeps
 #   bash scripts/bench_accel.sh --sweeps dist,multihost --quick
 #
-# Runs the kernel microbench on whatever backend jax resolves (TPU/GPU
-# when present, CPU interpret mode otherwise) and warms the wisdom store
+# Runs the kernel microbench on the accelerator and warms the wisdom store
 # next to the output, so a single invocation on real hardware both
-# refreshes benchmarks/BENCH_kernels.json with accelerator-tagged
-# records and leaves a store later planning sessions are served from.
-# Every record is stamped with backend + interpret-mode, and the
-# microbench's overwrite guard refuses to let a later CPU run silently
-# replace accelerator-measured records (--force passes through).
+# refreshes benchmarks/BENCH_kernels.json with accelerator-tagged records
+# and leaves a store later planning sessions are served from.  JAX is
+# touched by one process only (the microbench), which refuses to run when
+# it finds no accelerator: this script never falls back to the CPU.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -20,13 +18,6 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 OUT="${BENCH_OUT:-benchmarks/BENCH_kernels.json}"
 WISDOM="${BENCH_WISDOM:-benchmarks/wisdom.json}"
 
-BACKEND=$(python -c "import jax; print(jax.default_backend())")
-echo "benching on backend: ${BACKEND} -> ${OUT} (wisdom: ${WISDOM})"
-if [ "${BACKEND}" = "cpu" ]; then
-    # No accelerator visible: force a multi-device CPU topology so the
-    # dist/multihost/pfft3 sweeps still measure real collectives.
-    export XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=4"
-fi
-
-exec python -m benchmarks.kernel_microbench \
+echo "benching -> ${OUT} (wisdom: ${WISDOM})"
+exec python -m benchmarks.kernel_microbench --require-accelerator \
     --out "${OUT}" --wisdom "${WISDOM}" "$@"

@@ -46,7 +46,8 @@ from repro.core.pfft import _pfft_limb
 from repro.plan.calibrate import fit_cost_params
 from repro.plan.config import PlanConfig, normalize_pad
 from repro.plan.schedule import SegmentSchedule
-from repro.plan.tune import dist_panel_space, tune_dist_schedule, tune_schedule
+from repro.plan.tune import (dist_panel_space, kernel_exclusions,
+                             tune_dist_schedule, tune_schedule)
 from repro.plan.wisdom import (lookup_wisdom, partition_digest, record_wisdom,
                                topology_digest, wisdom_key)
 
@@ -371,6 +372,9 @@ def _resolve_schedule(n: int, method: Method, part: PartitionResult,
                                        dtype=np.dtype(dtype))
     tuning.update(info)
     tuning["source"] = tune
+    excluded = kernel_exclusions(n)
+    if excluded:
+        tuning["excluded"] = excluded
     if wisdom is not None and tune == "measure":
         extra = None
         if mesh is not None:

@@ -242,12 +242,8 @@ def _pfft_limb(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
         # computation when the kernel doesn't apply (non-pow2 N,
         # dtypes wider than the f32 planes).
         from repro.fft.fft2d import fft_rows_then_transpose
-        # radix=2 means the pure-jnp Stockham backend elsewhere, not a
-        # kernel radix: only an explicit radix-4 reaches the fused kernel
-        # (None lets it auto-pick 4, the pre-refactor behavior).
-        fused_radix = common.radix if common.radix == 4 else None
-        m = fft_rows_then_transpose(m, radix=fused_radix)
-        m = fft_rows_then_transpose(m, radix=fused_radix)
+        m = fft_rows_then_transpose(m)
+        m = fft_rows_then_transpose(m)
         return m
     m = segment_row_ffts(m, d, schedule=schedule)
     m = m.T
@@ -397,9 +393,8 @@ def _rpfft_limb(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
             and all(e.length == schedule.n for e in schedule)):
         from repro.fft.fft2d import (fft_rows_then_transpose,
                                      rfft_rows_then_transpose)
-        fused_radix = common.radix if common.radix == 4 else None
-        h = rfft_rows_then_transpose(m, radix=fused_radix)    # (nh, n)
-        return fft_rows_then_transpose(h, radix=fused_radix)  # (n, nh)
+        h = rfft_rows_then_transpose(m)     # (nh, n)
+        return fft_rows_then_transpose(h)   # (n, nh)
     h = segment_row_rffts(m, d, schedule=schedule).T          # (nh, n)
     d2, sched2 = _clip_schedule(schedule, np.asarray(d), nh)
     return segment_row_ffts(h, d2, schedule=sched2).T         # (n, nh)

@@ -45,7 +45,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.fpm import FPMSet
 from repro.core.partition import lb_partition, partition_rows
@@ -289,9 +288,9 @@ def pfft3_pencil(
                                       hosts=hosts_r, local=local_r,
                                       split_axis=2, concat_axis=0)
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(ax_r, ax_c, None),),
-                       out_specs=P(ax_c, ax_r, None), check_rep=False)
+                       out_specs=P(ax_c, ax_r, None), check_vma=False)
     def _run(block):                       # (N/r, N/c, N)  [a0, a1, a2]
         # Round 1: FFT a2 -> k2, exchange over c (split k2, concat a1),
         # swap back to pencil layout.  Panels split a0 — untouched by the
@@ -348,9 +347,9 @@ def pfft3_slab(m: jnp.ndarray, mesh: Mesh, axis_name: str = "fft", *,
                                        hosts=hosts, local=local,
                                        split_axis=2, concat_axis=0)
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(axis_name, None, None),),
-                       out_specs=P(axis_name, None, None), check_rep=False)
+                       out_specs=P(axis_name, None, None), check_vma=False)
     def _run(block):                        # (n/p, n, n)
         for _ in range(3):
             block = fft3(block)

@@ -49,7 +49,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.padding import pad_to_smooth
 from repro.core.pfft import czt_dft
@@ -291,11 +290,7 @@ def _local_phase(block: jnp.ndarray, axis_name: str, n: int, *,
     fused = config.fused and padded is None and program is None
     a2a, a2a_t = _exchange_fns(axis_name, host_shape)
     if fused:
-        # radix=2 means the pure-jnp Stockham elsewhere, not a kernel
-        # radix: only an explicit radix-4 reaches the fused kernel.
-        fused_radix = config.radix if config.radix == 4 else None
-        fft_t = functools.partial(fft_rows_then_transpose,
-                                  backend=backend, radix=fused_radix)
+        fft_t = functools.partial(fft_rows_then_transpose, backend=backend)
     if program is not None:
         fft = _grouped_local_fft(axis_name, n, padded=padded,
                                  pad_len=pad_len, program=program,
@@ -599,8 +594,8 @@ def pfft2_distributed(
         host_shape=host_shape)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec_rows,), out_specs=spec_rows,
-        check_rep=False,
+        jax.shard_map, mesh=mesh, in_specs=(spec_rows,), out_specs=spec_rows,
+        check_vma=False,
     )
     def _run(block):
         # Phase 1: row FFTs + distributed transpose.
@@ -710,8 +705,8 @@ def rpfft2_distributed(
     spec_rows = P(axis_name, None)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec_rows,), out_specs=spec_rows,
-        check_rep=False,
+        jax.shard_map, mesh=mesh, in_specs=(spec_rows,), out_specs=spec_rows,
+        check_vma=False,
     )
     def _run(block):
         # Phase 1: local rffts, pad the half spectrum to the p-divisible
@@ -758,8 +753,8 @@ def irpfft2_distributed(
     spec_rows = P(axis_name, None)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec_rows,), out_specs=spec_rows,
-        check_rep=False,
+        jax.shard_map, mesh=mesh, in_specs=(spec_rows,), out_specs=spec_rows,
+        check_vma=False,
     )
     def _run(block):
         # Inverse column FFTs first (on the transposed, sharded spectral

@@ -21,30 +21,26 @@ __all__ = ["fft2d_rowcol", "fft_rows", "fft_rows_then_transpose",
 
 
 def fft_rows(m: jnp.ndarray, *, use_stockham: bool = False,
-             backend: str | None = None,
-             radix: int | None = None) -> jnp.ndarray:
+             backend: str | None = None) -> jnp.ndarray:
     """1-D FFT along the last axis.
 
     backend: None/'xla' -> jnp.fft; 'stockham' -> pure-jnp radix-2;
     'pallas' -> the Pallas TPU kernel (interpret-mode on CPU).  Power-of-two
-    lengths required for stockham/pallas; XLA otherwise.  ``radix`` feeds
-    the Pallas kernel's Stockham radix (None auto-selects; the planner's
-    ``PlanConfig.radix`` lands here).
+    lengths required for stockham/pallas; XLA otherwise.
     """
     n = m.shape[-1]
     if backend is None:
         backend = "stockham" if use_stockham else "xla"
     if backend == "pallas" and not (n & (n - 1)):
         from repro.kernels.fft.ops import fft_rows_op
-        return fft_rows_op(m, radix=radix)
+        return fft_rows_op(m)
     if backend == "stockham" and not (n & (n - 1)):
         return fft1d_stockham(m)
     return jnp.fft.fft(m, axis=-1)
 
 
 def fft_rows_then_transpose(m: jnp.ndarray, *,
-                            backend: str | None = None,
-                            radix: int | None = None) -> jnp.ndarray:
+                            backend: str | None = None) -> jnp.ndarray:
     """One fused phase: ``FFT_rows(m).T`` without the intermediate matrix.
 
     Dispatches to the fused Pallas kernel when it applies (2-D input,
@@ -58,7 +54,7 @@ def fft_rows_then_transpose(m: jnp.ndarray, *,
                 and jnp.result_type(m, jnp.complex64) == jnp.complex64)
     if eligible and backend in (None, "pallas", "fused"):
         from repro.kernels.fused.ops import fft_rows_transpose_op
-        return fft_rows_transpose_op(m, radix=radix)
+        return fft_rows_transpose_op(m)
     return fft_rows(m, backend=backend).swapaxes(-1, -2)
 
 
@@ -86,8 +82,7 @@ def _packed_rfft(m: jnp.ndarray, fft_fn) -> jnp.ndarray:
     return out[..., :rows, :nh]
 
 
-def rfft_rows(m: jnp.ndarray, *, backend: str | None = None,
-              radix: int | None = None) -> jnp.ndarray:
+def rfft_rows(m: jnp.ndarray, *, backend: str | None = None) -> jnp.ndarray:
     """1-D *real* FFT along the last axis -> (..., n//2+1) half spectrum.
 
     Same backend vocabulary as ``fft_rows``: 'pallas' runs the packed
@@ -98,15 +93,14 @@ def rfft_rows(m: jnp.ndarray, *, backend: str | None = None,
     n = m.shape[-1]
     if backend == "pallas" and m.ndim >= 2 and not (n & (n - 1)):
         from repro.kernels.fft.real import rfft_rows_op
-        return rfft_rows_op(m, radix=radix)
+        return rfft_rows_op(m)
     if backend == "stockham" and m.ndim >= 2 and not (n & (n - 1)):
         return _packed_rfft(m, fft1d_stockham)
     return jnp.fft.rfft(m, axis=-1)
 
 
 def rfft_rows_then_transpose(m: jnp.ndarray, *,
-                             backend: str | None = None,
-                             radix: int | None = None) -> jnp.ndarray:
+                             backend: str | None = None) -> jnp.ndarray:
     """One fused real phase: ``rfft_rows(m).T`` without the intermediate.
 
     Eligibility mirrors ``fft_rows_then_transpose`` (2-D input,
@@ -118,12 +112,11 @@ def rfft_rows_then_transpose(m: jnp.ndarray, *,
                 and jnp.result_type(m, jnp.complex64) == jnp.complex64)
     if eligible and backend in (None, "pallas", "fused"):
         from repro.kernels.fused.real import rfft_rows_transpose_op
-        return rfft_rows_transpose_op(m, radix=radix)
+        return rfft_rows_transpose_op(m)
     return rfft_rows(m, backend=backend).swapaxes(-1, -2)
 
 
-def rfft2(m: jnp.ndarray, *, backend: str | None = None,
-          radix: int | None = None) -> jnp.ndarray:
+def rfft2(m: jnp.ndarray, *, backend: str | None = None) -> jnp.ndarray:
     """Real-input 2-D DFT -> the (..., n_rows, n//2+1) half spectrum.
 
     Matches ``jnp.fft.rfft2``: real row FFTs (half the transforms via row
@@ -131,8 +124,8 @@ def rfft2(m: jnp.ndarray, *, backend: str | None = None,
     columns.  Phase 2 is a plain complex ``fft_rows`` on the transposed
     half spectrum — the conjugate-symmetric half never materialises.
     """
-    h = rfft_rows(m, backend=backend, radix=radix).swapaxes(-1, -2)
-    h = fft_rows(h, backend=backend, radix=radix)
+    h = rfft_rows(m, backend=backend).swapaxes(-1, -2)
+    h = fft_rows(h, backend=backend)
     return h.swapaxes(-1, -2)
 
 

@@ -1,8 +1,8 @@
 """jit'd public op for the fused row-FFT -> transpose kernel.
 
 Same contract shape as ``repro.kernels.fft.ops.fft_rows_op`` (complex in,
-complex out, row padding to the block multiple, radix auto-selection, CPU
-interpret fallback) except the result comes back transposed: input
+complex out, row padding to the block multiple, interpret mode on the CPU
+backend) except the result comes back transposed: input
 ``(rows, n)`` -> output ``(n, rows)`` holding ``FFT_rows(x).T``.
 """
 
@@ -20,25 +20,23 @@ __all__ = ["fft_rows_transpose_op"]
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("inverse", "block_rows", "radix",
-                                    "interpret"))
+                   static_argnames=("inverse", "block_rows", "interpret"))
 def fft_rows_transpose_op(
     x: jnp.ndarray,
     *,
     inverse: bool = False,
     block_rows: int | None = None,
-    radix: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Fused ``FFT_rows(x).T`` via one Pallas dispatch.  x: (rows, n) complex."""
     if x.ndim != 2:
         raise ValueError(f"fused op takes a 2-D matrix, got shape {x.shape}")
     rows, n = x.shape
-    block_rows, radix, interpret = resolve_call_params(n, block_rows, radix,
-                                                       interpret)
+    block_rows, interpret, limit = resolve_call_params(
+        "fused", n, rows, block_rows, interpret)
     re, im, _ = rows_to_padded_planes(x, block_rows)
     ore, oim = fft_rows_transpose_pallas(re, im, block_rows=block_rows,
-                                         inverse=inverse, radix=radix,
-                                         interpret=interpret)
+                                         inverse=inverse, interpret=interpret,
+                                         vmem_limit_bytes=limit)
     out = (ore[:, :rows] + 1j * oim[:, :rows])
     return out.astype(jnp.result_type(x, jnp.complex64))

@@ -1,8 +1,8 @@
 """Pallas TPU kernel: fused *real* row FFT -> transposed write.
 
 The real-pipeline sibling of ``kernels.fused.kernel``: each grid program
-packs two real rows per complex Stockham FFT (see ``kernels.fft.real``),
-unpacks the pair in registers, transposes both spectra in registers, and
+packs two real rows per complex FFT (see ``kernels.fft.real``), unpacks
+the pair in VMEM, transposes both spectra in VMEM, and
 writes them to their transposed tile positions.  The half-spectrum crop
 happens host-side after reassembly — output tiles are full transform
 length ``n`` high for lane alignment, exactly like the complex fused
@@ -17,21 +17,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.fft.kernel import apply_stockham
+from repro.kernels.fft.kernel import compiler_params, table_specs
 from repro.kernels.fft.ops import resolve_call_params
-from repro.kernels.fft.real import _pack_real_rows, unpack_packed_fft
+from repro.kernels.fft.real import (_pack_real_rows, packed_spectra,
+                                    real_tables)
 
 __all__ = ["rfft_rows_transpose_pallas", "rfft_rows_transpose_op"]
 
 
-def _rfused_kernel(a_ref, b_ref, aor_ref, aoi_ref, bor_ref, boi_ref, *,
-                   radix: int):
-    zr, zi = apply_stockham(a_ref[...], b_ref[...], radix=radix)
-    a_re, a_im, b_re, b_im = unpack_packed_fft(zr, zi)
-    aor_ref[...] = a_re.T
-    aoi_ref[...] = a_im.T
-    bor_ref[...] = b_re.T
-    boi_ref[...] = b_im.T
+def _rfused_kernel(a_ref, b_ref, *refs):
+    *table_refs, aor_ref, aoi_ref, bor_ref, boi_ref = refs
+    a_re, a_im, b_re, b_im = packed_spectra(a_ref[...], b_ref[...],
+                                            table_refs, transposed=True)
+    aor_ref[...] = a_re
+    aoi_ref[...] = a_im
+    bor_ref[...] = b_re
+    boi_ref[...] = b_im
 
 
 def rfft_rows_transpose_pallas(
@@ -39,8 +40,8 @@ def rfft_rows_transpose_pallas(
     b: jnp.ndarray,
     *,
     block_rows: int = 8,
-    radix: int = 2,
     interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Two (pairs, n) real row planes -> four transposed (n, pairs) planes
     ``(FFT(a).T.re, FFT(a).T.im, FFT(b).T.re, FFT(b).T.im)``.
@@ -51,28 +52,27 @@ def rfft_rows_transpose_pallas(
     if pairs % block_rows:
         raise ValueError(
             f"pairs={pairs} not a multiple of block_rows={block_rows}")
-    grid = (pairs // block_rows,)
+    tables = real_tables(n)
     in_spec = pl.BlockSpec((block_rows, n), lambda i: (i, 0))
     out_spec = pl.BlockSpec((n, block_rows), lambda i: (0, i))
     out_shape = [jax.ShapeDtypeStruct((n, pairs), a.dtype)] * 4
     fn = pl.pallas_call(
-        functools.partial(_rfused_kernel, radix=radix),
-        grid=grid,
-        in_specs=[in_spec, in_spec],
+        _rfused_kernel,
+        grid=(pairs // block_rows,),
+        in_specs=[in_spec, in_spec, *table_specs(tables)],
         out_specs=[out_spec] * 4,
         out_shape=out_shape,
+        compiler_params=compiler_params(vmem_limit_bytes),
         interpret=interpret,
     )
-    return fn(a, b)
+    return fn(a, b, *tables)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "radix", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def rfft_rows_transpose_op(
     x: jnp.ndarray,
     *,
     block_rows: int | None = None,
-    radix: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Fused ``rfft_rows(x).T`` via one Pallas dispatch.
@@ -85,12 +85,12 @@ def rfft_rows_transpose_op(
         raise ValueError(f"fused op takes a 2-D matrix, got shape {x.shape}")
     rows, n = x.shape
     nh = n // 2 + 1
-    block_rows, radix, interpret = resolve_call_params(n, block_rows, radix,
-                                                       interpret)
+    block_rows, interpret, limit = resolve_call_params(
+        "rfused", n, (rows + 1) // 2, block_rows, interpret)
     a, b, total = _pack_real_rows(x, block_rows)
     ar, ai, br, bi = rfft_rows_transpose_pallas(a, b, block_rows=block_rows,
-                                                radix=radix,
-                                                interpret=interpret)
+                                                interpret=interpret,
+                                                vmem_limit_bytes=limit)
     spec_a = ar + 1j * ai   # (n, padded_pairs): columns are even rows
     spec_b = br + 1j * bi   # (n, padded_pairs): columns are odd rows
     # Re-interleave pair columns, then crop bins (rows here) and columns.

@@ -30,11 +30,7 @@ import os
 import numpy as np
 
 import jax
-
-try:  # AxisType landed after jax 0.4.37; Auto is the pre-AxisType default.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "make_fft_mesh",
            "make_pfft3_mesh", "mesh_host_shape", "register_emulated_hosts",
@@ -48,8 +44,6 @@ _EMULATED_HOSTS: dict[tuple[str, tuple[int, ...]], int] = {}
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
@@ -57,8 +51,6 @@ def _mesh_from_devices(grid, axes):
     """Mesh over an *explicit* device array (host-major orderings must not
     be re-shuffled by ``jax.make_mesh``'s own placement heuristics)."""
     from jax.sharding import Mesh
-    if AxisType is None:
-        return Mesh(np.asarray(grid), axes)
     return Mesh(np.asarray(grid), axes,
                 axis_types=(AxisType.Auto,) * len(axes))
 

@@ -49,6 +49,7 @@ wisdom store's flock business, not ours.
 from __future__ import annotations
 
 import asyncio
+import logging
 import time
 from typing import Any, NamedTuple, Sequence
 
@@ -62,6 +63,7 @@ from repro.plan.cost import (CostParams, estimate_cost, estimate_pfft3_cost,
 __all__ = ["AdmissionError", "DeadlineExceeded", "CohortKey",
            "RequestTicket", "FFTService"]
 
+_log = logging.getLogger(__name__)
 _clock = time.perf_counter   # monotonic: latency math must not see NTP steps
 
 _REAL_PREFIX = "rfft-"
@@ -494,6 +496,10 @@ class FFTService:
             outs = plan.execute_many([r.m for r in reqs],
                                      pad_to=_bucket(len(reqs)))
         except Exception as e:   # a bad cohort fails its own requests only
+            # The service keeps serving other cohorts; the traceback is
+            # logged, each ticket re-raises it, and stats()["failed"]
+            # counts it, so callers can refuse a run with failures.
+            _log.exception("cohort %s failed (%d requests)", key, len(reqs))
             for r in reqs:
                 r._resolve(None, e, None)
             self._stats["failed"] += len(reqs)

@@ -7,7 +7,9 @@ hashable value the planner can enumerate, price, measure, and persist:
 
 * ``radix`` selects the row-FFT implementation: ``None`` is the library
   (XLA) FFT, ``2`` the pure-jnp radix-2 Stockham, ``4`` the Pallas
-  radix-4 kernel (half the passes; see DESIGN.md §Row-FFT kernel).
+  row-FFT kernel (DFT matrix products on the MXU; see
+  ``repro.kernels.fft.kernel``).  The value 4 is a name kept for the
+  wisdom format, not a kernel radix.
 * ``fused`` runs each (row FFT, transpose) phase as one fused Pallas
   dispatch — no intermediate HBM matrix.
 * ``batched`` groups same-length segments into one FFT dispatch per
@@ -94,13 +96,12 @@ class PlanConfig:
 
     def row_fft_kwargs(self, backend: str | None = None) -> dict[str, Any]:
         """``fft_rows`` kwargs for this config (the one place the
-        backend-override + radix-only-for-pallas gating lives; both the
-        single-host and distributed row phases route through it).
-        ``backend`` is an explicit override, e.g. tests forcing the kernel.
+        backend override lives; both the single-host and distributed row
+        phases route through it).  ``backend`` is an explicit override,
+        e.g. tests forcing the kernel.
         """
-        eff = backend if backend is not None else self.fft_backend
-        return {"backend": eff,
-                "radix": self.radix if eff == "pallas" else None}
+        return {"backend": backend if backend is not None
+                else self.fft_backend}
 
     # ---- legacy-flag bridge --------------------------------------------
 

@@ -8,8 +8,7 @@ candidate config from
 * the FPM-predicted per-processor segment times (``time_at``) — or a
   nominal flop rate when no FPM is supplied,
 * per-backend compute multipliers (XLA library FFT vs pure-jnp Stockham
-  vs the Pallas kernel, whose radix sets the pass count via
-  ``stockham_stage_count``),
+  vs the Pallas kernel),
 * the HBM round-trip of the intermediate matrix that ``fused`` removes,
 * kernel dispatch counts (``plan_segment_batches`` for the batched path),
 * and the all_to_all term that ``pipeline_panels`` overlaps.
@@ -34,7 +33,8 @@ from repro.core.fpm import FPMSet, fft_flops
 from repro.plan.config import PlanConfig
 from repro.plan.schedule import SegmentSchedule
 
-__all__ = ["CommTiers", "CostParams", "comm_phase_time", "dist_comm_bytes",
+__all__ = ["CommTiers", "CostParams", "UnknownDeviceKind", "V5E_KIND",
+           "comm_phase_time", "dist_comm_bytes",
            "dist_comm_time", "estimate_cost", "estimate_grouped_cost",
            "estimate_schedule_cost", "estimate_pfft3_cost", "exchange_time",
            "halfspec_cols", "phase_dispatch_count", "pfft3_comm_bytes"]
@@ -52,6 +52,34 @@ _REAL_COMPUTE_FACTOR = 0.55
 
 def _is_pow2(n: int) -> bool:
     return n > 0 and not (n & (n - 1))
+
+
+class UnknownDeviceKind(ValueError):
+    """The accelerator's ``device_kind`` has no cost constants."""
+
+
+V5E_KIND = "TPU v5 lite"  # jax's device_kind for a TPU v5e chip
+
+# Accelerator constants keyed by ``device_kind``.  TPU v5e: HBM at 819
+# GB/s and 1,600 Gbit/s of chip-to-chip interconnect are the published
+# peaks (Google Cloud documentation, "TPU v5e"); the compute rate and
+# every backend factor are unmeasured guesses, to be fitted from chip
+# samples with ``plan.calibrate``.  The inter-host tier models the data
+# center network at roughly a quarter of ICI with higher latency.
+_ACCELERATOR_PARAMS: dict[str, dict] = {
+    V5E_KIND: dict(
+        nominal_flops=2e11,
+        dispatch_overhead_s=3e-6,
+        hbm_bytes_per_s=8.19e11,
+        backend_factor={"xla": 1.0, "stockham": 1.6, "pallas": 0.8},
+        fused_factor=0.8,
+        panel_overlap=0.6,
+        interconnect_bytes_per_s=9e10,
+        comm_latency_s=1e-6,
+        inter_bytes_per_s=2.5e10,
+        inter_latency_s=1e-5,
+    ),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,9 +103,14 @@ class CostParams:
     inter_latency_s: float = 2e-5      # inter-host per-message latency
 
     @classmethod
-    def for_backend(cls, backend: str | None = None) -> "CostParams":
+    def for_backend(cls, backend: str | None = None,
+                    device_kind: str | None = None) -> "CostParams":
+        """Constants for ``backend`` (default: JAX's).  An accelerator is
+        looked up by ``device_kind`` (default: its first device's); a
+        kind with no entry raises ``UnknownDeviceKind`` rather than
+        borrowing another chip's rates."""
+        import jax
         if backend is None:
-            import jax
             backend = jax.default_backend()
         if backend == "cpu":
             # Interpret-mode Pallas re-traces every lane op in Python; the
@@ -99,23 +132,15 @@ class CostParams:
                 inter_bytes_per_s=2e9,
                 inter_latency_s=2e-4,
             )
-        # Accelerator defaults (v5e-class): the radix-4 kernel beats the
-        # library FFT (half the passes, twiddles from iota), fused wins by
-        # skipping the HBM round trip; ICI all_to_all runs near link rate
-        # and DCN (the inter-host tier) at roughly a quarter of it with
-        # much higher per-message latency.
-        return cls(
-            nominal_flops=2e11,
-            dispatch_overhead_s=3e-6,
-            hbm_bytes_per_s=8e11,
-            backend_factor={"xla": 1.0, "stockham": 1.6, "pallas": 0.8},
-            fused_factor=0.8,
-            panel_overlap=0.6,
-            interconnect_bytes_per_s=9e10,
-            comm_latency_s=1e-6,
-            inter_bytes_per_s=2.5e10,
-            inter_latency_s=1e-5,
-        )
+        if device_kind is None:
+            device_kind = jax.devices(backend)[0].device_kind
+        try:
+            return cls(**_ACCELERATOR_PARAMS[device_kind])
+        except KeyError:
+            raise UnknownDeviceKind(
+                f"no cost constants for {backend} device kind "
+                f"{device_kind!r}; known: {sorted(_ACCELERATOR_PARAMS)}"
+            ) from None
 
 
 def halfspec_cols(n: int, p: int = 1) -> int:
@@ -348,13 +373,6 @@ def _factor_term(config: PlanConfig, length: int) -> tuple[str, float]:
         # Kernel backends need pow2 lengths (fft_rows falls back to XLA
         # otherwise, and the model mirrors that).
         return "xla", 1.0
-    if backend == "pallas":
-        # Radix sets the Stockham pass count: radix 4 makes ceil(log2 n / 2)
-        # trips over the data instead of log2 n.
-        from repro.kernels.fft.kernel import stockham_stage_count
-        log2n = max(int(np.log2(length)), 1)
-        return "pallas", stockham_stage_count(length, config.radix or 4) \
-            / log2n * 2.0
     return backend, 1.0
 
 

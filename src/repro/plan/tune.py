@@ -33,7 +33,8 @@ from repro.plan.cost import (CostParams, _compute_multiplier, _segment_work,
                              exchange_time, pfft3_comm_bytes)
 from repro.plan.schedule import SegmentSchedule
 
-__all__ = ["candidate_configs", "segment_candidate_configs",
+__all__ = ["candidate_configs", "kernel_exclusions",
+           "segment_candidate_configs",
            "measure_configs", "measure_dist_configs", "tune_config",
            "tune_schedule", "tune_dist_config", "tune_dist_schedule",
            "grouped_dist_schedule", "dist_panel_space",
@@ -70,6 +71,34 @@ def _measure_with_retry(thunk, retries: int, base_s: float = 0.05):
             delay *= 2.0
 
 
+def kernel_exclusions(n: int) -> dict[str, str]:
+    """Kernel families the chip cannot run at length ``n``, with why.
+
+    Keys are ``"fused"`` (the fused complex phase) and ``"fused-real"``
+    (the fused real phase, which also runs a fused complex phase).  Off
+    the TPU the kernels run in interpret mode at every length, so the
+    map is empty there.
+    """
+    import jax
+    if jax.default_backend() != "tpu" or not _is_pow2(n):
+        return {}
+    from repro.kernels.fft.ops import tpu_unsupported
+    out = {}
+    for family, kinds in (("fused", ("fused",)),
+                          ("fused-real", ("rfused", "fused"))):
+        reasons = [r for r in (tpu_unsupported(k, n) for k in kinds) if r]
+        if reasons:
+            out[family] = "; ".join(reasons)
+    return out
+
+
+def _drop_excluded(cands: Sequence[PlanConfig], n: int) -> list[PlanConfig]:
+    """``cands`` minus the fused configs ``kernel_exclusions`` names."""
+    excl = kernel_exclusions(n)
+    return [c for c in cands
+            if not (c.fused and ("fused-real" if c.real else "fused") in excl)]
+
+
 def candidate_configs(n: int, *, pad: str = "none", d=None,
                       panels: Sequence[int] = (1,)) -> list[PlanConfig]:
     """Valid ``PlanConfig`` candidates for an n x n problem.
@@ -97,7 +126,7 @@ def candidate_configs(n: int, *, pad: str = "none", d=None,
             # Fused collapses each phase to one dispatch; segmentation (and
             # therefore batched) is moot, and the kernel is radix-4.
             out.append(PlanConfig(radix=4, fused=True, pipeline_panels=k))
-    return out
+    return _drop_excluded(out, n)
 
 
 def segment_candidate_configs(length: int, *, pad: str = "none"
@@ -127,7 +156,7 @@ def _length_backend(cfg: PlanConfig, length: int) -> tuple[str, int | None]:
     kw = cfg.row_fft_kwargs()
     if kw["backend"] != "xla" and not _is_pow2(length):
         return "xla", None
-    return kw["backend"], kw["radix"]
+    return kw["backend"], cfg.radix if kw["backend"] == "pallas" else None
 
 
 def _timed_min(pairs, x, rounds: int) -> dict:
@@ -496,7 +525,6 @@ def _measure_tier_exchange(mesh, axis_name: str, n: int, hosts: int,
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.pfft_dist import _hier_groups  # lazy: core imports plan
 
@@ -508,8 +536,9 @@ def _measure_tier_exchange(mesh, axis_name: str, n: int, hosts: int,
     x = jax.device_put(x, NamedSharding(mesh, P(axis_name, None)))
 
     @jax.jit
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P(axis_name, None),),
-                       out_specs=P(axis_name, None), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh,
+                       in_specs=(P(axis_name, None),),
+                       out_specs=P(axis_name, None), check_vma=False)
     def ex(block):
         return jax.lax.all_to_all(block, axis_name, split_axis=1,
                                   concat_axis=0, tiled=True,
@@ -1087,12 +1116,13 @@ def _require_real_dtype(dtype) -> np.dtype:
     return dt
 
 
-def _real_candidates(cands: Sequence[PlanConfig]) -> list[PlanConfig]:
+def _real_candidates(cands: Sequence[PlanConfig], n: int
+                     ) -> list[PlanConfig]:
     """The real-flagged twins of a complex candidate list (czt dropped —
     the real pipeline has no Bluestein form)."""
     import dataclasses
-    return [dataclasses.replace(c, real=True) for c in cands
-            if c.pad != "czt"]
+    return _drop_excluded([dataclasses.replace(c, real=True) for c in cands
+                           if c.pad != "czt"], n)
 
 
 def _family_finalists(ranked, n: int, d, pad_lengths, top_k: int
@@ -1178,7 +1208,7 @@ def tune_rfft(n: int, *, d=None, pad_lengths=None, fpms: FPMSet | None = None,
         params = CostParams.for_backend()
 
     complex_cands = candidate_configs(n, pad=pad, d=d)
-    cands = _real_candidates(complex_cands) + complex_cands
+    cands = _real_candidates(complex_cands, n) + complex_cands
     ranked = sorted(
         ((cfg, estimate_cost(cfg, n=n, d=d, pad_lengths=pad_lengths,
                              fpms=fpms, params=params))
@@ -1311,7 +1341,7 @@ def tune_rfft_dist(n: int, mesh, axis_name: str = "fft", *,
 
     complex_cands = [c for c in candidate_configs(n, pad=pad, d=None,
                                                   panels=panels) if c.batched]
-    real_cands = [c for c in _real_candidates(complex_cands)
+    real_cands = [c for c in _real_candidates(complex_cands, n)
                   if not c.fused and c.pipeline_panels == 1]
     ranked = sorted(
         ((cfg, estimate_cost(cfg, n=n, fpms=fpms, params=params,

@@ -19,6 +19,7 @@ from repro.plan import (CostParams, PlanConfig, SegmentSchedule,
                         device_group_program, estimate_grouped_cost,
                         estimate_schedule_cost, grouped_dist_schedule,
                         spmd_program_config)
+from repro.plan.cost import V5E_KIND
 
 
 # ------------------------------------------------------------ the mapping
@@ -111,7 +112,7 @@ def test_grouped_dist_schedule_mixed_lengths_yield_mixed_configs():
     """Accelerator constants + mixed pow2/non-pow2 per-device pads: the
     pow2-padded devices take a kernel variant while the rest keep the
     library FFT — the candidate is genuinely heterogeneous."""
-    params = CostParams.for_backend("tpu")
+    params = CostParams.for_backend("tpu", device_kind=V5E_KIND)
     pads = np.array([48, 64, 48, 64])
     sched = grouped_dist_schedule(48, 4, pad_lengths=pads, pad="fpm",
                                   params=params)

@@ -3,15 +3,17 @@ equivalence against the unfused/radix-2/looped references."""
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core.pfft import (plan_segment_batches, pfft_lb,
                              segment_row_ffts)
 from repro.fft.fft2d import fft2d_rowcol, fft_rows_then_transpose
 from repro.plan import PlanConfig
-from repro.kernels.fft.kernel import (stockham_planes, stockham_planes_radix4,
-                                      stockham_stage_count)
-from repro.kernels.fft.ops import fft_rows_op, pick_radix
+from repro.kernels.fft.kernel import (dft_digits, dft_planes, dft_tables,
+                                      split_length, to_natural, to_transposed)
+from repro.kernels.fft.ops import (KernelUnsupported, fft_rows_op,
+                                   tpu_unsupported)
 from repro.kernels.fused.kernel import fft_rows_transpose_pallas
 from repro.kernels.fused.ops import fft_rows_transpose_op
 
@@ -21,66 +23,91 @@ def csignal(rng, rows, n, dtype=np.complex64):
                         + 1j * rng.standard_normal((rows, n))).astype(dtype))
 
 
-# ------------------------------------------------------------- radix-4 stages
+# ------------------------------------------------------ four-step digit layouts
 
-@pytest.mark.parametrize("n", [2, 4, 8, 16, 64, 128, 1024])
-def test_radix4_matches_radix2(rng, n):
-    re = jnp.asarray(rng.standard_normal((3, n)).astype(np.float32))
-    im = jnp.asarray(rng.standard_normal((3, n)).astype(np.float32))
-    r2 = stockham_planes(re, im)
-    r4 = stockham_planes_radix4(re, im)
-    tol = 1e-3 * n ** 0.5
-    np.testing.assert_allclose(np.asarray(r4[0]), np.asarray(r2[0]), atol=tol)
-    np.testing.assert_allclose(np.asarray(r4[1]), np.asarray(r2[1]), atol=tol)
+@pytest.mark.parametrize("n", [2, 16, 128, 512, 1024, 2048, 4096])
+def test_k1_major_digits_match_row_major(rng, n):
+    """The fused kernels' k1-major digit layout, transposed out, equals the
+    plain kernels' row-major layout in natural order, transposed."""
+    rows = 8
+    re = jnp.asarray(rng.standard_normal((rows, n)).astype(np.float32))
+    im = jnp.asarray(rng.standard_normal((rows, n)).astype(np.float32))
+    tables = dft_tables(n)
+    row_major = dft_digits(re, im, tables)
+    k1_major = dft_digits(re, im, tables, k1_major=True)
+    for a, b in zip(row_major, k1_major):
+        np.testing.assert_allclose(np.asarray(to_transposed(b, rows)),
+                                   np.asarray(to_natural(a, rows)).T,
+                                   atol=1e-3 * n ** 0.5)
 
 
 @pytest.mark.parametrize("inverse", [False, True])
-def test_radix4_inverse_roundtrip(rng, inverse):
-    n = 32
+def test_dft_planes_inverse_roundtrip(rng, inverse):
+    n = 1024
     re = jnp.asarray(rng.standard_normal((2, n)).astype(np.float32))
     im = jnp.asarray(rng.standard_normal((2, n)).astype(np.float32))
-    fr, fi = stockham_planes_radix4(re, im, inverse=inverse)
-    br, bi = stockham_planes_radix4(fr, fi, inverse=not inverse)
+    fr, fi = dft_planes(re, im, dft_tables(n, inverse))
+    br, bi = dft_planes(fr, fi, dft_tables(n, not inverse))
     np.testing.assert_allclose(np.asarray(br), np.asarray(re), atol=1e-4)
     np.testing.assert_allclose(np.asarray(bi), np.asarray(im), atol=1e-4)
 
 
-def test_stage_counts():
-    for log2n in range(1, 12):
+def test_split_length():
+    for log2n in range(0, 15):
         n = 1 << log2n
-        assert stockham_stage_count(n, 2) == log2n
-        assert stockham_stage_count(n, 4) == (log2n + 1) // 2
+        n1, n2 = split_length(n)
+        assert n1 * n2 == n
+        assert (n1, n2) == ((1, n) if n <= 512 else (n // 128, 128))
     with pytest.raises(ValueError):
-        stockham_stage_count(12)
-    with pytest.raises(ValueError):
-        stockham_stage_count(16, radix=8)
+        split_length(12)
+    t = dft_tables(1024)
+    assert [a.shape for a in t] == [(8, 8)] * 2 + [(8, 128)] * 2 + \
+        [(128, 128)] * 2
+    assert all(a.dtype == np.float32 and not a.flags.writeable for a in t)
 
 
-def test_pick_radix():
-    assert pick_radix(2) == 2
-    assert pick_radix(4) == 4
-    assert pick_radix(1024) == 4
+def test_tpu_unsupported_lengths():
+    """Plain kernels fit v5e VMEM at every length the planner offers;
+    the fused kernels' 128-row lane block stops fitting at long rows."""
+    for log2n in range(7, 15):
+        n = 1 << log2n
+        assert tpu_unsupported("fft", n) is None
+        assert tpu_unsupported("rfft", n) is None
+    assert tpu_unsupported("fused", 8192) is None
+    assert "VMEM" in tpu_unsupported("fused", 16384)
+    assert tpu_unsupported("rfused", 4096) is None
+    assert "VMEM" in tpu_unsupported("rfused", 8192)
 
 
-@pytest.mark.parametrize("n", [16, 128])
-def test_fft_op_radix4_vs_oracle(rng, n):
+def test_fused_op_refuses_unsupported_length_on_chip():
+    """Compiled (not interpreted), an unsupported length raises the named
+    error instead of falling back to another FFT."""
+    x = jnp.ones((8, 16384), jnp.complex64)
+    with pytest.raises(KernelUnsupported, match="fused kernel at n=16384"):
+        jax.eval_shape(lambda a: fft_rows_transpose_op(a, interpret=False), x)
+
+
+@pytest.mark.parametrize("n", [16, 1024])
+def test_fft_op_four_step_vs_oracle(rng, n):
     x = csignal(rng, 5, n)
-    out = fft_rows_op(x, radix=4, block_rows=2, interpret=True)
+    out = fft_rows_op(x, block_rows=2, interpret=True)
     np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(jnp.fft.fft(x, axis=-1)), atol=2e-3)
+                               np.asarray(jnp.fft.fft(x, axis=-1)),
+                               atol=2e-3 * (n / 16) ** 0.5)
 
 
 # ------------------------------------------------------------- fused kernel
 
-@pytest.mark.parametrize("radix", [2, 4])
+@pytest.mark.parametrize("inverse", [False, True])
 @pytest.mark.parametrize("block_rows", [1, 4])
-def test_fused_kernel_pallas_call(rng, radix, block_rows):
+def test_fused_kernel_pallas_call(rng, inverse, block_rows):
     rows, n = 8, 64
     re = jnp.asarray(rng.standard_normal((rows, n)).astype(np.float32))
     im = jnp.asarray(rng.standard_normal((rows, n)).astype(np.float32))
     ore, oim = fft_rows_transpose_pallas(re, im, block_rows=block_rows,
-                                         radix=radix, interpret=True)
-    ref = np.fft.fft(np.asarray(re) + 1j * np.asarray(im), axis=-1).T
+                                         inverse=inverse, interpret=True)
+    x = np.asarray(re) + 1j * np.asarray(im)
+    ref = (np.fft.ifft if inverse else np.fft.fft)(x, axis=-1).T
     np.testing.assert_allclose(np.asarray(ore), ref.real, atol=2e-3)
     np.testing.assert_allclose(np.asarray(oim), ref.imag, atol=2e-3)
 

@@ -7,8 +7,9 @@ import jax
 import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st  # hypothesis or fallback
 
-from repro.kernels.fft.kernel import fft_rows_pallas, stockham_planes
-from repro.kernels.fft.ops import fft_rows_op, pick_block_rows
+from repro.kernels.fft.kernel import dft_planes, dft_tables, fft_rows_pallas
+from repro.kernels.fft.ops import (KERNEL_KINDS, fft_rows_op, pick_block_rows,
+                                   vmem_bytes)
 from repro.kernels.fft.ref import fft_rows_ref
 from repro.kernels.transpose.kernel import transpose_pallas
 from repro.kernels.transpose.ops import transpose_op
@@ -25,9 +26,9 @@ def cplanes(rng, rows, n, dtype=np.float32):
 
 @pytest.mark.parametrize("n", [8, 32, 128, 512, 2048])
 @pytest.mark.parametrize("rows", [1, 4, 8])
-def test_stockham_planes_shape_sweep(rng, n, rows):
+def test_dft_planes_shape_sweep(rng, n, rows):
     re, im = cplanes(rng, rows, n)
-    ore, oim = stockham_planes(re, im)
+    ore, oim = dft_planes(re, im, dft_tables(n))
     rre, rim = fft_rows_ref(re, im)
     tol = 1e-3 * n ** 0.5
     np.testing.assert_allclose(np.asarray(ore), np.asarray(rre), atol=tol)
@@ -79,10 +80,19 @@ def test_fft_op_rejects_non_pow2():
         fft_rows_op(jnp.ones((4, 12), jnp.complex64), interpret=True)
 
 
-def test_pick_block_rows_vmem_budget():
-    assert pick_block_rows(128) >= 8
-    assert pick_block_rows(1 << 16) >= 1
-    assert pick_block_rows(1 << 16) * (1 << 16) * 4 * 6 <= 16 * 1024 * 1024
+@pytest.mark.parametrize("kind", KERNEL_KINDS)
+def test_pick_block_rows_vmem_budget(kind):
+    """Blocks tile legally on v5e — whole (8, 128) f32 tiles of rows, and
+    128 rows where they are the fused output's lane axis — and a plain
+    kernel's block fits Mosaic's 16 MiB default scoped VMEM."""
+    for log2n in range(7, 15):
+        n = 1 << log2n
+        b = pick_block_rows(n, kind)
+        assert b % 8 == 0
+        if kind in ("fused", "rfused"):
+            assert b == 128
+        else:
+            assert vmem_bytes(kind, n, b) <= 16 * 1024 * 1024
 
 
 @given(n=st.sampled_from([8, 16, 64, 256]), rows=st.integers(1, 6),

@@ -16,6 +16,7 @@ from repro.plan import (WISDOM_VERSION, CostParams, candidate_configs,
                         czt_fft_lengths, estimate_cost, fpm_pad_lengths,
                         load_wisdom, lookup_wisdom, record_wisdom,
                         tune_config, wisdom_key)
+from repro.plan.cost import V5E_KIND
 from repro.core.padding import determine_pad_length, smooth_candidates
 
 
@@ -102,13 +103,60 @@ def test_cost_batched_beats_looped_on_dispatch_overhead():
 def test_cost_cpu_prefers_library_accel_prefers_kernels():
     n, d = 256, np.array([64] * 4)
     cpu = CostParams.for_backend("cpu")
-    tpu = CostParams.for_backend("tpu")
+    tpu = CostParams.for_backend("tpu", device_kind=V5E_KIND)
     lib = PlanConfig()
     fused = PlanConfig(radix=4, fused=True)
     assert estimate_cost(lib, n=n, d=d, params=cpu) < \
         estimate_cost(fused, n=n, d=d, params=cpu)  # interpret-mode penalty
     assert estimate_cost(fused, n=n, d=d, params=tpu) < \
         estimate_cost(lib, n=n, d=d, params=tpu)  # no HBM round trip
+
+
+def test_cost_params_keyed_by_device_kind():
+    from repro.plan.cost import UnknownDeviceKind
+    v5e = CostParams.for_backend("tpu", device_kind=V5E_KIND)
+    assert v5e.hbm_bytes_per_s == pytest.approx(819e9)
+    with pytest.raises(UnknownDeviceKind, match="TPU v9"):
+        CostParams.for_backend("tpu", device_kind="TPU v9")
+
+
+@pytest.mark.parametrize("n,fused_ok,real_fused_ok",
+                         [(4096, True, True), (8192, True, False),
+                          (16384, False, False)])
+def test_tpu_candidates_drop_kernels_that_cannot_compile(
+        monkeypatch, n, fused_ok, real_fused_ok):
+    """On the TPU backend the fused configs whose kernels outgrow VMEM
+    are not offered, and ``kernel_exclusions`` names why."""
+    from repro.plan.tune import _real_candidates, kernel_exclusions
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cands = candidate_configs(n)
+    assert any(c.fused for c in cands) == fused_ok
+    assert any(c.fused for c in _real_candidates(cands, n)) == real_fused_ok
+    excl = kernel_exclusions(n)
+    assert ("fused" in excl) != fused_ok
+    assert ("fused-real" in excl) != real_fused_ok
+    assert all("VMEM" in why for why in excl.values())
+    monkeypatch.undo()
+    assert kernel_exclusions(n) == {}   # interpret mode runs every length
+    assert any(c.fused for c in candidate_configs(n))
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` wins untouched; otherwise the cache
+    is the fixed ``<repo>/.jax_cache``."""
+    from repro.launch import compile_cache as cc
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cc.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = cc.use_compile_cache()
+        assert path == str(cc.REPO_CACHE_DIR)
+        assert path.endswith(".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_cost_uses_fpm_times():

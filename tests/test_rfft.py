@@ -55,6 +55,40 @@ def test_packed_rfft_kernel_matches_oracle(rows, n):
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-3)
 
 
+@pytest.mark.parametrize("k1_major", [False, True])
+@pytest.mark.parametrize("n", [8, 512, 1024, 4096])
+def test_reverse_digits_is_exact_bin_reversal(n, k1_major):
+    """``reverse_digits`` puts bin (n - k) mod n where bin k was, exactly
+    (0/1 permutation products), in both digit layouts."""
+    from repro.kernels.fft.kernel import (dft_digits, dft_tables, to_natural,
+                                          to_transposed)
+    from repro.kernels.fft.real import reverse_digits, reverse_tables
+    rows = 8
+    rng = np.random.default_rng(n)
+    re = jnp.asarray(rng.standard_normal((rows, n)).astype(np.float32))
+    im = jnp.asarray(rng.standard_normal((rows, n)).astype(np.float32))
+    z, _ = dft_digits(re, im, dft_tables(n), k1_major=k1_major)
+    rz = reverse_digits(z, reverse_tables(n), k1_major=k1_major)
+    if k1_major:
+        z, rz = to_transposed(z, rows).T, to_transposed(rz, rows).T
+    else:
+        z, rz = to_natural(z, rows), to_natural(rz, rows)
+    k = np.arange(n)
+    np.testing.assert_array_equal(np.asarray(rz), np.asarray(z)[:, (-k) % n])
+
+
+@pytest.mark.parametrize("n", [1024, 4096])
+def test_packed_rfft_four_step_matches_oracle(n):
+    from repro.kernels.fused.real import rfft_rows_transpose_op
+    x = real_signal(n, seed=4, rows=10)
+    ref = np.fft.rfft(np.asarray(x, np.float64), axis=-1)
+    tol = 1e-5 * np.sqrt(np.mean(np.abs(ref) ** 2))
+    np.testing.assert_allclose(np.asarray(rfft_rows(x, backend="pallas")),
+                               ref, atol=tol)
+    np.testing.assert_allclose(np.asarray(rfft_rows_transpose_op(x)), ref.T,
+                               atol=tol)
+
+
 def test_packed_rfft_kernel_leading_dims():
     x = jnp.asarray(np.random.default_rng(2)
                     .standard_normal((2, 3, 6, 32)).astype(np.float32))

@@ -24,6 +24,7 @@ from repro.plan import (CostParams, SegmentPlan, SegmentSchedule,
                         estimate_schedule_cost, fit_cost_params, load_wisdom,
                         lookup_wisdom, record_wisdom, tune_schedule,
                         wisdom_key)
+from repro.plan.cost import V5E_KIND
 from repro.plan.wisdom import WISDOM_VERSION
 
 
@@ -337,7 +338,7 @@ def test_hetero_fpms_produce_multi_config_schedule():
     d = np.array([16, 16, 16])
     pads = np.array([48, 64, 64], dtype=np.int64)  # fast procs pad to pow2
     fpms = hetero_fpms(n)
-    params = CostParams.for_backend("tpu")
+    params = CostParams.for_backend("tpu", device_kind=V5E_KIND)
     sched, info = tune_schedule(n, d=d, pad_lengths=pads, fpms=fpms,
                                 mode="estimate", pad="fpm", params=params)
     assert len(sched.configs) >= 2

@@ -98,7 +98,6 @@ def test_error_feedback_residual_is_exact(rng):
 
 def test_compressed_psum_multidevice_equivalence():
     """int8 psum over a fake 'pods' axis approximates the exact psum."""
-    import jax.experimental.shard_map as shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     devs = jax.devices()
     if len(devs) < 1:
@@ -106,7 +105,7 @@ def test_compressed_psum_multidevice_equivalence():
     mesh = Mesh(np.array(devs[:1]), ("pods",))
     g = jnp.linspace(-1, 1, 128)
 
-    f = shard_map.shard_map(
+    f = jax.shard_map(
         lambda x: compressed_psum(x, "pods"), mesh=mesh,
         in_specs=P(), out_specs=P())
     out = f(g)
