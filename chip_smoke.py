@@ -7,7 +7,7 @@
 Drives the library through the entry points a user calls (``build_fpm``,
 ``plan_pfft``, ``plan_pfft3``, ``FFTService``) at the sizes users run,
 checks every result against float64 numpy, and prints one line per phase
-(max error against its tolerance, run time, compile time).  The last line
+(max error against its tolerance).  The last line
 of standard output is one JSON object naming the device.  The script runs
 in one process, exits non-zero on the first failure and refuses to run
 anywhere but a TPU.  JAX's compile cache is placed by
@@ -58,16 +58,12 @@ def _report(phase: str, err: float, tol: float, **fields) -> None:
 
 def run_compiled(fn, x):
     """AOT-compile ``fn`` for ``x`` and run it once: returns (out,
-    compile_s, run_s, holds_kernel) — ``holds_kernel`` is whether the
-    compiled program contains a Pallas kernel (``tpu_custom_call``)."""
+    holds_kernel) — ``holds_kernel`` is whether the compiled program
+    contains a Pallas kernel (``tpu_custom_call``)."""
     import jax
-    t0 = time.perf_counter()
     compiled = jax.jit(fn).lower(x).compile()
-    compile_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
     out = jax.block_until_ready(compiled(x))
-    run_s = time.perf_counter() - t0
-    return out, compile_s, run_s, "tpu_custom_call" in compiled.as_text()
+    return out, "tpu_custom_call" in compiled.as_text()
 
 
 def build_fpms(n: int, p: int = 2):
@@ -104,12 +100,11 @@ def phase_planned_complex(n: int, fpms) -> None:
                             config=PlanConfig(radix=4, fused=True)))]
     for name, kw in cases:
         plan = plan_pfft(n, **kw)
-        out, c_s, r_s, kernel = run_compiled(plan.execute, x)
+        out, kernel = run_compiled(plan.execute, x)
         if not kernel:
             raise AssertionError(f"complex-{name} n={n}: no tpu_custom_call "
                                  f"in the program of [{plan.config.describe()}]")
         _report(f"complex2d-{name}", _rel_err(out, ref), TOL, n=n,
-                time_s=f"{r_s:.4f}", compile_s=f"{c_s:.2f}",
                 config=f"[{plan.config.describe()}]",
                 source=plan.tuning["source"], tpu_custom_call=kernel)
         del out
@@ -121,10 +116,9 @@ def phase_czt(n: int, fpms) -> None:
     x = _complex_signal(np.random.default_rng(SEED + 1), (n, n))
     ref = np.fft.fft2(x.astype(np.complex128))
     plan = plan_pfft(n, fpms=fpms, method="fpm-czt", tune="estimate")
-    out, c_s, r_s, kernel = run_compiled(plan.execute, x)
-    _report("czt", _rel_err(out, ref), TOL, n=n, time_s=f"{r_s:.4f}",
-            compile_s=f"{c_s:.2f}", config=f"[{plan.config.describe()}]",
-            tpu_custom_call=kernel)
+    out, kernel = run_compiled(plan.execute, x)
+    _report("czt", _rel_err(out, ref), TOL, n=n,
+            config=f"[{plan.config.describe()}]", tpu_custom_call=kernel)
 
 
 def _direct_bins(x: np.ndarray, bins) -> np.ndarray:
@@ -150,7 +144,7 @@ def phase_largest(n: int) -> None:
     from repro.core import plan_pfft
     x = _complex_signal(np.random.default_rng(SEED + 2), (n, n))
     plan = plan_pfft(n, method="lb", p=1, tune="estimate")
-    out, c_s, r_s, kernel = run_compiled(plan.execute, x)
+    out, kernel = run_compiled(plan.execute, x)
     out = np.asarray(out)
     e_in = e_out = 0.0
     for lo in range(0, n, 1024):
@@ -163,9 +157,9 @@ def phase_largest(n: int) -> None:
     bin_err = float(np.max(np.abs(got - direct)) / np.sqrt(e_out / (n * n)))
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
     _report("largest-parseval", parseval, PARSEVAL_TOL, n=n)
-    _report("largest-bins", bin_err, TOL, n=n, time_s=f"{r_s:.4f}",
-            compile_s=f"{c_s:.2f}", config=f"[{plan.config.describe()}]",
-            tpu_custom_call=kernel, peak_bytes_in_use=peak)
+    _report("largest-bins", bin_err, TOL, n=n,
+            config=f"[{plan.config.describe()}]", tpu_custom_call=kernel,
+            peak_bytes_in_use=peak)
 
 
 def phase_real(n: int) -> None:
@@ -174,10 +168,9 @@ def phase_real(n: int) -> None:
     ref = np.fft.rfft2(x.astype(np.float64))
     plan = plan_pfft(n, method="rfft-lb", p=1, dtype="float32",
                      tune="estimate")
-    out, c_s, r_s, kernel = run_compiled(plan.execute, x)
-    _report("real2d", _rel_err(out, ref), TOL, n=n, time_s=f"{r_s:.4f}",
-            compile_s=f"{c_s:.2f}", config=f"[{plan.config.describe()}]",
-            tpu_custom_call=kernel)
+    out, kernel = run_compiled(plan.execute, x)
+    _report("real2d", _rel_err(out, ref), TOL, n=n,
+            config=f"[{plan.config.describe()}]", tpu_custom_call=kernel)
 
 
 def phase_served(sizes=(1024, 2048, 4096)) -> None:

@@ -40,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.fpm import FPMSet
 from repro.core.partition import PartitionResult, lb_partition, partition_rows
 from repro.core.pfft import _pfft_limb
@@ -137,8 +138,42 @@ def _build_raw(n: int, method: Method, d: np.ndarray,
     return raw
 
 
+class _Scoped:
+    """What every plan type shares with ``repro.obs``: a plan is tracked
+    while it lives, and names the instructions of its own executable."""
+
+    def __post_init__(self) -> None:
+        obs.register(self)
+
+    def scope_map(self) -> dict[str, str | None]:
+        """``{instruction name: pfft.* scope or None}`` of the executable
+        ``execute`` runs on one planned input (``repro.obs.scope_map``).
+
+        Lowers the plan's function on ``input_spec()`` (one planned
+        input, laid out as planned) and compiles it, which the compile
+        cache serves once the plan has run; computed once per plan,
+        never per call.  The names are those a profiler trace gives the
+        device ops, so the map reads an xprof trace of this plan by
+        program phase."""
+        found = getattr(self, "_scopes", None)
+        if found is None:
+            found = self._scopes = obs.compiled_scope_map(
+                self._fn, self.input_spec())
+        return found
+
+
+def _sharded(shape, dtype, mesh, spec) -> jax.ShapeDtypeStruct:
+    """An input of ``shape`` laid out by ``spec`` over ``mesh``, or on
+    the default device where there is no mesh."""
+    from jax.sharding import (NamedSharding, PartitionSpec,
+                              SingleDeviceSharding)
+    sharding = (SingleDeviceSharding(jax.devices()[0]) if mesh is None
+                else NamedSharding(mesh, PartitionSpec(*spec)))
+    return jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=sharding)
+
+
 @dataclasses.dataclass
-class PfftPlan:
+class PfftPlan(_Scoped):
     n: int
     method: Method
     partition: PartitionResult
@@ -171,16 +206,21 @@ class PfftPlan:
             raise ValueError(
                 f"plan is for ({self.n}, {self.n}) signals "
                 f"(optionally with leading batch dims), got {m.shape}")
-        if m.ndim == 2:
-            return self._fn(m)
-        fn = self._batched_fns.get(m.ndim)
-        if fn is None:
-            fn = self._fn
-            for _ in range(m.ndim - 2):
-                fn = jax.vmap(fn)
-            fn = jax.jit(fn)
-            self._batched_fns[m.ndim] = fn
-        return fn(m)
+        with obs.span("pfft.execute"):
+            if m.ndim == 2:
+                return self._fn(m)
+            fn = self._batched_fns.get(m.ndim)
+            if fn is None:
+                fn = self._fn
+                for _ in range(m.ndim - 2):
+                    fn = jax.vmap(fn)
+                fn = jax.jit(fn)
+                self._batched_fns[m.ndim] = fn
+            return fn(m)
+
+    def input_spec(self) -> jax.ShapeDtypeStruct:
+        return _sharded((self.n, self.n), self.dtype, self.mesh,
+                        (self.axis_name, None))
 
     def execute_many(self, ms, *, pad_to: int | None = None) -> list:
         """Serve a cohort: stack same-size signals into ONE batched dispatch.
@@ -488,40 +528,41 @@ def plan_pfft(n: int, *, p: int | None = None, fpms: FPMSet | None = None,
             fused=bool(fused) and pad_strategy == "none",
             pad=pad_strategy)
 
-    if base == "lb":
-        if p is None:
-            raise ValueError(f"method={method!r} requires p")
-        part = lb_partition(n, p)
-        pads = None
-    else:
-        if fpms is None:
-            raise ValueError(f"method={method!r} requires fpms")
-        if mesh is not None:
-            # SPMD shards rows evenly — the FPMs drive per-device pad
-            # lengths and execution variants, not row counts (the
-            # device-group lowering's realisation of heterogeneity).
+    with obs.span("pfft.plan.partition"):
+        if base == "lb":
+            if p is None:
+                raise ValueError(f"method={method!r} requires p")
             part = lb_partition(n, p)
-        else:
-            part = partition_rows(n, fpms, eps)
-        if base == "fpm-pad" and real:
-            # Even pads only: the packed real row FFT transforms two rows
-            # per complex FFT, and the half-spectrum crop identity holds
-            # for any length >= n, so the model picks among even
-            # beneficial lengths.
-            from repro.plan.pads import rfft_pad_lengths
-            pads = rfft_pad_lengths(fpms, part.d, n)
-        elif base == "fpm-pad":
-            from repro.plan.pads import fpm_pad_lengths
-            pads = fpm_pad_lengths(fpms, part.d, n)
-        elif base == "fpm-czt":
-            from repro.plan.pads import czt_fft_lengths
-            pads = czt_fft_lengths(fpms, part.d, n, limit_ratio=2.0)
-        else:
             pads = None
-
-    schedule, tuning = _resolve_schedule(n, method, part, pads, fpms, tune,
-                                         wisdom, config, dtype,
-                                         mesh=mesh, axis_name=axis_name)
+        else:
+            if fpms is None:
+                raise ValueError(f"method={method!r} requires fpms")
+            if mesh is not None:
+                # SPMD shards rows evenly — the FPMs drive per-device pad
+                # lengths and execution variants, not row counts (the
+                # device-group lowering's realisation of heterogeneity).
+                part = lb_partition(n, p)
+            else:
+                part = partition_rows(n, fpms, eps)
+            if base == "fpm-pad" and real:
+                # Even pads only: the packed real row FFT transforms two
+                # rows per complex FFT, and the half-spectrum crop
+                # identity holds for any length >= n, so the model picks
+                # among even beneficial lengths.
+                from repro.plan.pads import rfft_pad_lengths
+                pads = rfft_pad_lengths(fpms, part.d, n)
+            elif base == "fpm-pad":
+                from repro.plan.pads import fpm_pad_lengths
+                pads = fpm_pad_lengths(fpms, part.d, n)
+            elif base == "fpm-czt":
+                from repro.plan.pads import czt_fft_lengths
+                pads = czt_fft_lengths(fpms, part.d, n, limit_ratio=2.0)
+            else:
+                pads = None
+    with obs.span("pfft.plan.schedule"):
+        schedule, tuning = _resolve_schedule(n, method, part, pads, fpms,
+                                             tune, wisdom, config, dtype,
+                                             mesh=mesh, axis_name=axis_name)
     raw = _build_raw(n, method, part.d, schedule, mesh, axis_name, dtype)
     return PfftPlan(n=n, method=method, partition=part, pad_lengths=pads,
                     config=schedule.anchor_config, schedule=schedule,
@@ -577,7 +618,7 @@ def _execute_many(plan, ms, shape: tuple[int, ...],
 
 
 @dataclasses.dataclass
-class Pfft3Plan:
+class Pfft3Plan(_Scoped):
     """A planned 3-D transform — same plan/execute/wisdom lifecycle as
     ``PfftPlan``, for cubic N^3 signals.
 
@@ -604,20 +645,25 @@ class Pfft3Plan:
             raise ValueError(
                 f"plan is for ({self.n}, {self.n}, {self.n}) signals "
                 f"(optionally with leading batch dims), got {m.shape}")
-        if m.ndim == 3:
-            return self._fn(m)
-        if self.mesh is not None:
+        if m.ndim > 3 and self.mesh is not None:
             raise ValueError(
                 "distributed pfft3 plans transform one cube per call "
                 "(vmapping over shard_map is not supported); loop instead")
-        fn = self._batched_fns.get(m.ndim)
-        if fn is None:
-            fn = self._fn
-            for _ in range(m.ndim - 3):
-                fn = jax.vmap(fn)
-            fn = jax.jit(fn)
-            self._batched_fns[m.ndim] = fn
-        return fn(m)
+        with obs.span("pfft.execute"):
+            if m.ndim == 3:
+                return self._fn(m)
+            fn = self._batched_fns.get(m.ndim)
+            if fn is None:
+                fn = self._fn
+                for _ in range(m.ndim - 3):
+                    fn = jax.vmap(fn)
+                fn = jax.jit(fn)
+                self._batched_fns[m.ndim] = fn
+            return fn(m)
+
+    def input_spec(self) -> jax.ShapeDtypeStruct:
+        spec = (*self.axis_names, None) if self.mesh is not None else ()
+        return _sharded((self.n,) * 3, self.dtype, self.mesh, spec)
 
     def execute_many(self, ms, *, pad_to: int | None = None) -> list:
         """Serve a cohort of cubes in ONE batched dispatch — the 3-D
@@ -749,7 +795,7 @@ def plan_pfft3(n: int, *, p: int | None = None, mesh=None,
 # ------------------------------------------------------------------ huge 1-D
 
 @dataclasses.dataclass
-class Pfft1LargePlan:
+class Pfft1LargePlan(_Scoped):
     """A planned four-step huge-1-D transform (``core.pfft_large``)."""
     n: int
     n1: int
@@ -768,16 +814,20 @@ class Pfft1LargePlan:
             raise ValueError(
                 f"plan is for length-{self.n} 1-D signals "
                 f"(optionally with leading batch dims), got {x.shape}")
-        if x.ndim == 1:
-            return self._fn(x)
-        fn = self._batched_fns.get(x.ndim)
-        if fn is None:
-            fn = self._fn
-            for _ in range(x.ndim - 1):
-                fn = jax.vmap(fn)
-            fn = jax.jit(fn)
-            self._batched_fns[x.ndim] = fn
-        return fn(x)
+        with obs.span("pfft.execute"):
+            if x.ndim == 1:
+                return self._fn(x)
+            fn = self._batched_fns.get(x.ndim)
+            if fn is None:
+                fn = self._fn
+                for _ in range(x.ndim - 1):
+                    fn = jax.vmap(fn)
+                fn = jax.jit(fn)
+                self._batched_fns[x.ndim] = fn
+            return fn(x)
+
+    def input_spec(self) -> jax.ShapeDtypeStruct:
+        return _sharded((self.n,), self.dtype, None, ())
 
     def execute_many(self, xs, *, pad_to: int | None = None) -> list:
         """Serve a cohort of lines in ONE batched dispatch — the 1-D

@@ -32,6 +32,7 @@ import warnings
 import numpy as np
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.fpm import FPMSet
 from repro.core.partition import PartitionResult, lb_partition, partition_rows
 from repro.fft.fft2d import fft_rows, rfft_rows
@@ -140,10 +141,11 @@ def _group_row_ffts(rows: jnp.ndarray, length: int, n: int,
     """
     if config.pad == "czt" and length > n:
         return czt_dft(rows, length)
-    if length > n:
-        rows = jnp.pad(rows, ((0, 0), (0, length - n)))
-        return _row_fft(rows, config, backend)[:, :n]
-    return _row_fft(rows, config, backend)
+    with obs.scope(obs.ROWFFT):
+        if length > n:
+            rows = jnp.pad(rows, ((0, 0), (0, length - n)))
+            return _row_fft(rows, config, backend)[:, :n]
+        return _row_fft(rows, config, backend)
 
 
 def segment_row_ffts(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
@@ -195,11 +197,12 @@ def segment_row_ffts(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
         length, cfg, idx = groups[0]
         if len(idx) == m.shape[0] and np.array_equal(idx, np.arange(len(idx))):
             return _group_row_ffts(m, length, n, cfg, backend)
-    out = jnp.zeros(m.shape, jnp.result_type(m, jnp.complex64))
-    for length, cfg, idx in groups:
-        res = _group_row_ffts(m[idx], length, n, cfg, backend)
-        out = out.at[idx].set(res)
-    return out
+    with obs.scope(obs.ROWFFT):  # the segment gather/scatter
+        out = jnp.zeros(m.shape, jnp.result_type(m, jnp.complex64))
+        for length, cfg, idx in groups:
+            res = _group_row_ffts(m[idx], length, n, cfg, backend)
+            out = out.at[idx].set(res)
+        return out
 
 
 def _pfft_limb(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
@@ -245,10 +248,10 @@ def _pfft_limb(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
         m = fft_rows_then_transpose(m)
         m = fft_rows_then_transpose(m)
         return m
-    m = segment_row_ffts(m, d, schedule=schedule)
-    m = m.T
-    m = segment_row_ffts(m, d, schedule=schedule)
-    m = m.T
+    for _ in range(2):
+        m = segment_row_ffts(m, d, schedule=schedule)
+        with obs.scope(obs.TRANSPOSE):
+            m = m.T
     return m
 
 
@@ -308,10 +311,11 @@ def _group_row_rffts(rows: jnp.ndarray, length: int, n: int,
         raise ValueError("the real pipeline has no Bluestein form "
                          "(PlanConfig rejects real+czt)")
     kwargs = config.row_fft_kwargs(backend)
-    if length > n:
-        rows = jnp.pad(rows, ((0, 0), (0, length - n)))
-        return rfft_rows(rows, **kwargs)[:, :nh]
-    return rfft_rows(rows, **kwargs)
+    with obs.scope(obs.ROWFFT):
+        if length > n:
+            rows = jnp.pad(rows, ((0, 0), (0, length - n)))
+            return rfft_rows(rows, **kwargs)[:, :nh]
+        return rfft_rows(rows, **kwargs)
 
 
 def segment_row_rffts(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
@@ -350,11 +354,12 @@ def segment_row_rffts(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
         if len(idx) == m.shape[0] and np.array_equal(idx, np.arange(len(idx))):
             return _group_row_rffts(m, length, n, cfg, backend)
     ctype = jnp.result_type(m, jnp.complex64)
-    out = jnp.zeros((m.shape[0], nh), ctype)
-    for length, cfg, idx in groups:
-        res = _group_row_rffts(m[idx], length, n, cfg, backend)
-        out = out.at[idx].set(res)
-    return out
+    with obs.scope(obs.ROWFFT):  # the segment gather/scatter
+        out = jnp.zeros((m.shape[0], nh), ctype)
+        for length, cfg, idx in groups:
+            res = _group_row_rffts(m[idx], length, n, cfg, backend)
+            out = out.at[idx].set(res)
+        return out
 
 
 def _rpfft_limb(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
@@ -395,9 +400,13 @@ def _rpfft_limb(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
                                      rfft_rows_then_transpose)
         h = rfft_rows_then_transpose(m)     # (nh, n)
         return fft_rows_then_transpose(h)   # (n, nh)
-    h = segment_row_rffts(m, d, schedule=schedule).T          # (nh, n)
+    h = segment_row_rffts(m, d, schedule=schedule)
+    with obs.scope(obs.TRANSPOSE):
+        h = h.T                                                # (nh, n)
     d2, sched2 = _clip_schedule(schedule, np.asarray(d), nh)
-    return segment_row_ffts(h, d2, schedule=sched2).T         # (n, nh)
+    h = segment_row_ffts(h, d2, schedule=sched2)
+    with obs.scope(obs.TRANSPOSE):
+        return h.T                                             # (n, nh)
 
 
 def pfft_lb(m: jnp.ndarray, p: int, *, use_stockham: bool | None = None,
@@ -521,13 +530,16 @@ def czt_dft(x: jnp.ndarray, m_fft: int | None = None) -> jnp.ndarray:
         raise ValueError(f"m_fft={m_fft} < 2N-1={2 * n - 1}")
     ctype = jnp.result_type(x, jnp.complex64)
     chirp = jnp.asarray(_czt_chirp(n).astype(ctype))
-    a = jnp.zeros(x.shape[:-1] + (m_fft,), ctype).at[..., :n].set(x * chirp)
-    # Kernel b_j = conj(chirp)_{|j|}, wrapped for circular convolution.
-    b = jnp.zeros(m_fft, ctype)
-    b = b.at[:n].set(jnp.conj(chirp))
-    b = b.at[m_fft - n + 1:].set(jnp.conj(chirp)[1:n][::-1])
-    conv = jnp.fft.ifft(jnp.fft.fft(a, axis=-1) * jnp.fft.fft(b), axis=-1)
-    return (conv[..., :n] * chirp).astype(ctype)
+    with obs.scope(obs.ROWFFT):
+        a = jnp.zeros(x.shape[:-1] + (m_fft,), ctype).at[..., :n].set(
+            x * chirp)
+        # Kernel b_j = conj(chirp)_{|j|}, wrapped for circular convolution.
+        b = jnp.zeros(m_fft, ctype)
+        b = b.at[:n].set(jnp.conj(chirp))
+        b = b.at[m_fft - n + 1:].set(jnp.conj(chirp)[1:n][::-1])
+        conv = jnp.fft.ifft(jnp.fft.fft(a, axis=-1) * jnp.fft.fft(b),
+                            axis=-1)
+        return (conv[..., :n] * chirp).astype(ctype)
 
 
 def pfft_fpm_czt(m: jnp.ndarray, fpms: FPMSet, eps: float = 0.05, *,

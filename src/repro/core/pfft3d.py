@@ -46,11 +46,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import obs
 from repro.core.fpm import FPMSet
 from repro.core.partition import lb_partition, partition_rows
 from repro.core.pfft import _group_row_ffts
-from repro.core.pfft_dist import (_local_fft, default_dist_pad_len,
-                                  hier_all_to_all, require_mesh_divisible,
+from repro.core.pfft_dist import (_flat_a2a, _local_fft,
+                                  default_dist_pad_len, hier_all_to_all,
+                                  require_mesh_divisible,
                                   validate_spmd_schedule)
 from repro.plan.config import PlanConfig, normalize_pad
 from repro.plan.groups import DeviceGroupProgram, device_group_program
@@ -78,17 +80,19 @@ def _axis_pass(m: jnp.ndarray, d: np.ndarray, pads=None,
     cfg = config if config is not None else PlanConfig()
     offs = np.concatenate([[0], np.cumsum(d)])
     outs = []
-    for i in range(len(d)):
-        lo, hi = int(offs[i]), int(offs[i + 1])
-        if hi == lo:
-            continue
-        seg = m[lo:hi]
-        length = n
-        if pads is not None and int(pads[i]) > n:
-            length = int(pads[i])
-        rows = _group_row_ffts(seg.reshape(-1, n), length, n, cfg, backend)
-        outs.append(rows.reshape(seg.shape[:-1] + (n,)))
-    return jnp.concatenate(outs, axis=0)
+    with obs.scope(obs.ROWFFT):  # the segment slices and concatenation
+        for i in range(len(d)):
+            lo, hi = int(offs[i]), int(offs[i + 1])
+            if hi == lo:
+                continue
+            seg = m[lo:hi]
+            length = n
+            if pads is not None and int(pads[i]) > n:
+                length = int(pads[i])
+            rows = _group_row_ffts(seg.reshape(-1, n), length, n, cfg,
+                                   backend)
+            outs.append(rows.reshape(seg.shape[:-1] + (n,)))
+        return jnp.concatenate(outs, axis=0)
 
 
 def _pfft3(m: jnp.ndarray, d: np.ndarray, pads=None,
@@ -98,7 +102,8 @@ def _pfft3(m: jnp.ndarray, d: np.ndarray, pads=None,
     _require_cube(m)
     for _ in range(3):
         m = _axis_pass(m, d, pads, config, backend)  # FFT along last axis
-        m = jnp.moveaxis(m, -1, 0)           # rotate axes (z,y,x) -> (x,z,y)
+        with obs.scope(obs.TRANSPOSE):
+            m = jnp.moveaxis(m, -1, 0)       # rotate axes (z,y,x) -> (x,z,y)
     return m
 
 
@@ -196,13 +201,16 @@ def _pencil_phase(block: jnp.ndarray, fft3, a2a, rearrange, panels: int,
     ``concat_dim`` is where ``split_dim`` lands after ``rearrange``.
     """
     if panels <= 1:
-        return rearrange(a2a(fft3(block)))
+        out = a2a(fft3(block))
+        with obs.scope(obs.TRANSPOSE):
+            return rearrange(out)
     chunk = block.shape[split_dim] // panels
 
     def panel(i: int) -> jnp.ndarray:
         idx = [slice(None)] * 3
         idx[split_dim] = slice(i * chunk, (i + 1) * chunk)
-        return block[tuple(idx)]
+        with obs.scope(obs.TRANSPOSE):
+            return block[tuple(idx)]
 
     gathered = []
     current = fft3(panel(0))
@@ -211,7 +219,9 @@ def _pencil_phase(block: jnp.ndarray, fft3, a2a, rearrange, panels: int,
         current = fft3(panel(i))       # ... while transforming panel i
         gathered.append(in_flight)
     gathered.append(a2a(current))
-    return jnp.concatenate([rearrange(g) for g in gathered], axis=concat_dim)
+    with obs.scope(obs.TRANSPOSE):
+        return jnp.concatenate([rearrange(g) for g in gathered],
+                               axis=concat_dim)
 
 
 def pfft3_pencil(
@@ -272,10 +282,8 @@ def pfft3_pencil(
     fft3 = _pencil_rows_fft(n, padded=padded, pad_len=pad_len, config=config,
                             backend=backend, program=program,
                             axis_names=(ax_r, ax_c), c=c)
-    a2a_c = functools.partial(jax.lax.all_to_all, axis_name=ax_c,
-                              split_axis=2, concat_axis=1, tiled=True)
-    a2a_r = functools.partial(jax.lax.all_to_all, axis_name=ax_r,
-                              split_axis=2, concat_axis=0, tiled=True)
+    a2a_c = _flat_a2a(ax_c, 2, 1)
+    a2a_r = _flat_a2a(ax_r, 2, 0)
     if config.exchange == "hier":
         # On a host-major pencil mesh only the r axis spans hosts (the
         # c-axis communicators live inside one box — make_pfft3_mesh's
@@ -311,7 +319,8 @@ def pfft3_pencil(
         return out
     # Outside shard_map: GSPMD reshards, and the result matches
     # jnp.fft.fftn bin for bin.
-    return jnp.transpose(out, (2, 1, 0))
+    with obs.scope(obs.TRANSPOSE):
+        return jnp.transpose(out, (2, 1, 0))
 
 
 def pfft3_slab(m: jnp.ndarray, mesh: Mesh, axis_name: str = "fft", *,
@@ -337,8 +346,7 @@ def pfft3_slab(m: jnp.ndarray, mesh: Mesh, axis_name: str = "fft", *,
     fft3 = _pencil_rows_fft(n, padded=padded, pad_len=pad_len, config=cfg,
                             backend=backend, program=None, axis_names=None,
                             c=1)
-    rotate = functools.partial(jax.lax.all_to_all, axis_name=axis_name,
-                               split_axis=2, concat_axis=0, tiled=True)
+    rotate = _flat_a2a(axis_name, 2, 0)
     if cfg.exchange == "hier":
         from repro.launch.mesh import mesh_host_shape
         hosts, local = mesh_host_shape(mesh, axis_name)
@@ -356,7 +364,8 @@ def pfft3_slab(m: jnp.ndarray, mesh: Mesh, axis_name: str = "fft", *,
             # distributed rotation: split the transformed axis, concat the
             # sharded plane axis, then rotate locally.
             block = rotate(block)                                  # (n, n, n/p)
-            block = jnp.moveaxis(block, -1, 0)                     # (n/p, n, n)
+            with obs.scope(obs.TRANSPOSE):
+                block = jnp.moveaxis(block, -1, 0)                 # (n/p, n, n)
         return block
 
     return _run(m)

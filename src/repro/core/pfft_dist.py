@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.padding import pad_to_smooth
 from repro.core.pfft import czt_dft
 from repro.fft.fft2d import fft_rows, fft_rows_then_transpose, rfft_rows
@@ -130,17 +131,28 @@ def hier_all_to_all(x: jnp.ndarray, *, axis_name: str, hosts: int,
     p = hosts * local
     shape = x.shape
     w = shape[split_axis] // p
-    xs = x.reshape(shape[:split_axis] + (hosts, local, w)
-                   + shape[split_axis + 1:])
-    xs = xs.swapaxes(split_axis, split_axis + 1)
-    x = xs.reshape(shape)
-    intra, inter = _hier_groups(hosts, local)
-    x = jax.lax.all_to_all(x, axis_name, split_axis=split_axis,
-                           concat_axis=concat_axis, tiled=True,
-                           axis_index_groups=intra)
-    return jax.lax.all_to_all(x, axis_name, split_axis=split_axis,
-                              concat_axis=concat_axis, tiled=True,
-                              axis_index_groups=inter)
+    with obs.scope(obs.EXCHANGE):
+        xs = x.reshape(shape[:split_axis] + (hosts, local, w)
+                       + shape[split_axis + 1:])
+        xs = xs.swapaxes(split_axis, split_axis + 1)
+        x = xs.reshape(shape)
+        intra, inter = _hier_groups(hosts, local)
+        x = jax.lax.all_to_all(x, axis_name, split_axis=split_axis,
+                               concat_axis=concat_axis, tiled=True,
+                               axis_index_groups=intra)
+        return jax.lax.all_to_all(x, axis_name, split_axis=split_axis,
+                                  concat_axis=concat_axis, tiled=True,
+                                  axis_index_groups=inter)
+
+
+def _flat_a2a(axis_name: str, split_axis: int, concat_axis: int):
+    """The tiled ``all_to_all`` over ``axis_name``, traced under the
+    ``pfft.exchange`` scope."""
+    def a2a(x: jnp.ndarray) -> jnp.ndarray:
+        with obs.scope(obs.EXCHANGE):
+            return jax.lax.all_to_all(x, axis_name, split_axis=split_axis,
+                                      concat_axis=concat_axis, tiled=True)
+    return a2a
 
 
 def _exchange_fns(axis_name: str, host_shape: tuple[int, int] | None):
@@ -157,11 +169,7 @@ def _exchange_fns(axis_name: str, host_shape: tuple[int, int] | None):
                                   hosts=hosts, local=local,
                                   split_axis=0, concat_axis=1)
         return a2a, a2a_t
-    a2a = functools.partial(jax.lax.all_to_all, axis_name=axis_name,
-                            split_axis=1, concat_axis=0, tiled=True)
-    a2a_t = functools.partial(jax.lax.all_to_all, axis_name=axis_name,
-                              split_axis=0, concat_axis=1, tiled=True)
-    return a2a, a2a_t
+    return _flat_a2a(axis_name, 1, 0), _flat_a2a(axis_name, 0, 1)
 
 
 def _local_fft(block: jnp.ndarray, n: int, *, padded: str | None,
@@ -171,10 +179,11 @@ def _local_fft(block: jnp.ndarray, n: int, *, padded: str | None,
     if padded == "czt":
         return czt_dft(block, pad_len)
     kw = config.row_fft_kwargs(backend)
-    if padded == "crop" and pad_len > n:
-        block = jnp.pad(block, ((0, 0), (0, pad_len - n)))
-        return fft_rows(block, **kw)[:, :n]
-    return fft_rows(block, **kw)
+    with obs.scope(obs.ROWFFT):
+        if padded == "crop" and pad_len > n:
+            block = jnp.pad(block, ((0, 0), (0, pad_len - n)))
+            return fft_rows(block, **kw)[:, :n]
+        return fft_rows(block, **kw)
 
 
 def _faulted_fft(fft, axis_name: str, axis_size: int | None):
@@ -208,8 +217,9 @@ def _faulted_fft(fft, axis_name: str, axis_size: int | None):
     branches = [repeated(fft, r) for r in distinct]
 
     def slowed(block: jnp.ndarray) -> jnp.ndarray:
-        b = branch_of[jax.lax.axis_index(axis_name)]
-        return jax.lax.switch(b, branches, block)
+        with obs.scope(obs.ROWFFT):
+            b = branch_of[jax.lax.axis_index(axis_name)]
+            return jax.lax.switch(b, branches, block)
 
     return slowed
 
@@ -234,8 +244,9 @@ def _grouped_local_fft(axis_name: str, n: int, *, padded: str | None,
     groups = jnp.asarray(np.asarray(program.group_of_device, dtype=np.int32))
 
     def fft(block: jnp.ndarray) -> jnp.ndarray:
-        gid = groups[jax.lax.axis_index(axis_name)]
-        return jax.lax.switch(gid, branches, block)
+        with obs.scope(obs.ROWFFT):
+            gid = groups[jax.lax.axis_index(axis_name)]
+            return jax.lax.switch(gid, branches, block)
 
     return fft
 
@@ -316,19 +327,26 @@ def _local_phase(block: jnp.ndarray, axis_name: str, n: int, *,
     if k <= 1:
         if fused:
             return a2a_t(fft_t(block))  # (N/p, N): a row-block of M^T
-        return a2a(fft(block)).T
+        out = a2a(fft(block))
+        with obs.scope(obs.TRANSPOSE):
+            return out.T
 
     c = n_loc // k  # rows per panel
+
+    def panel(i: int) -> jnp.ndarray:
+        with obs.scope(obs.TRANSPOSE):
+            return block[i * c:(i + 1) * c]
+
     # Software pipeline: FFT panel 0; then alternate (issue all_to_all of
     # panel i, FFT panel i+1) so each exchange overlaps the next FFT.
     # Fused panels exchange transposed (see above); their gathered tiles
     # arrive already column-major, saving the per-panel transpose below.
     gathered = []
-    current = fft_t(block[:c]) if fused else fft(block[:c])
+    current = fft_t(panel(0)) if fused else fft(panel(0))
     exchange = a2a_t if fused else a2a
     for i in range(1, k):
         in_flight = exchange(current)      # exchange panel i-1 ...
-        nxt = block[i * c:(i + 1) * c]     # ... while transforming i
+        nxt = panel(i)                     # ... while transforming i
         current = fft_t(nxt) if fused else fft(nxt)
         gathered.append(in_flight)
     gathered.append(exchange(current))
@@ -338,12 +356,13 @@ def _local_phase(block: jnp.ndarray, axis_name: str, n: int, *,
     # rows q*n_loc + i*c + r (q peer-major, r in-panel).  Fused tiles are
     # already transposed, (N/p, N/k).  Interleave panels so output
     # columns are in global row order, matching the monolithic path.
-    tiles = [g if fused else g.T for g in gathered]   # (rows_out, n_loc/k)
-    rows_out = tiles[0].shape[0]
-    p = tiles[0].shape[1] * k // n_loc if n_loc else 1
-    panels_t = [t.reshape(rows_out, p, c) for t in tiles]
-    out = jnp.stack(panels_t, axis=2)      # (rows_out, p, k, c)
-    return out.reshape(rows_out, p * k * c)
+    with obs.scope(obs.TRANSPOSE):
+        tiles = [g if fused else g.T for g in gathered]  # (rows_out, n_loc/k)
+        rows_out = tiles[0].shape[0]
+        p = tiles[0].shape[1] * k // n_loc if n_loc else 1
+        panels_t = [t.reshape(rows_out, p, c) for t in tiles]
+        out = jnp.stack(panels_t, axis=2)      # (rows_out, p, k, c)
+        return out.reshape(rows_out, p * k * c)
 
 
 def validate_spmd_schedule(schedule: SegmentSchedule,
@@ -687,20 +706,21 @@ def rpfft2_distributed(
     nh = n // 2 + 1
     hc = halfspec_cols(n, p)
     kw = config.row_fft_kwargs(backend)
-    a2a = functools.partial(jax.lax.all_to_all, axis_name=axis_name,
-                            split_axis=1, concat_axis=0, tiled=True)
+    a2a = _flat_a2a(axis_name, 1, 0)
 
     def local_rfft(block: jnp.ndarray) -> jnp.ndarray:
-        if padded == "crop" and pad_len > n:
-            block = jnp.pad(block, ((0, 0), (0, pad_len - n)))
-            return rfft_rows(block, **kw)[:, :nh]
-        return rfft_rows(block, **kw)
+        with obs.scope(obs.ROWFFT):
+            if padded == "crop" and pad_len > n:
+                block = jnp.pad(block, ((0, 0), (0, pad_len - n)))
+                return rfft_rows(block, **kw)[:, :nh]
+            return rfft_rows(block, **kw)
 
     def local_fft(block: jnp.ndarray) -> jnp.ndarray:
-        if padded == "crop" and pad_len > n:
-            block = jnp.pad(block, ((0, 0), (0, pad_len - n)))
-            return fft_rows(block, **kw)[:, :n]
-        return fft_rows(block, **kw)
+        with obs.scope(obs.ROWFFT):
+            if padded == "crop" and pad_len > n:
+                block = jnp.pad(block, ((0, 0), (0, pad_len - n)))
+                return fft_rows(block, **kw)[:, :n]
+            return fft_rows(block, **kw)
 
     spec_rows = P(axis_name, None)
 
@@ -712,14 +732,20 @@ def rpfft2_distributed(
         # Phase 1: local rffts, pad the half spectrum to the p-divisible
         # panel width, exchange + transpose -> spectral rows sharded.
         h = local_rfft(block)                       # (n/p, nh)
-        h = jnp.pad(h, ((0, 0), (0, hc - nh)))      # (n/p, hc)
-        h = a2a(h).T                                # (hc/p, n)
+        with obs.scope(obs.TRANSPOSE):
+            h = jnp.pad(h, ((0, 0), (0, hc - nh)))  # (n/p, hc)
+        h = a2a(h)
+        with obs.scope(obs.TRANSPOSE):
+            h = h.T                                 # (hc/p, n)
         # Phase 2: complex FFTs down the (original) columns, exchange the
         # half-width panel back -> row-sharded (n/p, hc).
-        f = local_fft(h)                            # (hc/p, n)
-        return a2a(f).T                             # (n/p, hc)
+        f = a2a(local_fft(h))
+        with obs.scope(obs.TRANSPOSE):
+            return f.T                              # (n/p, hc)
 
-    return _run(m)[:, :nh]
+    out = _run(m)
+    with obs.scope(obs.TRANSPOSE):
+        return out[:, :nh]
 
 
 def irpfft2_distributed(
@@ -747,8 +773,7 @@ def irpfft2_distributed(
     p = int(mesh.shape[axis_name])
     require_mesh_divisible(n, p, axis_name)
     hc = halfspec_cols(n, p)
-    a2a = functools.partial(jax.lax.all_to_all, axis_name=axis_name,
-                            split_axis=1, concat_axis=0, tiled=True)
+    a2a = _flat_a2a(axis_name, 1, 0)
 
     spec_rows = P(axis_name, None)
 

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
 from repro.plan.config import PlanConfig
 
 __all__ = ["four_step_factors", "pfft1_large_apply"]
@@ -105,11 +106,16 @@ def pfft1_large_apply(x, *, config: PlanConfig | None = None,
     # Step 1: n1 rows of length n2.  x[j1 + n1*j2] reshapes to (n2, n1)
     # with j2 as the row index, so the length-n2 lines are the *columns*
     # — transpose first.
-    a = jnp.transpose(x.reshape(n2, n1))
+    with obs.scope(obs.TRANSPOSE):
+        a = jnp.transpose(x.reshape(n2, n1))
     b = _group_row_ffts(a, n2, n2, cfg, backend)
     # Step 2: pointwise twiddle W_N^{j1*k2}.
-    cmat = b * jnp.asarray(_twiddle(n1, n2))
+    with obs.scope(obs.ROWFFT):
+        cmat = b * jnp.asarray(_twiddle(n1, n2))
     # Step 3: n2 rows of length n1 (transpose brings k2 to the row index).
-    e = _group_row_ffts(jnp.transpose(cmat), n1, n1, cfg, backend)
+    with obs.scope(obs.TRANSPOSE):
+        cmat = jnp.transpose(cmat)
+    e = _group_row_ffts(cmat, n1, n1, cfg, backend)
     # Step 4: E[k2, k1] -> X[k2 + n2*k1] is a transpose-reshape.
-    return jnp.transpose(e).reshape(-1)
+    with obs.scope(obs.TRANSPOSE):
+        return jnp.transpose(e).reshape(-1)

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.fft.fft1d import fft1d_stockham
 
 __all__ = ["fft2d_rowcol", "fft_rows", "fft_rows_then_transpose",
@@ -31,12 +32,13 @@ def fft_rows(m: jnp.ndarray, *, use_stockham: bool = False,
     n = m.shape[-1]
     if backend is None:
         backend = "stockham" if use_stockham else "xla"
-    if backend == "pallas" and not (n & (n - 1)):
-        from repro.kernels.fft.ops import fft_rows_op
-        return fft_rows_op(m)
-    if backend == "stockham" and not (n & (n - 1)):
-        return fft1d_stockham(m)
-    return jnp.fft.fft(m, axis=-1)
+    with obs.scope(obs.ROWFFT):
+        if backend == "pallas" and not (n & (n - 1)):
+            from repro.kernels.fft.ops import fft_rows_op
+            return fft_rows_op(m)
+        if backend == "stockham" and not (n & (n - 1)):
+            return fft1d_stockham(m)
+        return jnp.fft.fft(m, axis=-1)
 
 
 def fft_rows_then_transpose(m: jnp.ndarray, *,
@@ -54,8 +56,11 @@ def fft_rows_then_transpose(m: jnp.ndarray, *,
                 and jnp.result_type(m, jnp.complex64) == jnp.complex64)
     if eligible and backend in (None, "pallas", "fused"):
         from repro.kernels.fused.ops import fft_rows_transpose_op
-        return fft_rows_transpose_op(m)
-    return fft_rows(m, backend=backend).swapaxes(-1, -2)
+        with obs.scope(obs.ROWFFT):
+            return fft_rows_transpose_op(m)
+    out = fft_rows(m, backend=backend)
+    with obs.scope(obs.TRANSPOSE):
+        return out.swapaxes(-1, -2)
 
 
 def _packed_rfft(m: jnp.ndarray, fft_fn) -> jnp.ndarray:
@@ -68,18 +73,20 @@ def _packed_rfft(m: jnp.ndarray, fft_fn) -> jnp.ndarray:
     """
     rows, n = m.shape[-2], m.shape[-1]
     nh = n // 2 + 1
-    if rows % 2:
-        pad = [(0, 0)] * (m.ndim - 2) + [(0, 1), (0, 0)]
-        m = jnp.pad(m, pad)
-    z = m[..., 0::2, :] + 1j * m[..., 1::2, :]
-    zf = fft_fn(z)
-    zrev = jnp.concatenate([zf[..., :1], jnp.flip(zf[..., 1:], axis=-1)],
-                           axis=-1)
-    spec_a = 0.5 * (zf + jnp.conj(zrev))
-    spec_b = -0.5j * (zf - jnp.conj(zrev))
-    out = jnp.stack([spec_a, spec_b], axis=-2)
-    out = out.reshape(out.shape[:-3] + (-1, n))
-    return out[..., :rows, :nh]
+    with obs.scope(obs.JOIN):
+        if rows % 2:
+            pad = [(0, 0)] * (m.ndim - 2) + [(0, 1), (0, 0)]
+            m = jnp.pad(m, pad)
+        z = m[..., 0::2, :] + 1j * m[..., 1::2, :]
+    with obs.scope(obs.ROWFFT):
+        zf = fft_fn(z)
+        zrev = jnp.concatenate([zf[..., :1], jnp.flip(zf[..., 1:], axis=-1)],
+                               axis=-1)
+        spec_a = 0.5 * (zf + jnp.conj(zrev))
+        spec_b = -0.5j * (zf - jnp.conj(zrev))
+        out = jnp.stack([spec_a, spec_b], axis=-2)
+        out = out.reshape(out.shape[:-3] + (-1, n))
+        return out[..., :rows, :nh]
 
 
 def rfft_rows(m: jnp.ndarray, *, backend: str | None = None) -> jnp.ndarray:
@@ -91,12 +98,13 @@ def rfft_rows(m: jnp.ndarray, *, backend: str | None = None) -> jnp.ndarray:
     lengths required for the kernel backends, XLA otherwise.
     """
     n = m.shape[-1]
-    if backend == "pallas" and m.ndim >= 2 and not (n & (n - 1)):
-        from repro.kernels.fft.real import rfft_rows_op
-        return rfft_rows_op(m)
     if backend == "stockham" and m.ndim >= 2 and not (n & (n - 1)):
         return _packed_rfft(m, fft1d_stockham)
-    return jnp.fft.rfft(m, axis=-1)
+    with obs.scope(obs.ROWFFT):
+        if backend == "pallas" and m.ndim >= 2 and not (n & (n - 1)):
+            from repro.kernels.fft.real import rfft_rows_op
+            return rfft_rows_op(m)
+        return jnp.fft.rfft(m, axis=-1)
 
 
 def rfft_rows_then_transpose(m: jnp.ndarray, *,
@@ -112,8 +120,11 @@ def rfft_rows_then_transpose(m: jnp.ndarray, *,
                 and jnp.result_type(m, jnp.complex64) == jnp.complex64)
     if eligible and backend in (None, "pallas", "fused"):
         from repro.kernels.fused.real import rfft_rows_transpose_op
-        return rfft_rows_transpose_op(m)
-    return rfft_rows(m, backend=backend).swapaxes(-1, -2)
+        with obs.scope(obs.ROWFFT):
+            return rfft_rows_transpose_op(m)
+    out = rfft_rows(m, backend=backend)
+    with obs.scope(obs.TRANSPOSE):
+        return out.swapaxes(-1, -2)
 
 
 def rfft2(m: jnp.ndarray, *, backend: str | None = None) -> jnp.ndarray:
@@ -124,9 +135,12 @@ def rfft2(m: jnp.ndarray, *, backend: str | None = None) -> jnp.ndarray:
     columns.  Phase 2 is a plain complex ``fft_rows`` on the transposed
     half spectrum — the conjugate-symmetric half never materialises.
     """
-    h = rfft_rows(m, backend=backend).swapaxes(-1, -2)
+    h = rfft_rows(m, backend=backend)
+    with obs.scope(obs.TRANSPOSE):
+        h = h.swapaxes(-1, -2)
     h = fft_rows(h, backend=backend)
-    return h.swapaxes(-1, -2)
+    with obs.scope(obs.TRANSPOSE):
+        return h.swapaxes(-1, -2)
 
 
 def irfft2(h: jnp.ndarray, *, n: int | None = None) -> jnp.ndarray:

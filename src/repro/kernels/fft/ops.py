@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.fft.kernel import (LANES, dft_tables, fft_rows_pallas,
                                       split_length)
 
@@ -131,10 +132,11 @@ def rows_to_padded_planes(x2: jnp.ndarray, block_rows: int
     multiple, plus the original row count for cropping the result."""
     total = x2.shape[0]
     padded = (total + block_rows - 1) // block_rows * block_rows
-    if padded != total:
-        x2 = jnp.pad(x2, ((0, padded - total), (0, 0)))
-    return (jnp.real(x2).astype(jnp.float32),
-            jnp.imag(x2).astype(jnp.float32), total)
+    with obs.scope(obs.SPLIT):
+        if padded != total:
+            x2 = jnp.pad(x2, ((0, padded - total), (0, 0)))
+        return (jnp.real(x2).astype(jnp.float32),
+                jnp.imag(x2).astype(jnp.float32), total)
 
 
 @functools.partial(jax.jit,
@@ -156,5 +158,7 @@ def fft_rows_op(
     re, im, total = rows_to_padded_planes(x2, block_rows)
     ore, oim = fft_rows_pallas(re, im, block_rows=block_rows, inverse=inverse,
                                interpret=interpret, vmem_limit_bytes=limit)
-    out = (ore[:total] + 1j * oim[:total]).astype(jnp.result_type(x, jnp.complex64))
-    return out.reshape(lead + (rows, n)) if lead else out.reshape((rows, n))
+    with obs.scope(obs.JOIN):
+        out = (ore[:total] + 1j * oim[:total]).astype(
+            jnp.result_type(x, jnp.complex64))
+        return out.reshape(lead + (rows, n)) if lead else out.reshape((rows, n))

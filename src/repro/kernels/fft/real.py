@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.kernels.fft.kernel import (bmm_left, compiler_params,
                                       dft_digits, dft_tables, frozen_f32,
                                       hi_dot, split_length, table_specs,
@@ -179,10 +180,11 @@ def _pack_real_rows(x2: jnp.ndarray, block_rows: int
     pairs = (total + 1) // 2
     padded_pairs = (pairs + block_rows - 1) // block_rows * block_rows
     padded_rows = 2 * padded_pairs
-    if padded_rows != total:
-        x2 = jnp.pad(x2, ((0, padded_rows - total), (0, 0)))
-    x2 = x2.astype(jnp.float32)
-    return x2[0::2], x2[1::2], total
+    with obs.scope(obs.SPLIT):
+        if padded_rows != total:
+            x2 = jnp.pad(x2, ((0, padded_rows - total), (0, 0)))
+        x2 = x2.astype(jnp.float32)
+        return x2[0::2], x2[1::2], total
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -208,9 +210,11 @@ def rfft_rows_op(
     ar, ai, br, bi = rfft_rows_pallas(a, b, block_rows=block_rows,
                                       interpret=interpret,
                                       vmem_limit_bytes=limit)
-    spec_a = ar + 1j * ai
-    spec_b = br + 1j * bi
-    # Re-interleave the even/odd row pairs, then crop rows and bins.
-    out = jnp.stack([spec_a, spec_b], axis=1).reshape(-1, n)[:total, :nh]
-    out = out.astype(jnp.result_type(x, jnp.complex64))
-    return out.reshape(lead + (rows, nh)) if lead else out.reshape((rows, nh))
+    with obs.scope(obs.JOIN):
+        spec_a = ar + 1j * ai
+        spec_b = br + 1j * bi
+        # Re-interleave the even/odd row pairs, then crop rows and bins.
+        out = jnp.stack([spec_a, spec_b], axis=1).reshape(-1, n)[:total, :nh]
+        out = out.astype(jnp.result_type(x, jnp.complex64))
+        return (out.reshape(lead + (rows, nh)) if lead
+                else out.reshape((rows, nh)))

@@ -13,6 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.fft.ops import resolve_call_params, rows_to_padded_planes
 from repro.kernels.fused.kernel import fft_rows_transpose_pallas
 
@@ -38,5 +39,6 @@ def fft_rows_transpose_op(
     ore, oim = fft_rows_transpose_pallas(re, im, block_rows=block_rows,
                                          inverse=inverse, interpret=interpret,
                                          vmem_limit_bytes=limit)
-    out = (ore[:, :rows] + 1j * oim[:, :rows])
-    return out.astype(jnp.result_type(x, jnp.complex64))
+    with obs.scope(obs.JOIN):
+        out = (ore[:, :rows] + 1j * oim[:, :rows])
+        return out.astype(jnp.result_type(x, jnp.complex64))

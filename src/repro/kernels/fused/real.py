@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.kernels.fft.kernel import compiler_params, table_specs
 from repro.kernels.fft.ops import resolve_call_params
 from repro.kernels.fft.real import (_pack_real_rows, packed_spectra,
@@ -91,8 +92,9 @@ def rfft_rows_transpose_op(
     ar, ai, br, bi = rfft_rows_transpose_pallas(a, b, block_rows=block_rows,
                                                 interpret=interpret,
                                                 vmem_limit_bytes=limit)
-    spec_a = ar + 1j * ai   # (n, padded_pairs): columns are even rows
-    spec_b = br + 1j * bi   # (n, padded_pairs): columns are odd rows
-    # Re-interleave pair columns, then crop bins (rows here) and columns.
-    out = jnp.stack([spec_a, spec_b], axis=2).reshape(n, -1)[:nh, :total]
-    return out.astype(jnp.result_type(x, jnp.complex64))
+    with obs.scope(obs.JOIN):
+        spec_a = ar + 1j * ai   # (n, padded_pairs): columns are even rows
+        spec_b = br + 1j * bi   # (n, padded_pairs): columns are odd rows
+        # Re-interleave pair columns, then crop bins (rows) and columns.
+        out = jnp.stack([spec_a, spec_b], axis=2).reshape(n, -1)[:nh, :total]
+        return out.astype(jnp.result_type(x, jnp.complex64))

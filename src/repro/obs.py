@@ -1,0 +1,189 @@
+"""Names for the program's phases: device scopes and host spans.
+
+Device scopes.  ``scope(name)`` is ``jax.named_scope(name)``: it only
+changes the HLO metadata (``op_name``) of what is traced inside it, so
+it costs nothing at run time.  The program puts one around the work of
+each phase, where the work happens:
+
+* ``pfft.split``     complex -> f32 (re, im) planes, row padding
+* ``pfft.rowfft``    the row FFTs (Pallas kernels, XLA's ``fft``,
+  Stockham, Bluestein) and the segment gather/scatter around them
+* ``pfft.join``      f32 planes -> complex
+* ``pfft.transpose`` layout moves between phases (transposes, panel
+  cuts and re-interleaves)
+* ``pfft.exchange``  the collectives of a distributed transpose
+
+Attribution rule for a compiled instruction: the innermost ``pfft.*``
+component of its ``op_name`` wins (a fusion carries the ``op_name`` XLA
+copies from its root).  One exception: the TPU compiler lowers
+complex <-> f32-plane conversions to ``X64SplitLow``/``X64SplitHigh``
+and ``X64Combine`` custom calls that take their operand's metadata, not
+the scope they were written in; they are the split and the join by
+definition and map to ``pfft.split`` / ``pfft.join`` wherever they
+appear.  An instruction the compiler or a lowering made on its own (a
+copy, a hoisted constant, a loop's initial state: no ``op_name``, or one
+that ends in a call such as ``jit(f)`` or ``shard_map``) takes the one
+scope all of its users share; the program's result, which nothing uses,
+takes the one scope its operands share.  ``scope_map`` applies the rule
+to compiled HLO text; a plan's ``scope_map()`` applies it to its own
+executable, and so names each op of an xprof trace of that plan (the
+trace names a device op by its instruction) by program phase.
+
+Host spans.  ``span(name)`` enters ``jax.profiler.TraceAnnotation`` (the
+profiler's host plane and clock) and adds its host-clock duration to a
+process-wide table read by ``snapshot()`` and cleared by ``reset()``:
+``{name: {"count", "total_s", "first_s"}}``.  The program's spans are
+``pfft.plan.partition``, ``pfft.plan.schedule`` and ``pfft.execute``
+(the only one per call: one annotation and two clock reads).
+"""
+
+from __future__ import annotations
+
+import re
+import threading
+import time
+import weakref
+
+import jax
+from jax.profiler import TraceAnnotation
+
+__all__ = ["EXCHANGE", "JOIN", "ROWFFT", "SCOPES", "SPLIT", "TRANSPOSE",
+           "compiled_scope_map", "live_scope_map", "register", "reset",
+           "scope", "scope_map", "snapshot", "span"]
+
+SPLIT, ROWFFT, JOIN, TRANSPOSE, EXCHANGE = SCOPES = (
+    "pfft.split", "pfft.rowfft", "pfft.join", "pfft.transpose",
+    "pfft.exchange")
+
+_LOWERED = {"X64SplitLow": SPLIT, "X64SplitHigh": SPLIT,
+            "X64Combine": JOIN}
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_TARGET = re.compile(r'custom_call_target="([^"]*)"')
+_REF = re.compile(r"%([\w.\-]+)")
+_PARAM = re.compile(r"\bparameter\(")
+_CALLS = ("shard_map",)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)``; ``name`` is one of ``SCOPES``."""
+    return jax.named_scope(name)
+
+
+def scope_map(hlo_text: str) -> dict[str, str | None]:
+    """``{instruction name: scope or None}`` of every instruction in
+    compiled HLO text, by the attribution rule above."""
+    out: dict[str, str | None] = {}
+    block: list[tuple[str, str]] = []
+    for line in hlo_text.splitlines() + [""]:
+        m = _INSTR.match(line)
+        if m is not None:
+            block.append((m.group(1), line))
+        elif block:
+            _attribute(block, out)
+            block = []
+    return out
+
+
+def _attribute(block: list[tuple[str, str]], out: dict) -> None:
+    """Scopes of one computation's instructions (in text order, which
+    puts every user after its operands)."""
+    names = {name for name, _ in block}
+    operands: dict[str, list[str]] = {}
+    users: dict[str, list[str]] = {name: [] for name in names}
+    made = []
+    for name, line in block:
+        rhs = line.split(" = ", 1)[1]
+        operands[name] = [r for r in _REF.findall(rhs)
+                          if r in names and r != name]
+        for ref in operands[name]:
+            users[ref].append(name)
+        target = _TARGET.search(line)
+        found = _LOWERED.get(target.group(1)) if target else None
+        op = _OP_NAME.search(line)
+        parts = op.group(1).split("/") if op else []
+        if found is None:
+            found = next((p for p in reversed(parts) if p in SCOPES), None)
+        out[name] = found
+        if found is None and not _PARAM.search(rhs) and (
+                not parts or "(" in parts[-1] or parts[-1] in _CALLS):
+            made.append(name)
+    for name in reversed(made):
+        found = {out[u] for u in users[name]}
+        if len(found) == 1:
+            out[name] = found.pop()
+    for name in made:
+        if not users[name]:
+            found = {out[o] for o in operands[name]}
+            if len(found) == 1:
+                out[name] = found.pop()
+
+
+def compiled_scope_map(fn, spec) -> dict[str, str | None]:
+    """``scope_map`` of the executable ``fn`` (a jitted function) runs
+    for inputs like ``spec`` (a ``jax.ShapeDtypeStruct``)."""
+    return scope_map(fn.lower(spec).compile().as_text())
+
+
+# Live plans, by id; a plan leaves when it is collected.  (Plans are
+# dataclasses with value equality, hence unhashable: no WeakSet.)
+_LIVE: weakref.WeakValueDictionary = weakref.WeakValueDictionary()
+
+
+def register(plan) -> None:
+    """Track ``plan`` (anything with ``scope_map()``) while it lives."""
+    _LIVE[id(plan)] = plan
+
+
+def live_scope_map() -> dict[str, str | None]:
+    """The merged ``scope_map()`` of every live plan; an instruction
+    name two plans map to different scopes maps to None."""
+    merged: dict[str, str | None] = {}
+    for plan in list(_LIVE.values()):
+        for name, found in plan.scope_map().items():
+            merged[name] = (found if merged.get(name, found) == found
+                            else None)
+    return merged
+
+
+_SPANS: dict[str, dict] = {}
+_LOCK = threading.Lock()
+
+
+class span:
+    """Context manager: a profiler host span and a host-clock record."""
+
+    __slots__ = ("name", "_ann", "_t0")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+
+    def __enter__(self) -> "span":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        with _LOCK:
+            rec = _SPANS.get(self.name)
+            if rec is None:
+                _SPANS[self.name] = {"count": 1, "total_s": dt,
+                                     "first_s": dt}
+            else:
+                rec["count"] += 1
+                rec["total_s"] += dt
+
+
+def snapshot() -> dict[str, dict]:
+    """A copy of the span table."""
+    with _LOCK:
+        return {k: dict(v) for k, v in _SPANS.items()}
+
+
+def reset() -> None:
+    """Clear the span table."""
+    with _LOCK:
+        _SPANS.clear()
