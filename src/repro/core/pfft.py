@@ -240,14 +240,12 @@ def _pfft_limb(m: jnp.ndarray, d: np.ndarray, *, pad_lengths=None,
     if (common is not None and common.fused
             and all(e.length == schedule.n for e in schedule)):
         # Segmentation without padding is purely a scheduling notion, so
-        # the whole-matrix fused phase computes the identical value.
-        # fft_rows_then_transpose itself falls back to the unfused
-        # computation when the kernel doesn't apply (non-pow2 N,
-        # dtypes wider than the f32 planes).
-        from repro.fft.fft2d import fft_rows_then_transpose
-        m = fft_rows_then_transpose(m)
-        m = fft_rows_then_transpose(m)
-        return m
+        # the whole-matrix fused transform computes the identical value.
+        # fft2d_fused itself falls back to the unfused computation when
+        # the kernel doesn't apply (non-pow2 N, dtypes wider than the
+        # f32 planes).
+        from repro.fft.fft2d import fft2d_fused
+        return fft2d_fused(m)
     for _ in range(2):
         m = segment_row_ffts(m, d, schedule=schedule)
         with obs.scope(obs.TRANSPOSE):

@@ -7,18 +7,31 @@ direct 2-D DFT to O(N^2 log N).
 ``fused=True`` collapses each (row FFT, transpose) pair into one Pallas
 dispatch (``repro.kernels.fused``): the transformed row block is written
 straight to its transposed tile, so the intermediate HBM matrix between
-steps 1-2 and 3-4 never exists.  See DESIGN.md §Fused pipeline.
+steps 1-2 and 3-4 never exists.  ``fft2d_fused`` runs the two fused
+phases on f32 planes, converting complex <-> planes once at each end.
+See DESIGN.md §Fused pipeline.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro import obs
 from repro.fft.fft1d import fft1d_stockham
 
-__all__ = ["fft2d_rowcol", "fft_rows", "fft_rows_then_transpose",
-           "irfft2", "rfft2", "rfft_rows", "rfft_rows_then_transpose"]
+__all__ = ["fft2d_fused", "fft2d_rowcol", "fft_rows",
+           "fft_rows_then_transpose", "irfft2", "rfft2", "rfft_rows",
+           "rfft_rows_then_transpose"]
+
+
+def _fusable(m: jnp.ndarray, *lengths: int) -> bool:
+    """Whether fused phases of these row lengths run the Pallas kernel on
+    ``m``: 2-D input, power-of-two lengths above 1, single-precision data
+    (the kernel computes in f32 planes, so wider dtypes keep the
+    full-precision path)."""
+    return (m.ndim == 2 and all(n > 1 and not (n & (n - 1)) for n in lengths)
+            and jnp.result_type(m, jnp.complex64) == jnp.complex64)
 
 
 def fft_rows(m: jnp.ndarray, *, use_stockham: bool = False,
@@ -45,22 +58,38 @@ def fft_rows_then_transpose(m: jnp.ndarray, *,
                             backend: str | None = None) -> jnp.ndarray:
     """One fused phase: ``FFT_rows(m).T`` without the intermediate matrix.
 
-    Dispatches to the fused Pallas kernel when it applies (2-D input,
-    power-of-two row length, single-precision data — the kernel computes
-    in f32 planes, so wider dtypes keep the full-precision path);
+    Dispatches to the fused Pallas kernel when it applies (``_fusable``);
     otherwise computes the same value as ``fft_rows`` + ``swapaxes`` so
     callers can use it unconditionally.
     """
-    n = m.shape[-1]
-    eligible = (m.ndim == 2 and n > 1 and not (n & (n - 1))
-                and jnp.result_type(m, jnp.complex64) == jnp.complex64)
-    if eligible and backend in (None, "pallas", "fused"):
+    if _fusable(m, m.shape[-1]) and backend in (None, "pallas", "fused"):
         from repro.kernels.fused.ops import fft_rows_transpose_op
         with obs.scope(obs.ROWFFT):
             return fft_rows_transpose_op(m)
     out = fft_rows(m, backend=backend)
     with obs.scope(obs.TRANSPOSE):
         return out.swapaxes(-1, -2)
+
+
+def fft2d_fused(m: jnp.ndarray) -> jnp.ndarray:
+    """2-D DFT as two fused phases, with f32 planes passed between them.
+
+    Splits ``m`` into (re, im) planes once, runs the plane form of the
+    fused op for each phase (phase 1's output planes are phase 2's
+    input) and joins once, so nothing runs between the two kernels.
+    Each axis is one phase's row length, so both must be ``_fusable``;
+    otherwise the value of two ``fft_rows_then_transpose`` calls.
+    """
+    if not _fusable(m, *m.shape):
+        return fft_rows_then_transpose(fft_rows_then_transpose(m))
+    from repro.kernels.fft.ops import rows_to_padded_planes
+    from repro.kernels.fused.ops import fft_rows_transpose_op
+    re, im, _ = rows_to_padded_planes(m, 1)  # each op pads to its block
+    with obs.scope(obs.ROWFFT):
+        planes = fft_rows_transpose_op((re, im))     # steps 1+2
+        planes = fft_rows_transpose_op(planes)       # steps 3+4
+    with obs.scope(obs.JOIN):
+        return jax.lax.complex(*planes)
 
 
 def _packed_rfft(m: jnp.ndarray, fft_fn) -> jnp.ndarray:
@@ -111,14 +140,10 @@ def rfft_rows_then_transpose(m: jnp.ndarray, *,
                              backend: str | None = None) -> jnp.ndarray:
     """One fused real phase: ``rfft_rows(m).T`` without the intermediate.
 
-    Eligibility mirrors ``fft_rows_then_transpose`` (2-D input,
-    power-of-two row length, f32-representable data); otherwise the
-    unfused value, so callers can use it unconditionally.
+    Eligibility is ``fft_rows_then_transpose``'s (``_fusable``);
+    otherwise the unfused value, so callers can use it unconditionally.
     """
-    n = m.shape[-1]
-    eligible = (m.ndim == 2 and n > 1 and not (n & (n - 1))
-                and jnp.result_type(m, jnp.complex64) == jnp.complex64)
-    if eligible and backend in (None, "pallas", "fused"):
+    if _fusable(m, m.shape[-1]) and backend in (None, "pallas", "fused"):
         from repro.kernels.fused.real import rfft_rows_transpose_op
         with obs.scope(obs.ROWFFT):
             return rfft_rows_transpose_op(m)
@@ -168,9 +193,7 @@ def fft2d_rowcol(m: jnp.ndarray, *, use_stockham: bool = False,
     (numerically equivalent; no intermediate HBM matrix).
     """
     if fused:
-        m = fft_rows_then_transpose(m)              # steps 1+2
-        m = fft_rows_then_transpose(m)              # steps 3+4
-        return m
+        return fft2d_fused(m)
     m = fft_rows(m, use_stockham=use_stockham)      # step 1
     m = m.swapaxes(-1, -2)                          # step 2
     m = fft_rows(m, use_stockham=use_stockham)      # step 3

@@ -126,15 +126,23 @@ def resolve_call_params(kind: str, n: int, rows: int,
     return block_rows, interpret, limit
 
 
-def rows_to_padded_planes(x2: jnp.ndarray, block_rows: int
+def rows_to_padded_planes(x2, block_rows: int
                           ) -> tuple[jnp.ndarray, jnp.ndarray, int]:
-    """(rows, n) complex -> f32 (re, im) planes row-padded to the block
-    multiple, plus the original row count for cropping the result."""
-    total = x2.shape[0]
-    padded = (total + block_rows - 1) // block_rows * block_rows
+    """(rows, n) complex, or an ``(re, im)`` pair of f32 planes -> f32
+    (re, im) planes row-padded to the block multiple, plus the original
+    row count for cropping the result."""
+    planes = isinstance(x2, tuple)
+    total = (x2[0] if planes else x2).shape[0]
+    extra = -total % block_rows
+    pad = ((0, extra), (0, 0))
     with obs.scope(obs.SPLIT):
-        if padded != total:
-            x2 = jnp.pad(x2, ((0, padded - total), (0, 0)))
+        if planes:
+            re, im = x2
+            if extra:
+                re, im = jnp.pad(re, pad), jnp.pad(im, pad)
+            return re, im, total
+        if extra:
+            x2 = jnp.pad(x2, pad)
         return (jnp.real(x2).astype(jnp.float32),
                 jnp.imag(x2).astype(jnp.float32), total)
 
@@ -159,6 +167,6 @@ def fft_rows_op(
     ore, oim = fft_rows_pallas(re, im, block_rows=block_rows, inverse=inverse,
                                interpret=interpret, vmem_limit_bytes=limit)
     with obs.scope(obs.JOIN):
-        out = (ore[:total] + 1j * oim[:total]).astype(
+        out = jax.lax.complex(ore[:total], oim[:total]).astype(
             jnp.result_type(x, jnp.complex64))
         return out.reshape(lead + (rows, n)) if lead else out.reshape((rows, n))

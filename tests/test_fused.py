@@ -6,9 +6,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core.pfft import (plan_segment_batches, pfft_lb,
+from repro.core.pfft import (_pfft_limb, plan_segment_batches, pfft_lb,
                              segment_row_ffts)
-from repro.fft.fft2d import fft2d_rowcol, fft_rows_then_transpose
+from repro.fft.fft2d import (fft2d_fused, fft2d_rowcol,
+                             fft_rows_then_transpose)
 from repro.plan import PlanConfig
 from repro.kernels.fft.kernel import (dft_digits, dft_planes, dft_tables,
                                       split_length, to_natural, to_transposed)
@@ -119,6 +120,12 @@ def test_fused_op_vs_unfused(rng, rows, n):
     assert out.shape == (n, rows)
     ref = jnp.fft.fft(x, axis=-1).T
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-3)
+    # The plane form runs the same kernel: the complex form's planes, exactly.
+    ore, oim = fft_rows_transpose_op((jnp.real(x), jnp.imag(x)),
+                                     interpret=True)
+    assert ore.dtype == oim.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(ore), np.asarray(out.real))
+    np.testing.assert_array_equal(np.asarray(oim), np.asarray(out.imag))
 
 
 def test_fused_op_rejects_bad_inputs():
@@ -126,6 +133,9 @@ def test_fused_op_rejects_bad_inputs():
         fft_rows_transpose_op(jnp.ones((4, 12), jnp.complex64), interpret=True)
     with pytest.raises(ValueError):
         fft_rows_transpose_op(jnp.ones((2, 4, 8), jnp.complex64),
+                              interpret=True)
+    with pytest.raises(ValueError, match="one shape"):
+        fft_rows_transpose_op((jnp.ones((4, 8)), jnp.ones((4, 16))),
                               interpret=True)
 
 
@@ -143,6 +153,39 @@ def test_fft2d_fused_vs_unfused_equivalence(rng, dtype, n):
                                atol=tol)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(jnp.fft.fft2(m)),
                                atol=tol)
+
+
+@pytest.mark.parametrize("n", [64, 256])
+def test_fft2d_fused_equals_op_composition(rng, n):
+    """Planes carried between the phases give the array the complex op
+    gives twice (the join and re-split between them is exact)."""
+    m = csignal(rng, n, n)
+    planes = fft2d_fused(m)
+    composed = fft_rows_transpose_op(fft_rows_transpose_op(m))
+    assert planes.dtype == composed.dtype == jnp.complex64
+    np.testing.assert_array_equal(np.asarray(planes), np.asarray(composed))
+    ref = np.fft.fft2(np.asarray(m, np.complex128))
+    for out in (planes, composed):
+        np.testing.assert_allclose(np.asarray(out), ref, atol=1e-2 * n ** 0.5)
+
+
+@pytest.mark.parametrize("shape", [(4, 32), (8, 32), (6, 32), (32, 12)])
+def test_fft2d_fused_shapes(rng, shape):
+    """Rectangular input (phase 2's row length is the row count), padded
+    blocks, and lengths the kernel does not take."""
+    m = csignal(rng, *shape)
+    out = fft2d_fused(m)
+    assert out.shape == shape
+    np.testing.assert_allclose(np.asarray(out),
+                               np.fft.fft2(np.asarray(m, np.complex128)),
+                               atol=1e-3)
+
+
+def test_pfft_limb_fused_equals_fft2d_fused(rng):
+    m = csignal(rng, 64, 64)
+    limb = _pfft_limb(m, np.array([20, 24, 20]), config=PlanConfig(fused=True))
+    np.testing.assert_array_equal(np.asarray(limb),
+                                  np.asarray(fft2d_rowcol(m, fused=True)))
 
 
 def test_fused_phase_fallbacks(rng):

@@ -67,3 +67,21 @@ def test_kernel_compiles_for_v5e(one_chip, no_persistent_cache, kind, n):
         return
     compiled = lowered.lower(x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_2d_program_for_v5e(one_chip, no_persistent_cache, monkeypatch):
+    """The fused 2-D transform carries f32 planes between its two kernels:
+    one split at the input, one ``X64Combine`` at the output, no join
+    between the phases, and the kernels keep the op wrapper's name (the
+    benchmark's row-FFT reader finds them by it)."""
+    import repro.kernels.fft.ops as fft_ops
+    from repro.fft.fft2d import fft2d_fused
+    monkeypatch.setattr(fft_ops, "_on_cpu", lambda: False)
+    x = jax.ShapeDtypeStruct((1024, 1024), jnp.complex64, sharding=one_chip)
+    text = jax.jit(fft2d_fused).lower(x).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert all(ln.split("=")[0].strip().lstrip("%").startswith(
+        "fft_rows_transpose_op") for ln in kernels)
+    assert text.count('custom_call_target="X64Combine"') == 1
+    assert "multiply_add" not in text
