@@ -140,26 +140,38 @@ def _build_raw(n: int, method: Method, d: np.ndarray,
 
 class _Scoped:
     """What every plan type shares with ``repro.obs``: a plan is tracked
-    while it lives, and names the instructions of its own executable."""
+    while it lives, and names and counts the instructions of its own
+    executable."""
 
     def __post_init__(self) -> None:
         obs.register(self)
 
-    def scope_map(self) -> dict[str, str | None]:
-        """``{instruction name: pfft.* scope or None}`` of the executable
-        ``execute`` runs on one planned input (``repro.obs.scope_map``).
+    def _compiled(self) -> tuple[dict[str, str | None], dict[str, int]]:
+        """``(scope_map, exchange counts)`` of the executable ``execute``
+        runs on one planned input.
 
         Lowers the plan's function on ``input_spec()`` (one planned
         input, laid out as planned) and compiles it, which the compile
         cache serves once the plan has run; computed once per plan,
-        never per call.  The names are those a profiler trace gives the
-        device ops, so the map reads an xprof trace of this plan by
-        program phase."""
-        found = getattr(self, "_scopes", None)
+        never per call."""
+        found = getattr(self, "_analysis", None)
         if found is None:
-            found = self._scopes = obs.compiled_scope_map(
-                self._fn, self.input_spec())
+            text = obs.compiled_text(self._fn, self.input_spec())
+            found = self._analysis = (obs.scope_map(text),
+                                      obs.exchange_counts(text))
         return found
+
+    def scope_map(self) -> dict[str, str | None]:
+        """``{instruction name: pfft.* scope or None}`` of the plan's
+        executable (``repro.obs.scope_map``).  The names are those a
+        profiler trace gives the device ops, so the map reads an xprof
+        trace of this plan by program phase."""
+        return self._compiled()[0]
+
+    def counters(self) -> dict[str, int]:
+        """The exchange counters of one transform of the plan's
+        executable (``repro.obs.exchange_counts``; none on one chip)."""
+        return self._compiled()[1]
 
 
 def _sharded(shape, dtype, mesh, spec) -> jax.ShapeDtypeStruct:

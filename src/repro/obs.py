@@ -35,10 +35,31 @@ process-wide table read by ``snapshot()`` and cleared by ``reset()``:
 ``{name: {"count", "total_s", "first_s"}}``.  The program's spans are
 ``pfft.plan.partition``, ``pfft.plan.schedule`` and ``pfft.execute``
 (the only one per call: one annotation and two clock reads).
+
+Counters.  A process-wide table ``{name: count}`` beside the span
+table, read by ``counters()`` and cleared by ``reset()``; ``count``
+records one.  The program's counters describe one transform of a live
+plan's executable: a plan's ``counters()`` reads them from the same
+compiled text as its ``scope_map()`` (``exchange_counts``), never from
+tuning candidates and never per call, and ``live_counters()`` merges
+those of every live plan into the table.
+
+* ``pfft.exchange.collectives``  the ``all-to-all`` instructions the
+  executable holds (a sync op or an async ``-done``; the TPU compiler
+  carries a complex exchange as two f32 collectives, re and im)
+* ``pfft.exchange.bytes``  the bytes each device sends off the device
+  through them: a collective over a group of g devices keeps 1/g of its
+  result where it is
+
+Both count instructions, not executions: they are per transform only
+while every exchange runs once a call, as in the slab pipeline, whose
+panel loop is unrolled.  A plan whose executable holds no
+``all-to-all`` (one chip) has none.
 """
 
 from __future__ import annotations
 
+import math
 import re
 import threading
 import time
@@ -47,9 +68,10 @@ import weakref
 import jax
 from jax.profiler import TraceAnnotation
 
-__all__ = ["EXCHANGE", "JOIN", "ROWFFT", "SCOPES", "SPLIT", "TRANSPOSE",
-           "compiled_scope_map", "live_scope_map", "register", "reset",
-           "scope", "scope_map", "snapshot", "span"]
+__all__ = ["COLLECTIVES", "EXCHANGE", "EXCHANGE_BYTES", "JOIN", "ROWFFT",
+           "SCOPES", "SPLIT", "TRANSPOSE", "compiled_text", "count",
+           "counters", "exchange_counts", "live_counters", "live_scope_map",
+           "register", "reset", "scope", "scope_map", "snapshot", "span"]
 
 SPLIT, ROWFFT, JOIN, TRANSPOSE, EXCHANGE = SCOPES = (
     "pfft.split", "pfft.rowfft", "pfft.join", "pfft.transpose",
@@ -63,6 +85,17 @@ _TARGET = re.compile(r'custom_call_target="([^"]*)"')
 _REF = re.compile(r"%([\w.\-]+)")
 _PARAM = re.compile(r"\bparameter\(")
 _CALLS = ("shard_map",)
+
+COLLECTIVES, EXCHANGE_BYTES = ("pfft.exchange.collectives",
+                               "pfft.exchange.bytes")
+_A2A = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*?) all-to-all(-start|-done)?\(")
+_ARRAY = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+             "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+             "u64": 8, "c64": 8, "c128": 16}
+_GROUPS = re.compile(r"replica_groups=(?:\{\{([\d,]*)\}|\[\d+,(\d+)\])")
+_PARTITIONS = re.compile(r"\bnum_partitions=(\d+)")
 
 
 def scope(name: str):
@@ -119,10 +152,44 @@ def _attribute(block: list[tuple[str, str]], out: dict) -> None:
                 out[name] = found.pop()
 
 
-def compiled_scope_map(fn, spec) -> dict[str, str | None]:
-    """``scope_map`` of the executable ``fn`` (a jitted function) runs
+def compiled_text(fn, spec) -> str:
+    """The HLO text of the executable ``fn`` (a jitted function) runs
     for inputs like ``spec`` (a ``jax.ShapeDtypeStruct``)."""
-    return scope_map(fn.lower(spec).compile().as_text())
+    return fn.lower(spec).compile().as_text()
+
+
+def exchange_counts(hlo_text: str) -> dict[str, int]:
+    """``{COLLECTIVES: k, EXCHANGE_BYTES: b}`` of the ``all-to-all``
+    instructions in compiled HLO text (see the module docstring), or
+    ``{}`` where it has none."""
+    found = _PARTITIONS.search(hlo_text)
+    default = int(found.group(1)) if found else 1
+    started: dict[str, int] = {}     # an async start's group size
+    k = sent = 0
+    for line in hlo_text.splitlines():
+        m = _A2A.match(line)
+        if m is None:
+            continue
+        name, result, kind = m.groups()
+        g = _GROUPS.search(line)
+        if g is None:
+            size = default
+        elif g.group(2):
+            size = int(g.group(2))
+        else:
+            size = len(g.group(1).split(","))
+        if kind == "-start":
+            started[name] = size
+            continue
+        if kind == "-done":
+            ref = _REF.search(line, m.end())
+            size = started.get(ref.group(1) if ref else "", default)
+        nbytes = sum(_ITEMSIZE[dt] * math.prod(int(d) for d in dims.split(",")
+                                               if d)
+                     for dt, dims in _ARRAY.findall(result))
+        k += 1
+        sent += nbytes * (size - 1) // size
+    return {COLLECTIVES: k, EXCHANGE_BYTES: sent} if k else {}
 
 
 # Live plans, by id; a plan leaves when it is collected.  (Plans are
@@ -146,7 +213,19 @@ def live_scope_map() -> dict[str, str | None]:
     return merged
 
 
+def live_counters() -> dict[str, int]:
+    """``counters()`` after recording the merged ``counters()`` of every
+    live plan (where two differ, the later-made plan's)."""
+    merged: dict[str, int] = {}
+    for plan in list(_LIVE.values()):
+        merged.update(getattr(plan, "counters", dict)())
+    for name, value in merged.items():
+        count(name, value)
+    return counters()
+
+
 _SPANS: dict[str, dict] = {}
+_COUNTERS: dict[str, int] = {}
 _LOCK = threading.Lock()
 
 
@@ -183,7 +262,20 @@ def snapshot() -> dict[str, dict]:
         return {k: dict(v) for k, v in _SPANS.items()}
 
 
+def count(name: str, value: int) -> None:
+    """Record ``value`` as the counter ``name`` (the latest one stands)."""
+    with _LOCK:
+        _COUNTERS[name] = value
+
+
+def counters() -> dict[str, int]:
+    """A copy of the counter table."""
+    with _LOCK:
+        return dict(_COUNTERS)
+
+
 def reset() -> None:
-    """Clear the span table."""
+    """Clear the span and counter tables."""
     with _LOCK:
         _SPANS.clear()
+        _COUNTERS.clear()

@@ -188,3 +188,73 @@ def test_other_plan_types_execute_in_a_span():
     for plan in (p3, p1):
         found = plan.scope_map()
         assert obs.ROWFFT in set(found.values())
+
+
+A2A_HLO = """\
+HloModule jit_raw, is_scheduled=true, num_partitions=4
+
+ENTRY %main (x.1: f32[64,4,32]) -> f32[64,4,32] {
+  %x.1 = f32[64,4,32]{2,1,0} parameter(0)
+  %all_to_all.2 = f32[64,4,32]{2,0,1:T(8,128)} all-to-all(%x.1), channel_id=1, replica_groups={{0,1,2,3}}, dimensions={1}, metadata={op_name="jit(raw)/shard_map/pfft.exchange/all_to_all"}
+  %all-to-all.3 = (c64[16,8]{1,0}, c64[16,8]{1,0}, c64[16,8]{1,0}, c64[16,8]{1,0}) all-to-all(%a, %b, %c, %d), channel_id=2, replica_groups=[1,4]<=[4]
+  %all-to-all-start.4 = ((f32[8,8]{1,0}), f32[8,8]{1,0}) all-to-all-start(%x.1), replica_groups={{0,1},{2,3}}
+  %all-to-all-done.4 = f32[8,8]{1,0} all-to-all-done(%all-to-all-start.4)
+  %all-to-all.5 = f32[16]{0} all-to-all(%y), dimensions={0}
+  %gte.6 = c64[16,8]{1,0} get-tuple-element(%all-to-all.3), index=0
+  ROOT %copy.7 = f32[64,4,32]{2,1,0} copy(%all_to_all.2)
+}
+"""
+
+
+def test_exchange_counts_read_every_form_of_all_to_all():
+    got = obs.exchange_counts(A2A_HLO)
+    sync = 64 * 4 * 32 * 4 * 3 // 4         # f32, 3 of 4 parts leave
+    tup = 4 * 16 * 8 * 8 * 3 // 4           # c64 tuple, iota groups of 4
+    async_ = 8 * 8 * 4 // 2                 # counted at its -done, pairs
+    default = 16 * 4 * 3 // 4               # no groups: num_partitions
+    assert got == {obs.COLLECTIVES: 4,
+                   obs.EXCHANGE_BYTES: sync + tup + async_ + default}
+    assert obs.exchange_counts(HLO) == {}
+
+
+def test_counter_table_records_reads_and_resets():
+    obs.reset()
+    obs.count("a", 3)
+    obs.count("a", 5)                       # the latest stands
+    got = obs.counters()
+    assert got == {"a": 5}
+    got["a"] = 99                           # a copy
+    assert obs.counters() == {"a": 5}
+    with obs.span("s"):
+        pass
+    obs.reset()
+    assert obs.counters() == {} and obs.snapshot() == {}
+
+
+class _CountingPlan(_FakePlan):
+    def __init__(self, counts):
+        super().__init__({})
+        self.counts = counts
+
+    def counters(self):
+        return self.counts
+
+
+def test_live_counters_ask_every_live_plan():
+    obs.reset()
+    plain = _FakePlan({})                   # no counters at all
+    counting = _CountingPlan({obs.EXCHANGE_BYTES: 7})
+    obs.register(plain)
+    obs.register(counting)
+    assert obs.counters() == {}             # nothing recorded yet
+    assert obs.live_counters()[obs.EXCHANGE_BYTES] == 7
+    obs.reset()
+    assert obs.live_counters()[obs.EXCHANGE_BYTES] == 7
+    del plain, counting
+
+
+def test_one_chip_plan_has_no_exchange_counters():
+    obs.reset()
+    plan = plan_pfft(N, method="lb", p=4, config=PlanConfig(radix=4))
+    assert plan.counters() == {}
+    assert obs.counters() == {}

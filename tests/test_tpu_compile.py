@@ -31,14 +31,18 @@ ROWS = 256
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -85,3 +89,26 @@ def test_fused_2d_program_for_v5e(one_chip, no_persistent_cache, monkeypatch):
         "fft_rows_transpose_op") for ln in kernels)
     assert text.count('custom_call_target="X64Combine"') == 1
     assert "multiply_add" not in text
+
+
+def test_slab_exchange_counts_for_v5e_2x2(topo, no_persistent_cache,
+                                          monkeypatch):
+    """The four-chip cell's program at N = 32768, as the chip plans it
+    (8 panels), compiled for a described 2x2.  The TPU compiler carries
+    each complex64 exchange as two f32 all-to-alls (re and im), so its 2
+    phases x 8 panels count 32 collectives; each device sends 2 x 3/4 of
+    its 8 N^2/4 bytes off the device all the same."""
+    import numpy as np
+    from jax.sharding import Mesh
+    import repro.kernels.fft.ops as fft_ops
+    from repro import obs
+    from repro.core import plan_pfft
+    from repro.plan.config import PlanConfig
+    monkeypatch.setattr(fft_ops, "_on_cpu", lambda: False)
+    n = 32768
+    plan = plan_pfft(n, method="lb", mesh=Mesh(np.array(topo.devices),
+                                                ("fft",)),
+                     config=PlanConfig(radix=4, batched=True,
+                                       pipeline_panels=8))
+    assert plan.counters() == {obs.COLLECTIVES: 32,
+                               obs.EXCHANGE_BYTES: 2 * 3 * 8 * n * n // 16}
