@@ -147,8 +147,8 @@ class _Scoped:
         obs.register(self)
 
     def _compiled(self) -> tuple[dict[str, str | None], dict[str, int]]:
-        """``(scope_map, exchange counts)`` of the executable ``execute``
-        runs on one planned input.
+        """``(scope_map, counters)`` of the executable ``execute`` runs on
+        one planned input.
 
         Lowers the plan's function on ``input_spec()`` (one planned
         input, laid out as planned) and compiles it, which the compile
@@ -157,8 +157,10 @@ class _Scoped:
         found = getattr(self, "_analysis", None)
         if found is None:
             text = obs.compiled_text(self._fn, self.input_spec())
-            found = self._analysis = (obs.scope_map(text),
-                                      obs.exchange_counts(text))
+            scopes = obs.scope_map(text)
+            found = self._analysis = (
+                scopes, {**obs.exchange_counts(text),
+                         **obs.sparse_tile_counts(text, scopes)})
         return found
 
     def scope_map(self) -> dict[str, str | None]:
@@ -169,8 +171,9 @@ class _Scoped:
         return self._compiled()[0]
 
     def counters(self) -> dict[str, int]:
-        """The exchange counters of one transform of the plan's
-        executable (``repro.obs.exchange_counts``; none on one chip)."""
+        """The counters of one transform of the plan's executable:
+        ``repro.obs.exchange_counts`` (none on one chip) and
+        ``repro.obs.sparse_tile_counts`` (none on the CPU)."""
         return self._compiled()[1]
 
 

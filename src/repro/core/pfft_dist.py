@@ -340,7 +340,7 @@ def _local_phase(block: jnp.ndarray, axis_name: str, n: int, *,
     # Software pipeline: FFT panel 0; then alternate (issue all_to_all of
     # panel i, FFT panel i+1) so each exchange overlaps the next FFT.
     # Fused panels exchange transposed (see above); their gathered tiles
-    # arrive already column-major, saving the per-panel transpose below.
+    # arrive already column-major, saving the transpose of the interleave.
     gathered = []
     current = fft_t(panel(0)) if fused else fft(panel(0))
     exchange = a2a_t if fused else a2a
@@ -351,18 +351,33 @@ def _local_phase(block: jnp.ndarray, axis_name: str, n: int, *,
         gathered.append(in_flight)
     gathered.append(exchange(current))
 
-    # Unfused: each g_i is (N/k, N/p): peer-major stack of that peer's
-    # panel-i rows, column slice j.  Transposed, its columns are global
-    # rows q*n_loc + i*c + r (q peer-major, r in-panel).  Fused tiles are
-    # already transposed, (N/p, N/k).  Interleave panels so output
-    # columns are in global row order, matching the monolithic path.
     with obs.scope(obs.TRANSPOSE):
-        tiles = [g if fused else g.T for g in gathered]  # (rows_out, n_loc/k)
-        rows_out = tiles[0].shape[0]
-        p = tiles[0].shape[1] * k // n_loc if n_loc else 1
-        panels_t = [t.reshape(rows_out, p, c) for t in tiles]
-        out = jnp.stack(panels_t, axis=2)      # (rows_out, p, k, c)
-        return out.reshape(rows_out, p * k * c)
+        return _interleave_panels(gathered, c=c, fused=fused)
+
+
+def _interleave_panels(gathered: list[jnp.ndarray], *, c: int,
+                       fused: bool) -> jnp.ndarray:
+    """The k exchanged panels of a pipelined phase as the monolithic
+    phase's output ``(rows_out, N)``: output column ``q*n_loc + i*c + r``
+    is row ``r`` of peer ``q``'s panel ``i``.
+
+    Unfused, each ``g_i`` is ``(p*c, rows_out)``, the peer-major stack of
+    every peer's panel-i rows; fused tiles arrive transposed, ``(rows_out,
+    p*c)``.  Each panel is cut into its p peer blocks, the p*k blocks are
+    joined in output order along the rows (unfused) or columns (fused),
+    and the unfused result is transposed once.  The blocks stay 2-D and
+    dense: a stack on a new second-minor axis tiles each panel one
+    sublane of eight high on a TPU, and a 4-D transpose of the stacked
+    panels lets the compiler move the last phase's transpose past the
+    complex join, as a complex64 copy.
+    """
+    if fused:
+        p = gathered[0].shape[1] // c
+        return jnp.concatenate([g[:, q * c:(q + 1) * c] for q in range(p)
+                                for g in gathered], axis=1)
+    p = gathered[0].shape[0] // c
+    return jnp.concatenate([g[q * c:(q + 1) * c] for q in range(p)
+                            for g in gathered]).T
 
 
 def validate_spmd_schedule(schedule: SegmentSchedule,

@@ -50,11 +50,16 @@ those of every live plan into the table.
 * ``pfft.exchange.bytes``  the bytes each device sends off the device
   through them: a collective over a group of g devices keeps 1/g of its
   result where it is
+* ``pfft.transpose.sparse_tiles``  the instructions of a ``pfft.*``
+  scope whose result, 1 MiB or more, is laid out in tiles one row high
+  (``T(1,...)``): on a TPU such an array fills one sublane of the eight
+  of each vreg, so moving it costs up to eight times its bytes
 
-Both count instructions, not executions: they are per transform only
-while every exchange runs once a call, as in the slab pipeline, whose
-panel loop is unrolled.  A plan whose executable holds no
-``all-to-all`` (one chip) has none.
+All three count instructions, not executions: they are per transform
+only while every exchange runs once a call, as in the slab pipeline,
+whose panel loop is unrolled.  A plan whose executable holds no
+``all-to-all`` (one chip) has no exchange counters; an executable whose
+text carries no tiled layouts (the CPU's) has no ``sparse_tiles``.
 """
 
 from __future__ import annotations
@@ -69,9 +74,10 @@ import jax
 from jax.profiler import TraceAnnotation
 
 __all__ = ["COLLECTIVES", "EXCHANGE", "EXCHANGE_BYTES", "JOIN", "ROWFFT",
-           "SCOPES", "SPLIT", "TRANSPOSE", "compiled_text", "count",
-           "counters", "exchange_counts", "live_counters", "live_scope_map",
-           "register", "reset", "scope", "scope_map", "snapshot", "span"]
+           "SCOPES", "SPARSE_TILES", "SPLIT", "TRANSPOSE", "compiled_text",
+           "count", "counters", "exchange_counts", "live_counters",
+           "live_scope_map", "register", "reset", "scope", "scope_map",
+           "snapshot", "span", "sparse_tile_counts"]
 
 SPLIT, ROWFFT, JOIN, TRANSPOSE, EXCHANGE = SCOPES = (
     "pfft.split", "pfft.rowfft", "pfft.join", "pfft.transpose",
@@ -94,6 +100,9 @@ _ARRAY = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
 _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
              "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
              "u64": 8, "c64": 8, "c128": 16}
+SPARSE_TILES = "pfft.transpose.sparse_tiles"
+_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*?) [\w\-]+\(")
+_SPARSE_MIN_BYTES = 1 << 20
 _GROUPS = re.compile(r"replica_groups=(?:\{\{([\d,]*)\}|\[\d+,(\d+)\])")
 _PARTITIONS = re.compile(r"\bnum_partitions=(\d+)")
 
@@ -184,12 +193,34 @@ def exchange_counts(hlo_text: str) -> dict[str, int]:
         if kind == "-done":
             ref = _REF.search(line, m.end())
             size = started.get(ref.group(1) if ref else "", default)
-        nbytes = sum(_ITEMSIZE[dt] * math.prod(int(d) for d in dims.split(",")
-                                               if d)
-                     for dt, dims in _ARRAY.findall(result))
         k += 1
-        sent += nbytes * (size - 1) // size
+        sent += _nbytes(result) * (size - 1) // size
     return {COLLECTIVES: k, EXCHANGE_BYTES: sent} if k else {}
+
+
+def sparse_tile_counts(hlo_text: str,
+                       scopes: dict[str, str | None]) -> dict[str, int]:
+    """``{SPARSE_TILES: k}`` of compiled HLO text whose ``scope_map`` is
+    ``scopes`` (see the module docstring), or ``{}`` where the text
+    carries no tiled layouts."""
+    if ":T(" not in hlo_text:
+        return {}
+    k = 0
+    for line in hlo_text.splitlines():
+        m = _RESULT.match(line)
+        if (m is not None and "T(1," in m.group(2)
+                and scopes.get(m.group(1)) is not None
+                and _nbytes(m.group(2)) >= _SPARSE_MIN_BYTES):
+            k += 1
+    return {SPARSE_TILES: k}
+
+
+def _nbytes(result: str) -> int:
+    """The bytes of the arrays an instruction's result type names (a
+    type without a size here, such as ``token``, counts none)."""
+    return sum(_ITEMSIZE.get(dt, 0)
+               * math.prod(int(d) for d in dims.split(",") if d)
+               for dt, dims in _ARRAY.findall(result))
 
 
 # Live plans, by id; a plan leaves when it is collected.  (Plans are

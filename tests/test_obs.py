@@ -258,3 +258,32 @@ def test_one_chip_plan_has_no_exchange_counters():
     plan = plan_pfft(N, method="lb", p=4, config=PlanConfig(radix=4))
     assert plan.counters() == {}
     assert obs.counters() == {}
+
+
+TILES_HLO = """\
+HloModule jit_raw, is_scheduled=true
+
+ENTRY %main (x.1: f32[8192,4,1024]) -> f32[8192,4,8,1024] {
+  %x.1 = f32[8192,4,1024]{2,1,0:T(8,128)} parameter(0)
+  %copy.2 = f32[8192,4,1,1024]{3,2,1,0:T(1,128)} copy(%x.1), metadata={op_name="jit(raw)/shard_map/pfft.transpose/broadcast_in_dim"}
+  %copy.3 = f32[8192,4,8,1024]{3,2,1,0:T(8,128)} copy(%x.1), metadata={op_name="jit(raw)/shard_map/pfft.transpose/transpose"}
+  %copy.4 = f32[8,4,1,1024]{3,2,1,0:T(1,128)} copy(%x.1), metadata={op_name="jit(raw)/shard_map/pfft.transpose/reshape"}
+  %copy.5 = f32[8192,4,1,1024]{3,2,1,0:T(1,128)} copy(%x.1), metadata={op_name="jit(raw)/neg"}
+  %fusion.6 = (f32[8192,4,1,1024]{3,2,1,0:T(1,128)}, f32[8]{0}) fusion(%x.1), kind=kLoop, calls=%f, metadata={op_name="jit(raw)/pfft.exchange/all_to_all"}
+  %fusion.7 = (f32[8192,4,1,1024]{3,2,1,0:T(1,128)}, token[]) fusion(%x.1), kind=kLoop, calls=%g, metadata={op_name="jit(raw)/pfft.exchange/all_to_all"}
+  ROOT %tuple.8 = (f32[8192,4,8,1024]{3,2,1,0:T(8,128)}) tuple(%copy.3)
+}
+"""
+
+
+def test_sparse_tile_counts_read_one_row_tiles_of_named_arrays():
+    """A result of 1 MiB or more, tiled one row high, inside a ``pfft.*``
+    scope counts, whatever else its result holds; a dense tile, a small
+    array or an unnamed op does not; a text with no tiled layout has no
+    counter."""
+    def counts(text):
+        return obs.sparse_tile_counts(text, obs.scope_map(text))
+    assert counts(TILES_HLO) == {obs.SPARSE_TILES: 3}  # copy.2, fusion.6/7
+    dense = TILES_HLO.replace("T(1,128)", "T(8,128)")
+    assert counts(dense) == {obs.SPARSE_TILES: 0}
+    assert counts(HLO) == {}                        # CPU text: no tiles

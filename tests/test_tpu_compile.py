@@ -10,6 +10,7 @@ compile checks, not chip runs.
 
 import functools
 import os
+import re
 
 import pytest
 import jax
@@ -91,24 +92,57 @@ def test_fused_2d_program_for_v5e(one_chip, no_persistent_cache, monkeypatch):
     assert "multiply_add" not in text
 
 
-def test_slab_exchange_counts_for_v5e_2x2(topo, no_persistent_cache,
-                                          monkeypatch):
+@pytest.fixture(scope="module")
+def slab_program(topo, no_persistent_cache):
     """The four-chip cell's program at N = 32768, as the chip plans it
-    (8 panels), compiled for a described 2x2.  The TPU compiler carries
-    each complex64 exchange as two f32 all-to-alls (re and im), so its 2
-    phases x 8 panels count 32 collectives; each device sends 2 x 3/4 of
-    its 8 N^2/4 bytes off the device all the same."""
+    (8 panels), compiled for a described 2x2: ``(plan.counters(), the
+    executable's HLO text)``."""
     import numpy as np
     from jax.sharding import Mesh
     import repro.kernels.fft.ops as fft_ops
     from repro import obs
     from repro.core import plan_pfft
     from repro.plan.config import PlanConfig
-    monkeypatch.setattr(fft_ops, "_on_cpu", lambda: False)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fft_ops, "_on_cpu", lambda: False)
+        plan = plan_pfft(32768, method="lb",
+                         mesh=Mesh(np.array(topo.devices), ("fft",)),
+                         config=PlanConfig(radix=4, batched=True,
+                                           pipeline_panels=8))
+        return plan.counters(), obs.compiled_text(plan._fn,
+                                                  plan.input_spec())
+
+
+def test_slab_exchange_counts_for_v5e_2x2(slab_program):
+    """The TPU compiler carries each complex64 exchange as two f32
+    all-to-alls (re and im), so the program's 2 phases x 8 panels count
+    32 collectives; each device sends 2 x 3/4 of its 8 N^2/4 bytes off
+    the device all the same."""
+    from repro import obs
     n = 32768
-    plan = plan_pfft(n, method="lb", mesh=Mesh(np.array(topo.devices),
-                                                ("fft",)),
-                     config=PlanConfig(radix=4, batched=True,
-                                       pipeline_panels=8))
-    assert plan.counters() == {obs.COLLECTIVES: 32,
-                               obs.EXCHANGE_BYTES: 2 * 3 * 8 * n * n // 16}
+    counts, _ = slab_program
+    assert {k: counts[k] for k in (obs.COLLECTIVES, obs.EXCHANGE_BYTES)} \
+        == {obs.COLLECTIVES: 32, obs.EXCHANGE_BYTES: 2 * 3 * 8 * n * n // 16}
+
+
+_INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+) = (\S+) ([\w-]+)\(")
+
+
+def test_slab_interleave_stays_dense_in_f32_planes(slab_program):
+    """The pipelined phases re-interleave their exchanged panels without
+    one-row tiles, and the program carries f32 planes from one split at
+    the input to one ``X64Combine`` that writes the row-major complex64
+    result: no complex copy moves a transpose past the join."""
+    from repro import obs
+    counts, text = slab_program
+    assert counts.get(obs.SPARSE_TILES, 0) == 0
+    for target, calls in (("X64SplitLow", 1), ("X64SplitHigh", 1),
+                          ("X64Combine", 1)):
+        assert text.count(f'custom_call_target="{target}"') == calls, target
+    body = text[text.index("ENTRY"):].split("\n}")[0]
+    found = [m.groups() for m in map(_INSTR.match, body.splitlines()) if m]
+    c64 = [(name, op) for _, name, shape, op in found
+           if shape.startswith("c64")]
+    assert [op for _, op in c64] == ["parameter", "custom-call"], c64
+    root = next((shape, op) for is_root, _, shape, op in found if is_root)
+    assert root[1] == "custom-call" and root[0].endswith("{1,0:T(8,128)}")
