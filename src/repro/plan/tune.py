@@ -1,15 +1,18 @@
 """Estimate/measure tuner — FFTW's planner loop over ``PlanConfig`` space.
 
-``candidate_configs`` enumerates the valid variant space for a problem
-(radix x fused x batched x pipeline_panels, pruned by structural
-constraints); ``tune_config`` ranks it:
+Every family tuner (``tune_config``, ``tune_schedule``,
+``tune_dist_config``, ``tune_dist_schedule``, ``tune_pfft3``,
+``tune_pfft1_large``, ``tune_rfft``, ``tune_rfft_dist``) builds a *pot* —
+its candidates, a price for each, the key of the program each runs and a
+way to time a list of them — and hands it to one loop,
+``_rank_and_race``:
 
 * ``mode="estimate"`` — cost model only (``plan.cost``), no device work.
   FFTW's ESTIMATE: instant, right whenever the model's ranking is.
-* ``mode="measure"`` — time the ``top_k`` cheapest candidates on device
-  (``measure_configs``: interleaved round-robin, per-config min) and take
-  the winner.  FFTW's MEASURE: pays seconds once so every later execute
-  is served by the best plan.
+* ``mode="measure"`` — time the ``top_k`` cheapest distinct programs on
+  device (``_time_programs``: interleaved shuffled rounds, per-program
+  min) and take the winner.  FFTW's MEASURE: pays seconds once so every
+  later execute is served by the best plan.
 
 The caller (``plan_pfft`` / the microbenchmark) persists the result via
 ``plan.wisdom`` so measurement happens once per (n, dtype, p, method,
@@ -20,17 +23,18 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Sequence
+from typing import Callable, NamedTuple, Sequence
 
 import numpy as np
 
 from repro.core.fpm import FPMSet
 from repro.plan.config import PlanConfig
-from repro.plan.cost import (CostParams, _compute_multiplier, _segment_work,
-                             comm_phase_time, dist_comm_bytes, dist_comm_time,
-                             estimate_cost, estimate_grouped_cost,
-                             estimate_pfft3_cost, estimate_schedule_cost,
-                             exchange_time, pfft3_comm_bytes)
+from repro.plan.cost import (CostParams, _compute_multiplier, _is_pow2,
+                             _segment_work, comm_phase_time, dist_comm_bytes,
+                             dist_comm_time, estimate_cost,
+                             estimate_grouped_cost, estimate_pfft3_cost,
+                             estimate_schedule_cost, halfspec_cols,
+                             pfft3_comm_bytes)
 from repro.plan.schedule import SegmentSchedule
 
 __all__ = ["candidate_configs", "kernel_exclusions",
@@ -44,29 +48,30 @@ __all__ = ["candidate_configs", "kernel_exclusions",
            "tune_pfft1_large"]
 
 
-def _is_pow2(n: int) -> bool:
-    return n > 0 and not (n & (n - 1))
-
-
-def _measure_with_retry(thunk, retries: int, base_s: float = 0.05):
+def _measure_with_retry(thunk, retries: int, failures: dict, key: str,
+                        prefix: str = "", base_s: float = 0.05):
     """Run a measurement thunk, retrying transient failures with
-    exponential backoff; re-raises after the budget is exhausted.
+    exponential backoff.
 
     Device measurement is the tuner's only fallible step (a transiently
-    wedged device, an allocator hiccup mid-chaos) — the distributed
-    tuners call this when ``measure_retries > 0`` and fall back to the
-    estimate ranking (``info["measure_fallback"]``) if even the retries
-    fail, so a flaky measurement degrades a *plan choice*, never the
-    caller.  ``retries=0`` (the default everywhere) keeps the historical
+    wedged device, an allocator hiccup mid-chaos).  With ``retries > 0``
+    a failure that outlasts the retries is recorded as
+    ``failures[key] = prefix + repr(err)`` and None is returned, so a
+    flaky measurement degrades a *plan choice* (the tuner loop falls back
+    to the estimate ranking, or loses one comm sample), never the caller.
+    ``retries=0`` (the default everywhere) keeps the historical
     raise-through behavior.
     """
     delay = float(base_s)
     for attempt in range(int(retries) + 1):
         try:
             return thunk()
-        except Exception:
-            if attempt >= retries:
+        except Exception as err:
+            if retries <= 0:
                 raise
+            if attempt >= retries:
+                failures[key] = prefix + repr(err)
+                return None
             time.sleep(delay)
             delay *= 2.0
 
@@ -159,69 +164,6 @@ def _length_backend(cfg: PlanConfig, length: int) -> tuple[str, int | None]:
     return kw["backend"], cfg.radix if kw["backend"] == "pallas" else None
 
 
-def _timed_min(pairs, x, rounds: int) -> dict:
-    """{item: best seconds} over ``rounds`` shuffled-interleaved episodes.
-
-    The shared timing discipline of every measure harness here: an
-    untimed same-fn warm run before each timed one (evict the shuffled
-    neighbour's allocator/cache state), per-item min across rounds.
-    ``pairs``: [(item, compiled fn)].
-    """
-    import jax
-
-    rng = np.random.default_rng(1)
-    times = {item: float("inf") for item, _ in pairs}
-    for _ in range(max(rounds, 1)):
-        for i in rng.permutation(len(pairs)):
-            item, fn = pairs[int(i)]
-            jax.block_until_ready(fn(x))  # warm: evict neighbour's state
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(x))
-            times[item] = min(times[item], time.perf_counter() - t0)
-    return times
-
-
-def measure_configs(configs: Sequence[PlanConfig | SegmentSchedule], n: int,
-                    *, d=None, pad_lengths=None, dtype=np.complex64,
-                    rounds: int = 3
-                    ) -> dict[PlanConfig | SegmentSchedule, float]:
-    """On-device seconds of the jitted limb per config: {config: best_s}.
-
-    Interleaved in a per-round *shuffled* order, per-config min over
-    ``rounds``, with an untimed same-config warm run before every timed
-    one: close variants (batched vs looped) differ by far less than the
-    episode-to-episode jitter, and a fixed visiting order would tax each
-    config by whatever allocator/cache state its fixed neighbour leaves
-    behind (one warm run does not fully neutralise an interpret-mode
-    Pallas predecessor).  Shuffling varies the predecessor; min keeps
-    each config's best-context episode.  This is the shared harness of
-    measure-mode tuning and the planner microbenchmark.
-
-    ``d=None`` means one whole-matrix segment (the cost model's
-    convention).  Items may be ``PlanConfig``s *or* ``SegmentSchedule``s
-    (both hashable) — ``tune_schedule``'s measure mode races assembled
-    heterogeneous schedules against homogeneous configs in one pot.
-    """
-    import jax
-    import jax.numpy as jnp
-    from repro.core.pfft import _pfft_limb  # lazy: core imports plan.config
-
-    d_eff = np.asarray(d) if d is not None else np.array([n], dtype=np.int64)
-    rng = np.random.default_rng(0)
-    x = jnp.asarray((rng.standard_normal((n, n))
-                     + 1j * rng.standard_normal((n, n))).astype(dtype))
-    pairs = []
-    for item in configs:
-        if isinstance(item, SegmentSchedule):
-            kw = {"schedule": item}
-        else:
-            kw = {"pad_lengths": pad_lengths, "config": item}
-        fn = jax.jit(lambda m, kw=kw: _pfft_limb(m, d_eff, **kw))
-        jax.block_until_ready(fn(x))  # compile
-        pairs.append((item, fn))
-    return _timed_min(pairs, x, rounds)
-
-
 def _behavior_key(cfg: PlanConfig, n: int, d, pad_lengths) -> tuple:
     """What program actually runs under ``cfg`` for this problem.
 
@@ -238,6 +180,219 @@ def _behavior_key(cfg: PlanConfig, n: int, d, pad_lengths) -> tuple:
             tuple(per_len))
 
 
+def _segment_time(cfg: PlanConfig, fpms: FPMSet | None, i: int, rows: int,
+                  length: int, params: CostParams) -> float:
+    """Estimated seconds of processor ``i``'s ``rows`` rows at ``length``
+    under ``cfg``: its FPM's ``time_at`` (or the nominal flop rate) times
+    the backend multiplier."""
+    if fpms is not None:
+        t = fpms[i].time_at(rows, length)
+    else:
+        from repro.core.fpm import fft_flops
+        t = float(fft_flops(rows, length)) / params.nominal_flops
+    return t * _compute_multiplier(cfg, length, params)
+
+
+# ------------------------------------------------------------ the one loop
+
+def _time_programs(build, items, shape, dtype, rounds: int, *,
+                   mesh=None, spec=None) -> dict:
+    """{item: best seconds} of ``jax.jit(build(item))`` on one input.
+
+    The measure harness of every tuner.  The input is ``default_rng(0)``
+    normal draws of ``shape`` (complex when ``dtype`` is), laid out over
+    ``mesh`` by the partition ``spec`` first when a mesh is given, so
+    placement is not billed to whichever item runs first.  Every program
+    is compiled before any is timed.  Then ``rounds`` episodes visit the
+    items in a *shuffled* order, each timed run preceded by an untimed
+    same-item warm run, and each item keeps its best: close variants
+    (batched vs looped) differ by far less than the episode-to-episode
+    jitter, and a fixed visiting order would tax each item by whatever
+    allocator/cache state its fixed neighbour leaves behind (one warm run
+    does not fully neutralise an interpret-mode Pallas predecessor).
+    Shuffling varies the predecessor; min keeps each item's best-context
+    episode.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal(shape)
+    if np.dtype(dtype).kind == "c":
+        x = x + 1j * rng.standard_normal(shape)
+    x = jnp.asarray(x.astype(dtype))
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        x = jax.device_put(x, NamedSharding(mesh, P(*spec)))
+    fns = []
+    for item in items:
+        fn = jax.jit(build(item))
+        jax.block_until_ready(fn(x))  # compile
+        fns.append((item, fn))
+
+    order = np.random.default_rng(1)
+    times = {item: float("inf") for item, _ in fns}
+    for _ in range(max(rounds, 1)):
+        for i in order.permutation(len(fns)):
+            item, fn = fns[int(i)]
+            jax.block_until_ready(fn(x))  # warm: evict neighbour's state
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(x))
+            times[item] = min(times[item], time.perf_counter() - t0)
+    return times
+
+
+class _CommProbe(NamedTuple):
+    """How a mesh tuner turns its winner's end-to-end time into a comm
+    sample: ``local_s(winner)`` times the local program(s) alone, and
+    ``stats["comm_time_meas_s"] = total − phases·local_s``, clamped at 0
+    (pipelined panels can hide comm below the subtraction's noise)."""
+    stats: dict           # the tuner's topology facts, info["dist"] etc.
+    local: str            # the key local_s is recorded under
+    phases: float         # local programs in one transform
+    local_s: Callable     # winner -> seconds
+
+
+def _shown(cfg: PlanConfig, t: float) -> tuple:
+    return (cfg.to_dict(), t)
+
+
+def _rank_and_race(rec: dict, cands, price, *, mode: str,
+                   params: CostParams | None = None, top_k: int = 3,
+                   key=lambda c: c, measure=None, retries: int = 0,
+                   family=None, probe: _CommProbe | None = None,
+                   shown=_shown):
+    """The tuner loop every family shares; returns the winning candidate.
+
+    Ranks the hashable ``cands`` by ``price(c, params)`` (``params``
+    defaults to the backend's constants; a stable sort: candidate order
+    breaks ties) into ``rec["ranked"]`` and, in estimate mode,
+    returns the cheapest.  In measure mode the finalists are the
+    ``top_k`` cheapest candidates of distinct ``key`` (one per *program*:
+    ranking ties are often configs whose differences runtime fallbacks
+    erase), plus the cheapest of each ``family`` value not yet among them;
+    ``measure(finalists) -> {candidate: seconds}`` times them and the
+    fastest wins (``rec["measured"]``, ``rec["time_s"]``).  ``measure=None``
+    means a 1-device mesh: there is nothing distributed to time.  With
+    ``retries > 0`` a measurement that still fails falls back to the
+    estimate winner (``rec["measure_fallback"]``) and a failed ``probe``
+    is recorded as ``probe.stats["comm_sample_error"]``; with 0 both
+    raise.
+    """
+    if mode not in ("estimate", "measure"):
+        raise ValueError(f"mode must be 'estimate' or 'measure', got {mode!r}")
+    if params is None:
+        params = CostParams.for_backend()
+    ranked = sorted(((c, float(price(c, params))) for c in cands),
+                    key=lambda kv: kv[1])
+    rec["mode"] = mode
+    rec["ranked"] = [shown(c, t) for c, t in ranked]
+    best = ranked[0][0]
+    if mode == "estimate":
+        return best
+    if measure is None:
+        rec["measure_fallback"] = "1-device mesh: measure == estimate"
+        return best
+
+    finalists, seen = [], set()
+    for c, _ in ranked:
+        program = key(c)
+        if program not in seen:
+            seen.add(program)
+            finalists.append(c)
+        if len(finalists) >= max(top_k, 1):
+            break
+    if family is not None:
+        have = {family(c) for c in finalists}
+        for c, _ in ranked:
+            if family(c) not in have:
+                have.add(family(c))
+                finalists.append(c)
+    measured = _measure_with_retry(
+        lambda: measure(finalists), retries, rec, "measure_fallback",
+        f"measurement failed after {retries} retries: ")
+    if measured is None:
+        # Serve the estimate ranking rather than fail the caller (the
+        # self-healing re-planner must always get a plan).
+        return best
+    winner = min(measured, key=measured.get)
+    rec["measured"] = [shown(c, float(t)) for c, t in measured.items()]
+    rec["time_s"] = float(measured[winner])
+
+    if probe is None:
+        return winner
+    # A failed probe loses only this round's comm sample; the winner stands.
+    local_s = _measure_with_retry(lambda: probe.local_s(winner), retries,
+                                  probe.stats, "comm_sample_error")
+    if local_s is not None:
+        probe.stats[probe.local] = float(local_s)
+        probe.stats["comm_time_meas_s"] = float(
+            max(rec["time_s"] - probe.phases * local_s, 0.0))
+    return winner
+
+
+def _settle(info: dict, schedule: SegmentSchedule
+            ) -> tuple[SegmentSchedule, dict]:
+    info["chosen"] = ("heterogeneous" if len(schedule.configs) > 1
+                      else "homogeneous")
+    info["schedule"] = schedule.to_dict()
+    return schedule, info
+
+
+def _race_schedules(info: dict, hetero: SegmentSchedule, est_hetero: float,
+                    homo: SegmentSchedule, est_homo: float,
+                    homo_cfg: PlanConfig, *, tie: SegmentSchedule, mode: str,
+                    measure, retries: int = 0, measured_as: str = "measured"
+                    ) -> tuple[SegmentSchedule, dict]:
+    """The heterogeneous-vs-homogeneous race: a two-candidate pot whose
+    order makes ``tie`` win an estimate tie."""
+    info["heterogeneous"] = {"schedule": hetero.to_dict(),
+                             "est_s": float(est_hetero)}
+    info["homogeneous"] = {"config": homo_cfg.to_dict(),
+                           "est_s": float(est_homo)}
+    race: dict = {}
+    winner = _rank_and_race(
+        race, [hetero, homo] if tie is hetero else [homo, hetero],
+        lambda s, _: est_hetero if s is hetero else est_homo, mode=mode,
+        top_k=2, retries=retries, measure=measure,
+        shown=lambda s, t: (s.describe(), t))
+    if "measured" in race:
+        info[measured_as] = race["measured"]
+        info["time_s"] = race["time_s"]
+    if "measure_fallback" in race:
+        info["measure_fallback"] = race["measure_fallback"]
+    return _settle(info, winner)
+
+
+# ------------------------------------------------------------- single host
+
+def measure_configs(configs: Sequence[PlanConfig | SegmentSchedule], n: int,
+                    *, d=None, pad_lengths=None, dtype=np.complex64,
+                    rounds: int = 3
+                    ) -> dict[PlanConfig | SegmentSchedule, float]:
+    """On-device seconds of the jitted limb per config: {config: best_s}.
+
+    The tuners' shuffled-interleaved per-config-min harness
+    (``_time_programs``) over the single-host limb; the planner
+    microbenchmark times through it too.
+
+    ``d=None`` means one whole-matrix segment (the cost model's
+    convention).  Items may be ``PlanConfig``s *or* ``SegmentSchedule``s
+    (both hashable) — ``tune_schedule``'s measure mode races assembled
+    heterogeneous schedules against homogeneous configs in one pot.
+    """
+    from repro.core.pfft import _pfft_limb  # lazy: core imports plan.config
+
+    d_eff = np.asarray(d) if d is not None else np.array([n], dtype=np.int64)
+
+    def build(item):
+        if isinstance(item, SegmentSchedule):
+            return lambda m: _pfft_limb(m, d_eff, schedule=item)
+        return lambda m: _pfft_limb(m, d_eff, pad_lengths=pad_lengths,
+                                    config=item)
+    return _time_programs(build, configs, (n, n), dtype, rounds)
+
+
 def tune_config(n: int, *, d=None, pad_lengths=None, fpms: FPMSet | None = None,
                 mode: str = "estimate", pad: str = "none",
                 params: CostParams | None = None, top_k: int = 3,
@@ -251,47 +406,23 @@ def tune_config(n: int, *, d=None, pad_lengths=None, fpms: FPMSet | None = None,
     the ``top_k`` finalists (``"measured"``) — the planner's audit trail,
     also persisted into wisdom entries.
     """
-    if mode not in ("estimate", "measure"):
-        raise ValueError(f"mode must be 'estimate' or 'measure', got {mode!r}")
     if d is not None:
         d = np.asarray(d)
-
-    cands = candidate_configs(n, pad=pad, d=d, panels=panels)
-    if params is None:
-        params = CostParams.for_backend()
-    ranked = sorted(
-        ((cfg, estimate_cost(cfg, n=n, d=d, pad_lengths=pad_lengths,
-                             fpms=fpms, params=params, comm_bytes=comm_bytes))
-         for cfg in cands),
-        key=lambda kv: kv[1])
-    info: dict = {
-        "mode": mode,
-        "ranked": [(cfg.to_dict(), float(c)) for cfg, c in ranked],
-    }
-
-    if mode == "estimate":
-        return ranked[0][0], info
-
-    if comm_bytes:
+    if mode == "measure" and comm_bytes:
         raise ValueError(
             "measure mode with comm_bytes needs the mesh the bytes cross — "
             "use tune_dist_config(mesh=...) to time the distributed "
             "pipeline end to end")
-    # One finalist per distinct *program*: ties in the ranking are often
-    # configs whose differences are erased by runtime fallbacks.
-    finalists, seen = [], set()
-    for cfg, _ in ranked:
-        key = _behavior_key(cfg, n, d, pad_lengths)
-        if key not in seen:
-            seen.add(key)
-            finalists.append(cfg)
-        if len(finalists) >= max(top_k, 1):
-            break
-    measured = measure_configs(finalists, n, d=d, pad_lengths=pad_lengths,
-                               dtype=dtype, rounds=reps)
-    winner = min(measured, key=measured.get)
-    info["measured"] = [(cfg.to_dict(), float(t)) for cfg, t in measured.items()]
-    info["time_s"] = float(measured[winner])
+    info: dict = {}
+    winner = _rank_and_race(
+        info, candidate_configs(n, pad=pad, d=d, panels=panels),
+        lambda c, prm: estimate_cost(c, n=n, d=d, pad_lengths=pad_lengths,
+                                     fpms=fpms, params=prm,
+                                     comm_bytes=comm_bytes),
+        mode=mode, params=params, top_k=top_k,
+        key=lambda c: _behavior_key(c, n, d, pad_lengths),
+        measure=lambda fins: measure_configs(
+            fins, n, d=d, pad_lengths=pad_lengths, dtype=dtype, rounds=reps))
     return winner, info
 
 
@@ -302,17 +433,11 @@ def _measure_length_group(configs: Sequence[PlanConfig], rows: int,
 
     The program is exactly what the schedule executor runs for a
     ``(length, config)`` group: gather ``rows`` rows of the N-wide
-    matrix, pad to ``length`` (or chirp-Z at it), transform, crop.  Same
-    shuffled-interleaved-min discipline as ``measure_configs``.
+    matrix, pad to ``length`` (or chirp-Z at it), transform, crop.
     """
-    import jax
     import jax.numpy as jnp
 
-    rng = np.random.default_rng(0)
-    x = jnp.asarray((rng.standard_normal((rows, n))
-                     + 1j * rng.standard_normal((rows, n))).astype(dtype))
-
-    def group_fn(cfg: PlanConfig):
+    def build(cfg: PlanConfig):
         if cfg.pad == "czt":
             from repro.core.pfft import czt_dft
             return lambda m: czt_dft(m, length)
@@ -322,13 +447,7 @@ def _measure_length_group(configs: Sequence[PlanConfig], rows: int,
             return lambda m: fft_rows(
                 jnp.pad(m, ((0, 0), (0, length - n))), **kw)[:, :n]
         return lambda m: fft_rows(m, **kw)
-
-    pairs = []
-    for cfg in configs:
-        fn = jax.jit(group_fn(cfg))
-        jax.block_until_ready(fn(x))  # compile
-        pairs.append((cfg, fn))
-    return _timed_min(pairs, x, rounds)
+    return _time_programs(build, configs, (rows, n), dtype, rounds)
 
 
 def tune_schedule(n: int, *, d=None, pad_lengths=None,
@@ -348,22 +467,19 @@ def tune_schedule(n: int, *, d=None, pad_lengths=None,
     * Single-length problems are exactly the PR-2 homogeneous problem and
       delegate to ``tune_config`` (whose candidate space also covers
       ``fused``/``batched=False``/``pipeline_panels``).
-    * Otherwise, estimate mode picks the per-group argmin under the
-      makespan objective, then keeps the heterogeneous schedule only if
-      it beats the best *homogeneous* config's estimate (dispatch counts
-      included) — the makespan can only improve, but extra dispatch
-      groups are not free.
+    * Otherwise each length group is a pot of its own: estimate mode
+      picks the per-group argmin under the makespan objective, then keeps
+      the heterogeneous schedule only if it beats the best *homogeneous*
+      config's estimate (dispatch counts included; a tie keeps the
+      heterogeneous one) — the makespan can only improve, but extra
+      dispatch groups are not free.
     * Measure mode times only the Pareto top-``top_k`` candidates per
       length group (distinct behaviors, cheapest-estimate first), then
       races the assembled schedule against the homogeneous winner end to
       end; ``info["time_s"]`` is the winner's limb time.
     """
-    if mode not in ("estimate", "measure"):
-        raise ValueError(f"mode must be 'estimate' or 'measure', got {mode!r}")
     if d is not None:
         d = np.asarray(d)
-    if params is None:
-        params = CostParams.for_backend()
 
     # (processor index, rows, effective length) of each non-empty segment.
     idx = [i for i, rows in enumerate(np.asarray(d))
@@ -379,10 +495,8 @@ def tune_schedule(n: int, *, d=None, pad_lengths=None,
                                 mode=mode, pad=pad, params=params,
                                 top_k=top_k, panels=panels,
                                 comm_bytes=comm_bytes, dtype=dtype, reps=reps)
-        schedule = SegmentSchedule.homogeneous(cfg, n, d, pad_lengths)
-        info["chosen"] = "homogeneous"
-        info["schedule"] = schedule.to_dict()
-        return schedule, info
+        return _settle(info, SegmentSchedule.homogeneous(cfg, n, d,
+                                                         pad_lengths))
 
     if mode == "measure" and comm_bytes:
         raise ValueError(
@@ -390,44 +504,25 @@ def tune_schedule(n: int, *, d=None, pad_lengths=None,
             "use tune_dist_schedule(mesh=...) to time the distributed "
             "pipeline end to end")
 
-    def group_time(cfg: PlanConfig, members, length: int) -> float:
-        """Estimated makespan contribution of one length group under cfg."""
-        def seg_t(i: int, rows: int) -> float:
-            if fpms is not None:
-                t = fpms[i].time_at(rows, length)
-            else:
-                from repro.core.fpm import fft_flops
-                t = float(fft_flops(rows, length)) / params.nominal_flops
-            return t * _compute_multiplier(cfg, length, params)
-        return max(seg_t(i, rows) for i, rows in members)
-
-    info: dict = {"mode": mode, "groups": {}}
+    info: dict = {"groups": {}}
     picks: dict[int, PlanConfig] = {}
     for length, members in groups.items():
-        cands = segment_candidate_configs(length, pad=pad)
-        ranked = sorted(((cfg, group_time(cfg, members, length))
-                         for cfg in cands), key=lambda kv: kv[1])
-        info["groups"][str(length)] = [(c.to_dict(), float(t))
-                                       for c, t in ranked]
-        if mode == "estimate":
-            picks[length] = ranked[0][0]
-            continue
-        # Pareto finalists: one per distinct program (pow2 fallbacks erase
-        # radix differences), cheapest-estimate first, at most top_k.
-        finalists, seen = [], set()
-        for cfg, _ in ranked:
-            key = (cfg.pad,) + _length_backend(cfg, length)
-            if key not in seen:
-                seen.add(key)
-                finalists.append(cfg)
-            if len(finalists) >= max(top_k, 1):
-                break
-        measured = _measure_length_group(
-            finalists, rows=sum(r for _, r in members), length=length,
-            n=n, dtype=dtype, rounds=reps)
-        picks[length] = min(measured, key=measured.get)
-        info.setdefault("group_measured", {})[str(length)] = [
-            (c.to_dict(), float(t)) for c, t in measured.items()]
+        group: dict = {}
+        picks[length] = _rank_and_race(
+            group, segment_candidate_configs(length, pad=pad),
+            # A group's estimate is its makespan contribution.
+            lambda c, prm: max(_segment_time(c, fpms, i, rows, length, prm)
+                               for i, rows in members),
+            mode=mode, params=params, top_k=top_k,
+            # Pow2 fallbacks erase radix differences.
+            key=lambda c: (c.pad,) + _length_backend(c, length),
+            measure=lambda fins: _measure_length_group(
+                fins, rows=sum(r for _, r in members), length=length, n=n,
+                dtype=dtype, rounds=reps))
+        info["groups"][str(length)] = group["ranked"]
+        if "measured" in group:
+            info.setdefault("group_measured", {})[str(length)] = \
+                group["measured"]
 
     p = len(d) if d is not None else 1
     default = PlanConfig(pad=pad)
@@ -440,32 +535,19 @@ def tune_schedule(n: int, *, d=None, pad_lengths=None,
                                         comm_bytes=comm_bytes)
 
     # Homogeneous envelope: the full PR-2 candidate space under one config.
-    homo_ranked = sorted(
-        ((cfg, estimate_cost(cfg, n=n, d=d, pad_lengths=pad_lengths,
-                             fpms=fpms, params=params, comm_bytes=comm_bytes))
-         for cfg in candidate_configs(n, pad=pad, d=d, panels=panels)),
-        key=lambda kv: kv[1])
-    homo_cfg, est_homo = homo_ranked[0]
-    homo = SegmentSchedule.homogeneous(homo_cfg, n, d, pad_lengths)
-    info["ranked"] = [(c.to_dict(), float(t)) for c, t in homo_ranked]
-    info["heterogeneous"] = {"schedule": hetero.to_dict(),
-                             "est_s": float(est_hetero)}
-    info["homogeneous"] = {"config": homo_cfg.to_dict(),
-                           "est_s": float(est_homo)}
-
-    if mode == "estimate":
-        winner = homo if est_homo < est_hetero else hetero
-    else:
-        raced = measure_configs([hetero, homo], n, d=d,
-                                pad_lengths=pad_lengths, dtype=dtype,
-                                rounds=reps)
-        winner = min(raced, key=raced.get)
-        info["measured"] = [(s.describe(), float(t)) for s, t in raced.items()]
-        info["time_s"] = float(raced[winner])
-    info["chosen"] = ("heterogeneous" if len(winner.configs) > 1
-                      else "homogeneous")
-    info["schedule"] = winner.to_dict()
-    return winner, info
+    homo_cfg = _rank_and_race(
+        info, candidate_configs(n, pad=pad, d=d, panels=panels),
+        lambda c, prm: estimate_cost(c, n=n, d=d, pad_lengths=pad_lengths,
+                                     fpms=fpms, params=prm,
+                                     comm_bytes=comm_bytes),
+        mode="estimate", params=params)
+    info["mode"] = mode  # the envelope is only ranked; the race times it
+    return _race_schedules(
+        info, hetero, est_hetero,
+        SegmentSchedule.homogeneous(homo_cfg, n, d, pad_lengths),
+        info["ranked"][0][1], homo_cfg, tie=hetero, mode=mode,
+        measure=lambda fins: measure_configs(
+            fins, n, d=d, pad_lengths=pad_lengths, dtype=dtype, rounds=reps))
 
 
 # --------------------------------------------------------------- distributed
@@ -490,25 +572,51 @@ def dist_panel_space(n: int, p: int, max_panels: int = 8) -> tuple[int, ...]:
     return tuple(ks) or (1,)
 
 
-def _measure_local_phase(cfg: PlanConfig, n: int, p: int, pad_len: int,
-                         dtype, rounds: int) -> float:
-    """Seconds of one *local* phase limb of the distributed pipeline: the
-    row-FFT program one device runs on its (N/p, N) block, without the
-    ``all_to_all``.  Subtracting two of these from the end-to-end time is
-    what turns a distributed measurement into a *comm* sample."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core.pfft_dist import _local_fft  # lazy: core imports plan
+def _slab_candidates(n: int, mesh, axis_name: str, pad: str, panels
+                     ) -> tuple[int, list[PlanConfig]]:
+    """(p, candidates) of the slab pipeline over ``mesh``'s ``axis_name``.
 
-    rng = np.random.default_rng(0)
-    x = jnp.asarray((rng.standard_normal((max(n // p, 1), n))
-                     + 1j * rng.standard_normal((max(n // p, 1), n))
-                     ).astype(dtype))
-    fn = jax.jit(lambda b: _local_fft(b, n, padded=cfg.dist_padded,
-                                      pad_len=pad_len, config=cfg,
-                                      backend=None))
-    jax.block_until_ready(fn(x))  # compile
-    return min(_timed_min([(cfg, fn)], x, rounds).values())
+    ``batched`` shapes the segment dispatch plan; the slab has one
+    whole-block segment per device, so the knob is meaningless here and
+    would only burn finalist slots on identical programs.
+    """
+    p = int(mesh.shape[axis_name])
+    if n % p:
+        raise ValueError(f"N={n} must be divisible by mesh axis "
+                         f"{axis_name}={p}")
+    if panels is None:
+        panels = dist_panel_space(n, p)
+    return p, [c for c in candidate_configs(n, pad=pad, d=None,
+                                            panels=panels) if c.batched]
+
+
+def _with_hier(cands: list[PlanConfig]) -> list[PlanConfig]:
+    """``cands`` plus the hierarchical-exchange twin of each complex one:
+    on a host-major axis that exchange is a real program alternative,
+    raced as its own config dimension.  (The real path exchanges padded
+    half-spectrum panels flat-only.)"""
+    import dataclasses
+    return cands + [dataclasses.replace(c, exchange="hier")
+                    for c in cands if not c.real]
+
+
+def _measure_local_phase(cfg: PlanConfig, n: int, rows: int,
+                         pad_len: int | None, dtype, rounds: int) -> float:
+    """Seconds of one *local* row-FFT pass of a distributed pipeline: the
+    program one device runs on its (rows, N) block, without the
+    ``all_to_all``.  Subtracting these from the end-to-end time is what
+    turns a distributed measurement into a *comm* sample.  ``pad_len``
+    None is the executor's own default, so the probe runs the program
+    the end-to-end race ran."""
+    from repro.core.pfft_dist import _local_fft, default_dist_pad_len  # lazy
+
+    if pad_len is None:
+        pad_len = default_dist_pad_len(n, cfg.dist_padded)
+    return _time_programs(
+        lambda c: lambda b: _local_fft(b, n, padded=c.dist_padded,
+                                       pad_len=pad_len, config=c,
+                                       backend=None),
+        [cfg], (rows, n), dtype, rounds)[cfg]
 
 
 def _measure_tier_exchange(mesh, axis_name: str, n: int, hosts: int,
@@ -524,28 +632,19 @@ def _measure_tier_exchange(mesh, axis_name: str, n: int, hosts: int,
     what ``plan/calibrate.py`` fits the two-tier comm params from.
     """
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
     from repro.core.pfft_dist import _hier_groups  # lazy: core imports plan
 
     intra, inter = _hier_groups(hosts, local)
-    groups = intra if tier == "intra" else inter
-    rng = np.random.default_rng(0)
-    x = jnp.asarray((rng.standard_normal((n, n))
-                     + 1j * rng.standard_normal((n, n))).astype(dtype))
-    x = jax.device_put(x, NamedSharding(mesh, P(axis_name, None)))
-
-    @jax.jit
-    @functools.partial(jax.shard_map, mesh=mesh,
-                       in_specs=(P(axis_name, None),),
-                       out_specs=P(axis_name, None), check_vma=False)
-    def ex(block):
-        return jax.lax.all_to_all(block, axis_name, split_axis=1,
-                                  concat_axis=0, tiled=True,
-                                  axis_index_groups=groups)
-
-    jax.block_until_ready(ex(x))  # compile
-    return min(_timed_min([(tier, ex)], x, rounds).values())
+    exchange = jax.shard_map(
+        functools.partial(jax.lax.all_to_all, axis_name=axis_name,
+                          split_axis=1, concat_axis=0, tiled=True,
+                          axis_index_groups=intra if tier == "intra"
+                          else inter),
+        mesh=mesh, in_specs=(P(axis_name, None),),
+        out_specs=P(axis_name, None), check_vma=False)
+    return _time_programs(lambda _: exchange, [tier], (n, n), dtype, rounds,
+                          mesh=mesh, spec=(axis_name, None))[tier]
 
 
 def measure_dist_configs(configs: Sequence[PlanConfig | SegmentSchedule],
@@ -559,7 +658,7 @@ def measure_dist_configs(configs: Sequence[PlanConfig | SegmentSchedule],
     prices ``comm_bytes`` candidates by model alone), this times the full
     pipeline — both all_to_all exchanges, pipelined panels, fused local
     phases — on the caller's actual ``Mesh``.  Same shuffled-interleaved
-    per-config-min harness (``_timed_min``); the input is laid out
+    per-config-min harness (``_time_programs``); the input is laid out
     row-sharded over ``axis_name`` first so placement cost is not billed
     to whichever config runs first.
 
@@ -569,26 +668,17 @@ def measure_dist_configs(configs: Sequence[PlanConfig | SegmentSchedule],
     with its own entry lengths (the uniform-length rule), so ``pad_len``
     applies only to bare configs.
     """
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.pfft_dist import pfft2_distributed  # lazy
 
-    rng = np.random.default_rng(0)
-    x = jnp.asarray((rng.standard_normal((n, n))
-                     + 1j * rng.standard_normal((n, n))).astype(dtype))
-    x = jax.device_put(x, NamedSharding(mesh, P(axis_name, None)))
-    pairs = []
-    for item in configs:
+    def build(item):
         if isinstance(item, SegmentSchedule):
             kw = {"schedule": item}
         else:
             kw = {"config": item, "pad_len": pad_len}
-        fn = jax.jit(functools.partial(pfft2_distributed, mesh=mesh,
-                                       axis_name=axis_name, **kw))
-        jax.block_until_ready(fn(x))  # compile
-        pairs.append((item, fn))
-    return _timed_min(pairs, x, rounds)
+        return functools.partial(pfft2_distributed, mesh=mesh,
+                                 axis_name=axis_name, **kw)
+    return _time_programs(build, configs, (n, n), dtype, rounds, mesh=mesh,
+                          spec=(axis_name, None))
 
 
 def tune_dist_config(n: int, mesh, axis_name: str = "fft", *,
@@ -618,114 +708,44 @@ def tune_dist_config(n: int, mesh, axis_name: str = "fft", *,
     ``comm_time_est_s`` and ``comm_time_meas_s`` cover the transform's
     *two* all_to_all phases, so they compare directly.
     """
-    if mode not in ("estimate", "measure"):
-        raise ValueError(f"mode must be 'estimate' or 'measure', got {mode!r}")
-    p = int(mesh.shape[axis_name])
-    if n % p:
-        raise ValueError(f"N={n} must be divisible by mesh axis "
-                         f"{axis_name}={p}")
-    if panels is None:
-        panels = dist_panel_space(n, p)
+    p, cands = _slab_candidates(n, mesh, axis_name, pad, panels)
     if params is None:
         params = CostParams.for_backend()
     comm_bytes = dist_comm_bytes(n, p)
     from repro.launch.mesh import mesh_host_shape  # lazy: launch is thin
     hosts, local = mesh_host_shape(mesh, axis_name)
-
-    # ``batched`` shapes the segment dispatch plan; the dist pipeline has
-    # one whole-block segment per device, so the knob is meaningless here
-    # and would only burn finalist slots on identical programs.
-    cands = [c for c in candidate_configs(n, pad=pad, d=None, panels=panels)
-             if c.batched]
     if hosts > 1 and local > 1:
-        # Host-major axis: the hierarchical exchange is a real program
-        # alternative — race it as its own config dimension.  (The real
-        # path exchanges padded half-spectrum panels flat-only.)
-        import dataclasses
-        cands += [dataclasses.replace(c, exchange="hier")
-                  for c in cands if not c.real]
-    ranked = sorted(
-        ((cfg, estimate_cost(
-            cfg, n=n, fpms=fpms, params=params, comm_bytes=comm_bytes,
-            comm_time_s=dist_comm_time(n, p, params=params, hosts=hosts,
-                                       exchange=cfg.exchange)))
-         for cfg in cands),
-        key=lambda kv: kv[1])
-    info: dict = {
-        "mode": mode,
-        "ranked": [(cfg.to_dict(), float(c)) for cfg, c in ranked],
-        "dist": {
-            "devices": p,
-            "hosts": int(hosts),
-            "axis_name": axis_name,
-            "comm_bytes": float(comm_bytes),
-            # Both phases, like the measured sample it is judged against.
-            "comm_time_est_s": float(2.0 * comm_phase_time(
-                comm_bytes, params.interconnect_bytes_per_s,
-                params.comm_latency_s)),
-        },
+        cands = _with_hier(cands)
+    stats = {
+        "devices": p,
+        "hosts": int(hosts),
+        "axis_name": axis_name,
+        "comm_bytes": float(comm_bytes),
+        # Both phases, like the measured sample it is judged against.
+        "comm_time_est_s": float(2.0 * comm_phase_time(
+            comm_bytes, params.interconnect_bytes_per_s,
+            params.comm_latency_s)),
     }
-
-    if mode == "estimate":
-        return ranked[0][0], info
-    if p <= 1:
-        # Nothing distributed to time: the 1-device all_to_all is a local
-        # reshuffle and an end-to-end race would just re-measure the limb.
-        info["measure_fallback"] = "1-device mesh: measure == estimate"
-        return ranked[0][0], info
-
-    # One finalist per distinct *distributed* program: the single-host
-    # behavior key plus the panel count (panels change the collective
-    # structure even when the local program is identical).
-    finalists, seen = [], set()
-    for cfg, _ in ranked:
-        key = (_behavior_key(cfg, n, None, None), cfg.pipeline_panels)
-        if key not in seen:
-            seen.add(key)
-            finalists.append(cfg)
-        if len(finalists) >= max(top_k, 1):
-            break
-    try:
-        measured = _measure_with_retry(
-            lambda: measure_dist_configs(finalists, n, mesh, axis_name,
-                                         pad_len=pad_len, dtype=dtype,
-                                         rounds=reps),
-            measure_retries)
-    except Exception as err:
-        if measure_retries <= 0:
-            raise
-        # Retries exhausted: serve the estimate ranking rather than fail
-        # the caller (the self-healing re-planner must always get a plan).
-        info["measure_fallback"] = (
-            f"measurement failed after {measure_retries} retries: {err!r}")
-        return ranked[0][0], info
-    winner = min(measured, key=measured.get)
-    info["measured"] = [(cfg.to_dict(), float(t)) for cfg, t in measured.items()]
-    info["time_s"] = float(measured[winner])
-
-    # Comm sample: end-to-end minus the two measured local phases of the
-    # winning config.  Clamped at 0 — overlap (pipelined panels) can
-    # legitimately hide comm below the subtraction's noise floor.
-    eff_len = pad_len
-    if eff_len is None:
-        # The executor's own default, so the local probe runs the same
-        # program the end-to-end measurement ran.
-        from repro.core.pfft_dist import default_dist_pad_len
-        eff_len = default_dist_pad_len(n, winner.dist_padded)
-    try:
-        local_s = _measure_with_retry(
-            lambda: _measure_local_phase(winner, n, p, eff_len, dtype, reps),
-            measure_retries)
-    except Exception as err:
-        if measure_retries <= 0:
-            raise
-        # The winner stands; only the comm sample is lost this round.
-        info["dist"]["comm_sample_error"] = repr(err)
+    info: dict = {"dist": stats}
+    winner = _rank_and_race(
+        info, cands,
+        lambda c, prm: estimate_cost(
+            c, n=n, fpms=fpms, params=prm, comm_bytes=comm_bytes,
+            comm_time_s=dist_comm_time(n, p, params=prm, hosts=hosts,
+                                       exchange=c.exchange)),
+        mode=mode, params=params, top_k=top_k, retries=measure_retries,
+        # The panel count changes the collective structure even when the
+        # local program is identical.
+        key=lambda c: (_behavior_key(c, n, None, None), c.pipeline_panels),
+        measure=None if p <= 1 else lambda fins: measure_dist_configs(
+            fins, n, mesh, axis_name, pad_len=pad_len, dtype=dtype,
+            rounds=reps),
+        probe=_CommProbe(stats, "local_phase_s", 2.0,
+                         lambda w: _measure_local_phase(
+                             w, n, n // p, pad_len, dtype, reps)))
+    if "comm_time_meas_s" not in stats:
         return winner, info
-    info["dist"]["local_phase_s"] = float(local_s)
-    info["dist"]["comm_time_meas_s"] = float(
-        max(measured[winner] - 2.0 * local_s, 0.0))
-    info["dist"]["exchange"] = winner.exchange
+    stats["exchange"] = winner.exchange
     if hosts > 1 and local > 1:
         # Per-tier samples: one grouped all_to_all per tier, so calibrate
         # can fit the intra- and inter-host comm params separately.  The
@@ -738,20 +758,16 @@ def tune_dist_config(n: int, mesh, axis_name: str = "fft", *,
                                        ("inter", tiers.inter, hosts - 1)):
             if not tier_bytes:
                 continue
-            try:
-                t = _measure_with_retry(
-                    lambda tier=tier: _measure_tier_exchange(
-                        mesh, axis_name, n, hosts, local, tier, dtype, reps),
-                    measure_retries)
-            except Exception as err:
-                if measure_retries <= 0:
-                    raise
-                info["dist"]["tier_sample_error"] = repr(err)
+            t = _measure_with_retry(
+                lambda tier=tier: _measure_tier_exchange(
+                    mesh, axis_name, n, hosts, local, tier, dtype, reps),
+                measure_retries, stats, "tier_sample_error")
+            if t is None:
                 break
             samples.append({"tier": tier, "bytes": float(tier_bytes),
                             "msgs": int(msgs), "time_s": float(t)})
         if samples:
-            info["dist"]["comm_samples"] = samples
+            stats["comm_samples"] = samples
     return winner, info
 
 
@@ -776,28 +792,6 @@ def pfft3_panel_space(n: int, r: int, c: int, max_panels: int = 8
     return tuple(ks) or (1,)
 
 
-def _measure_pfft3_local_pass(cfg: PlanConfig, n: int, r: int, c: int,
-                              pad_len: int, dtype, rounds: int) -> float:
-    """Seconds of one *local* axis pass of the pencil pipeline: the
-    row-FFT program one device runs on its (N/r · N/c, N) pencil rows,
-    without either ``all_to_all``.  Subtracting three of these from the
-    end-to-end time turns a pencil measurement into a *comm* sample
-    covering the transform's two exchange rounds."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core.pfft_dist import _local_fft  # lazy: core imports plan
-
-    rows = max((n // max(r, 1)) * (n // max(c, 1)), 1)
-    rng = np.random.default_rng(0)
-    x = jnp.asarray((rng.standard_normal((rows, n))
-                     + 1j * rng.standard_normal((rows, n))).astype(dtype))
-    fn = jax.jit(lambda b: _local_fft(b, n, padded=cfg.dist_padded,
-                                      pad_len=pad_len, config=cfg,
-                                      backend=None))
-    jax.block_until_ready(fn(x))  # compile
-    return min(_timed_min([(cfg, fn)], x, rounds).values())
-
-
 def measure_pfft3_configs(configs: Sequence[PlanConfig], n: int, mesh,
                           axis_names: Sequence[str] = ("fft_r", "fft_c"), *,
                           pad_len: int | None = None, dtype=np.complex64,
@@ -808,29 +802,20 @@ def measure_pfft3_configs(configs: Sequence[PlanConfig], n: int, mesh,
     pipeline — three local passes, both all_to_all rounds, pipelined
     panels, the final global transpose — on the caller's actual 2-D
     ``Mesh``.  Same shuffled-interleaved per-config-min harness
-    (``_timed_min``); the cube is laid out pencil-sharded over
+    (``_time_programs``); the cube is laid out pencil-sharded over
     ``axis_names`` first so placement cost is not billed to whichever
     config runs first.  One call races one *orientation* — callers
     (``tune_pfft3``) merge per-orientation races themselves.
     """
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.pfft3d import pfft3_pencil  # lazy
 
     axes = tuple(axis_names)
-    rng = np.random.default_rng(0)
-    x = jnp.asarray((rng.standard_normal((n, n, n))
-                     + 1j * rng.standard_normal((n, n, n))).astype(dtype))
-    x = jax.device_put(x, NamedSharding(mesh, P(axes[0], axes[1], None)))
-    pairs = []
-    for cfg in configs:
-        fn = jax.jit(functools.partial(pfft3_pencil, mesh=mesh,
-                                       axis_names=axes, config=cfg,
-                                       pad_len=pad_len))
-        jax.block_until_ready(fn(x))  # compile
-        pairs.append((cfg, fn))
-    return _timed_min(pairs, x, rounds)
+    return _time_programs(
+        lambda cfg: functools.partial(pfft3_pencil, mesh=mesh,
+                                      axis_names=axes, config=cfg,
+                                      pad_len=pad_len),
+        configs, (n, n, n), dtype, rounds, mesh=mesh,
+        spec=(axes[0], axes[1], None))
 
 
 def tune_pfft3(n: int, mesh=None,
@@ -859,8 +844,6 @@ def tune_pfft3(n: int, mesh=None,
     run, the comm sample ``comm_time_meas_s = total − 3·local_pass``
     (clamped at 0) covering both exchange rounds.
     """
-    if mode not in ("estimate", "measure"):
-        raise ValueError(f"mode must be 'estimate' or 'measure', got {mode!r}")
     axes0 = tuple(axis_names)
     if mesh is not None:
         r = int(mesh.shape[axes0[0]])
@@ -888,11 +871,8 @@ def tune_pfft3(n: int, mesh=None,
                                               panels=panels)
              if cfg.batched and not cfg.fused]
     if any(h > 1 and l > 1 for h, l in host_shapes.values()):
-        # Some orientation puts a host-major axis under the row exchange:
-        # race the hierarchical form as its own config dimension.
-        import dataclasses
-        cands += [dataclasses.replace(cfg, exchange="hier")
-                  for cfg in cands if not cfg.real]
+        # Some orientation puts a host-major axis under the row exchange.
+        cands = _with_hier(cands)
     # Orientation space: which mesh axis plays "row".  On a square mesh
     # (or single host) the transposed program is identical.
     if mesh is not None and r != c:
@@ -902,7 +882,8 @@ def tune_pfft3(n: int, mesh=None,
     else:
         orientations = [None]
 
-    def est(cfg: PlanConfig, waxes) -> float:
+    def est(cand, prm: CostParams) -> float:
+        cfg, waxes = cand
         if waxes is None:
             r_o, c_o, h_o = 1, 1, 1
         else:
@@ -912,122 +893,57 @@ def tune_pfft3(n: int, mesh=None,
             # the hierarchical form applies to); a non-host-major row
             # axis prices — and runs — as flat.
             h_o = host_shapes[waxes[0]][0]
-        return estimate_pfft3_cost(cfg, n=n, r=r_o, c=c_o, params=params,
+        return estimate_pfft3_cost(cfg, n=n, r=r_o, c=c_o, params=prm,
                                    pad_len=pad_len, hosts=h_o)
 
-    ranked = sorted(((cfg, waxes, est(cfg, waxes))
-                     for cfg in cands for waxes in orientations),
-                    key=lambda kv: kv[2])
-    info: dict = {
-        "mode": mode,
-        "ranked": [(cfg.to_dict(),
-                    list(waxes) if waxes is not None else None, float(t))
-                   for cfg, waxes, t in ranked],
-        "pfft3": {
-            "r": r, "c": c,
-            "hosts": int(host_shapes.get(axes0[0], (1, 1))[0]),
-            "axis_names": list(axes0) if mesh is not None else None,
-            "comm_bytes": float(comm_bytes),
-            "comm_time_est_s": float(
-                sum(comm_phase_time(b, params.interconnect_bytes_per_s,
-                                    params.comm_latency_s)
-                    for b in (pfft3_comm_bytes(n, c),
-                              pfft3_comm_bytes(n, r)))),
-        },
-    }
-
-    if mode == "estimate":
-        cfg, waxes, _ = ranked[0]
-        info["orientation"] = list(waxes) if waxes is not None else None
-        return cfg, waxes, info
-    if r * c <= 1 and mesh is not None:
-        info["measure_fallback"] = "1-device mesh: measure == estimate"
-        cfg, waxes, _ = ranked[0]
-        info["orientation"] = list(waxes) if waxes is not None else None
-        return cfg, waxes, info
-
-    # One finalist per distinct *pencil* program: single-host behavior key
-    # plus panel count plus orientation (orientation changes which round
-    # crosses which communicator even when the local program is the same).
-    finalists, seen = [], set()
-    for cfg, waxes, _ in ranked:
-        key = (_behavior_key(cfg, n, None, None), cfg.pipeline_panels, waxes)
-        if key not in seen:
-            seen.add(key)
-            finalists.append((cfg, waxes))
-        if len(finalists) >= max(top_k, 1):
-            break
-
-    def run_races() -> dict:
-        merged: dict = {}
+    def measure(finalists) -> dict:
         if mesh is None:
             # Single host: time the production single-host program.
-            import jax
-            import jax.numpy as jnp
             from repro.core.pfft3d import pfft3_lb  # lazy
-
-            rng = np.random.default_rng(0)
-            x = jnp.asarray((rng.standard_normal((n, n, n))
-                             + 1j * rng.standard_normal((n, n, n))
-                             ).astype(dtype))
-            pairs = []
-            for cfg, _ in finalists:
-                fn = jax.jit(lambda m, c=cfg: pfft3_lb(m, 1, config=c))
-                jax.block_until_ready(fn(x))  # compile
-                pairs.append((cfg, fn))
-            for cfg, t in _timed_min(pairs, x, reps).items():
-                merged[(cfg, None)] = t
-            return merged
+            return _time_programs(
+                lambda cand: lambda m: pfft3_lb(m, 1, config=cand[0]),
+                finalists, (n, n, n), dtype, reps)
+        merged: dict = {}
         for waxes in orientations:
             group = [cfg for cfg, wa in finalists if wa == waxes]
-            if not group:
-                continue
-            times = measure_pfft3_configs(group, n, mesh, waxes,
-                                          pad_len=pad_len, dtype=dtype,
-                                          rounds=reps)
-            for cfg, t in times.items():
-                merged[(cfg, waxes)] = t
+            if group:
+                times = measure_pfft3_configs(group, n, mesh, waxes,
+                                              pad_len=pad_len, dtype=dtype,
+                                              rounds=reps)
+                merged.update(((cfg, waxes), t) for cfg, t in times.items())
         return merged
 
-    try:
-        measured = _measure_with_retry(run_races, measure_retries)
-    except Exception as err:
-        if measure_retries <= 0:
-            raise
-        info["measure_fallback"] = (
-            f"measurement failed after {measure_retries} retries: {err!r}")
-        cfg, waxes, _ = ranked[0]
-        info["orientation"] = list(waxes) if waxes is not None else None
-        return cfg, waxes, info
-    wcfg, waxes = min(measured, key=measured.get)
-    info["measured"] = [(cfg.to_dict(),
-                         list(wa) if wa is not None else None, float(t))
-                        for (cfg, wa), t in measured.items()]
-    info["time_s"] = float(measured[(wcfg, waxes)])
+    stats = {
+        "r": r, "c": c,
+        "hosts": int(host_shapes.get(axes0[0], (1, 1))[0]),
+        "axis_names": list(axes0) if mesh is not None else None,
+        "comm_bytes": float(comm_bytes),
+        "comm_time_est_s": float(
+            sum(comm_phase_time(b, params.interconnect_bytes_per_s,
+                                params.comm_latency_s)
+                for b in (pfft3_comm_bytes(n, c),
+                          pfft3_comm_bytes(n, r)))),
+    }
+    info: dict = {"pfft3": stats}
+    cfg, waxes = _rank_and_race(
+        info, [(cfg, waxes) for cfg in cands for waxes in orientations], est,
+        mode=mode, params=params, top_k=top_k, retries=measure_retries,
+        # Orientation changes which round crosses which communicator even
+        # when the local program is the same.
+        key=lambda cand: (_behavior_key(cand[0], n, None, None),
+                          cand[0].pipeline_panels, cand[1]),
+        measure=None if mesh is not None and r * c <= 1 else measure,
+        probe=_CommProbe(stats, "local_pass_s", 3.0,
+                         lambda w: _measure_local_phase(
+                             w[0], n, (n // r) * (n // c), pad_len, dtype,
+                             reps)),
+        shown=lambda cand, t: (cand[0].to_dict(),
+                               list(cand[1]) if cand[1] is not None
+                               else None, t))
     info["orientation"] = list(waxes) if waxes is not None else None
-
-    # Comm sample: end-to-end minus the three measured local passes of
-    # the winning program.  Clamped at 0 — pipelined panels can hide comm
-    # below the subtraction's noise floor.
-    eff_len = pad_len
-    if eff_len is None:
-        from repro.core.pfft_dist import default_dist_pad_len
-        eff_len = default_dist_pad_len(n, wcfg.dist_padded)
-    try:
-        local_s = _measure_with_retry(
-            lambda: _measure_pfft3_local_pass(wcfg, n, r, c, eff_len, dtype,
-                                              reps),
-            measure_retries)
-    except Exception as err:
-        if measure_retries <= 0:
-            raise
-        info["pfft3"]["comm_sample_error"] = repr(err)
-        return wcfg, waxes, info
-    info["pfft3"]["local_pass_s"] = float(local_s)
-    info["pfft3"]["comm_time_meas_s"] = float(
-        max(measured[(wcfg, waxes)] - 3.0 * local_s, 0.0))
-    info["pfft3"]["exchange"] = wcfg.exchange
-    return wcfg, waxes, info
+    if "comm_time_meas_s" in stats:
+        stats["exchange"] = cfg.exchange
+    return cfg, waxes, info
 
 
 def tune_pfft1_large(n: int, *, n1: int | None = None, n2: int | None = None,
@@ -1043,76 +959,46 @@ def tune_pfft1_large(n: int, *, n1: int | None = None, n2: int | None = None,
     helps the pow2 side may be a fallback no-op on the other.  Measure
     mode times the jitted production ``pfft1_large_apply`` end to end.
     """
-    if mode not in ("estimate", "measure"):
-        raise ValueError(f"mode must be 'estimate' or 'measure', got {mode!r}")
-    from repro.core.fpm import fft_flops
     from repro.core.pfft_large import four_step_factors  # lazy
 
     n1, n2 = four_step_factors(n, n1=n1, n2=n2)
-    if params is None:
-        params = CostParams.for_backend()
 
     radices: list[int | None] = [None]
     if _is_pow2(n1) or _is_pow2(n2):
         radices += [2, 4]
-    cands = [PlanConfig(radix=rad) for rad in radices]
 
-    def est(cfg: PlanConfig) -> float:
-        compute = (
-            float(fft_flops(n1, n2)) / params.nominal_flops
-            * _compute_multiplier(cfg, n2, params)
-            + float(fft_flops(n2, n1)) / params.nominal_flops
-            * _compute_multiplier(cfg, n1, params))
+    def est(cfg: PlanConfig, prm: CostParams) -> float:
+        compute = (_segment_time(cfg, None, 0, n1, n2, prm)
+                   + _segment_time(cfg, None, 0, n2, n1, prm))
         itemsize = np.dtype(dtype).itemsize
-        traffic = 4.0 * n * itemsize / params.hbm_bytes_per_s
-        return compute + traffic + 2.0 * params.dispatch_overhead_s
+        traffic = 4.0 * n * itemsize / prm.hbm_bytes_per_s
+        return compute + traffic + 2.0 * prm.dispatch_overhead_s
 
-    ranked = sorted(((cfg, est(cfg)) for cfg in cands), key=lambda kv: kv[1])
-    info: dict = {
-        "mode": mode,
-        "ranked": [(cfg.to_dict(), float(t)) for cfg, t in ranked],
-        "four_step": {"n1": int(n1), "n2": int(n2)},
-    }
-    if mode == "estimate":
-        return ranked[0][0], info
+    def measure(finalists) -> dict:
+        from repro.core.pfft_large import pfft1_large_apply  # lazy
+        return _time_programs(
+            lambda c: lambda v: pfft1_large_apply(v, config=c, n1=n1, n2=n2),
+            finalists, (n,), dtype, reps)
 
-    import jax
-    import jax.numpy as jnp
-    from repro.core.pfft_large import pfft1_large_apply  # lazy
-
-    finalists, seen = [], set()
-    for cfg, _ in ranked:
-        key = (_length_backend(cfg, n1), _length_backend(cfg, n2))
-        if key not in seen:
-            seen.add(key)
-            finalists.append(cfg)
-        if len(finalists) >= max(top_k, 1):
-            break
-    rng = np.random.default_rng(0)
-    x = jnp.asarray((rng.standard_normal(n)
-                     + 1j * rng.standard_normal(n)).astype(dtype))
-    pairs = []
-    for cfg in finalists:
-        fn = jax.jit(lambda v, c=cfg: pfft1_large_apply(v, config=c, n1=n1,
-                                                        n2=n2))
-        jax.block_until_ready(fn(x))  # compile
-        pairs.append((cfg, fn))
-    measured = _timed_min(pairs, x, reps)
-    winner = min(measured, key=measured.get)
-    info["measured"] = [(cfg.to_dict(), float(t))
-                        for cfg, t in measured.items()]
-    info["time_s"] = float(measured[winner])
+    info: dict = {"four_step": {"n1": int(n1), "n2": int(n2)}}
+    winner = _rank_and_race(
+        info, [PlanConfig(radix=rad) for rad in radices], est, mode=mode,
+        params=params, top_k=top_k,
+        key=lambda c: (_length_backend(c, n1), _length_backend(c, n2)),
+        measure=measure)
     return winner, info
 
 
 # ------------------------------------------------------------------- real
 
-def _require_real_dtype(dtype) -> np.dtype:
-    """Validate a real-pipeline input dtype; returns the np.dtype."""
+def _require_real_dtype(dtype, pad: str = "none") -> np.dtype:
+    """Validate a real-pipeline input dtype and pad; returns the np.dtype."""
     dt = np.dtype(dtype)
     if dt not in (np.dtype(np.float32), np.dtype(np.float64)):
         raise ValueError(
             f"the real pipeline tunes float32/float64 inputs, got {dt.name}")
+    if pad == "czt":
+        raise ValueError("the real pipeline has no Bluestein form")
     return dt
 
 
@@ -1125,25 +1011,11 @@ def _real_candidates(cands: Sequence[PlanConfig], n: int
                            if c.pad != "czt"], n)
 
 
-def _family_finalists(ranked, n: int, d, pad_lengths, top_k: int
-                      ) -> list[PlanConfig]:
-    """Distinct-program finalists that always include the best candidate
-    of *each* family (real and complex), so measure mode genuinely races
-    real-vs-complex rather than burning every slot on one side."""
-    finalists, seen = [], set()
-    for cfg, _ in ranked:
-        key = _behavior_key(cfg, n, d, pad_lengths)
-        if key not in seen:
-            seen.add(key)
-            finalists.append(cfg)
-        if len(finalists) >= max(top_k, 1):
-            break
-    for want_real in (True, False):
-        if not any(c.real == want_real for c in finalists):
-            best = next((c for c, _ in ranked if c.real == want_real), None)
-            if best is not None:
-                finalists.append(best)
-    return finalists
+def _is_real(cfg: PlanConfig) -> bool:
+    """The family of a real pot's candidate: finalists always include the
+    best of each, so measure mode genuinely races real-vs-complex rather
+    than burning every slot on one side."""
+    return cfg.real
 
 
 def measure_rfft_configs(configs: Sequence[PlanConfig], n: int, *, d=None,
@@ -1158,28 +1030,21 @@ def measure_rfft_configs(configs: Sequence[PlanConfig], n: int, *, d=None,
     padded real phase equals the padded complex phase's half spectrum
     bin for bin — see ``core.pfft.halfspec_distribution``).
     """
-    import jax
-    import jax.numpy as jnp
     from repro.core.pfft import _pfft_limb, _rpfft_limb  # lazy
 
     dt = _require_real_dtype(dtype)
-    ctype = np.complex64 if dt == np.dtype(np.float32) else np.complex128
+    ctype = np.result_type(dt, np.complex64)
     nh = n // 2 + 1
     d_eff = np.asarray(d) if d is not None else np.array([n], dtype=np.int64)
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((n, n)).astype(dt))
-    pairs = []
-    for cfg in configs:
+
+    def build(cfg: PlanConfig):
         if cfg.real:
-            fn = jax.jit(lambda m, c=cfg: _rpfft_limb(
-                m, d_eff, pad_lengths=pad_lengths, config=c))
-        else:
-            fn = jax.jit(lambda m, c=cfg: _pfft_limb(
-                m.astype(ctype), d_eff, pad_lengths=pad_lengths,
-                config=c)[:, :nh])
-        jax.block_until_ready(fn(x))  # compile
-        pairs.append((cfg, fn))
-    return _timed_min(pairs, x, rounds)
+            return lambda m: _rpfft_limb(m, d_eff, pad_lengths=pad_lengths,
+                                         config=cfg)
+        return lambda m: _pfft_limb(m.astype(ctype), d_eff,
+                                    pad_lengths=pad_lengths,
+                                    config=cfg)[:, :nh]
+    return _time_programs(build, configs, (n, n), dt, rounds)
 
 
 def tune_rfft(n: int, *, d=None, pad_lengths=None, fpms: FPMSet | None = None,
@@ -1197,39 +1062,20 @@ def tune_rfft(n: int, *, d=None, pad_lengths=None, fpms: FPMSet | None = None,
     side won; the returned schedule's configs carry the ``real`` flag the
     executor routes on.
     """
-    if mode not in ("estimate", "measure"):
-        raise ValueError(f"mode must be 'estimate' or 'measure', got {mode!r}")
-    _require_real_dtype(dtype)
-    if pad == "czt":
-        raise ValueError("the real pipeline has no Bluestein form")
+    _require_real_dtype(dtype, pad)
     if d is not None:
         d = np.asarray(d)
-    if params is None:
-        params = CostParams.for_backend()
 
     complex_cands = candidate_configs(n, pad=pad, d=d)
-    cands = _real_candidates(complex_cands, n) + complex_cands
-    ranked = sorted(
-        ((cfg, estimate_cost(cfg, n=n, d=d, pad_lengths=pad_lengths,
-                             fpms=fpms, params=params))
-         for cfg in cands),
-        key=lambda kv: kv[1])
-    info: dict = {
-        "mode": mode,
-        "ranked": [(cfg.to_dict(), float(c)) for cfg, c in ranked],
-    }
-
-    if mode == "estimate":
-        winner = ranked[0][0]
-    else:
-        finalists = _family_finalists(ranked, n, d, pad_lengths, top_k)
-        measured = measure_rfft_configs(finalists, n, d=d,
-                                        pad_lengths=pad_lengths, dtype=dtype,
-                                        rounds=reps)
-        winner = min(measured, key=measured.get)
-        info["measured"] = [(cfg.to_dict(), float(t))
-                            for cfg, t in measured.items()]
-        info["time_s"] = float(measured[winner])
+    info: dict = {}
+    winner = _rank_and_race(
+        info, _real_candidates(complex_cands, n) + complex_cands,
+        lambda c, prm: estimate_cost(c, n=n, d=d, pad_lengths=pad_lengths,
+                                     fpms=fpms, params=prm),
+        mode=mode, params=params, top_k=top_k, family=_is_real,
+        key=lambda c: _behavior_key(c, n, d, pad_lengths),
+        measure=lambda fins: measure_rfft_configs(
+            fins, n, d=d, pad_lengths=pad_lengths, dtype=dtype, rounds=reps))
     info["chosen_path"] = "real" if winner.real else "complex"
     schedule = SegmentSchedule.homogeneous(winner, n, d, pad_lengths)
     info["schedule"] = schedule.to_dict()
@@ -1247,61 +1093,48 @@ def measure_rfft_dist_configs(configs: Sequence[PlanConfig], n: int, mesh,
     on the same mesh, same sharded-input discipline as
     ``measure_dist_configs``.
     """
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.pfft_dist import (pfft2_distributed,  # lazy
                                       rpfft2_distributed)
 
     dt = _require_real_dtype(dtype)
-    ctype = np.complex64 if dt == np.dtype(np.float32) else np.complex128
+    ctype = np.result_type(dt, np.complex64)
     nh = n // 2 + 1
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((n, n)).astype(dt))
-    x = jax.device_put(x, NamedSharding(mesh, P(axis_name, None)))
-    pairs = []
-    for cfg in configs:
+
+    def build(cfg: PlanConfig):
         if cfg.real:
-            fn = jax.jit(functools.partial(rpfft2_distributed, mesh=mesh,
-                                           axis_name=axis_name, config=cfg,
-                                           pad_len=pad_len))
-        else:
-            fn = jax.jit(lambda m, c=cfg: pfft2_distributed(
-                m.astype(ctype), mesh=mesh, axis_name=axis_name, config=c,
-                pad_len=pad_len)[:, :nh])
-        jax.block_until_ready(fn(x))  # compile
-        pairs.append((cfg, fn))
-    return _timed_min(pairs, x, rounds)
+            return functools.partial(rpfft2_distributed, mesh=mesh,
+                                     axis_name=axis_name, config=cfg,
+                                     pad_len=pad_len)
+        return lambda m: pfft2_distributed(
+            m.astype(ctype), mesh=mesh, axis_name=axis_name, config=cfg,
+            pad_len=pad_len)[:, :nh]
+    return _time_programs(build, configs, (n, n), dt, rounds, mesh=mesh,
+                          spec=(axis_name, None))
 
 
-def _measure_local_real_phases(cfg: PlanConfig, n: int, p: int, pad_len: int,
-                               dtype, rounds: int) -> float:
+def _measure_local_real_phases(cfg: PlanConfig, n: int, p: int,
+                               pad_len: int | None, dtype,
+                               rounds: int) -> float:
     """Combined seconds of the real pipeline's two *local* phase programs
     (rfft on the (N/p, N) row block + complex FFT on the (hc/p, N)
     spectral block) — the subtraction term that turns an end-to-end real
     measurement into a comm sample, mirroring ``_measure_local_phase``.
     """
-    import jax
-    import jax.numpy as jnp
     from repro.core.pfft import _group_row_rffts, _group_row_ffts  # lazy
-    from repro.plan.cost import halfspec_cols
+    from repro.core.pfft_dist import default_dist_pad_len
 
     dt = _require_real_dtype(dtype)
-    ctype = np.complex64 if dt == np.dtype(np.float32) else np.complex128
-    hc = halfspec_cols(n, p)
-    rng = np.random.default_rng(0)
-    x1 = jnp.asarray(rng.standard_normal((max(n // p, 1), n)).astype(dt))
-    x2 = jnp.asarray((rng.standard_normal((max(hc // p, 1), n))
-                      + 1j * rng.standard_normal((max(hc // p, 1), n))
-                      ).astype(ctype))
+    if pad_len is None:
+        pad_len = default_dist_pad_len(n, cfg.dist_padded)
     length = pad_len if cfg.pad == "fpm" else n
-    fn1 = jax.jit(lambda b: _group_row_rffts(b, length, n, cfg, None))
-    fn2 = jax.jit(lambda b: _group_row_ffts(b, length, n, cfg, None))
-    jax.block_until_ready(fn1(x1))  # compile
-    jax.block_until_ready(fn2(x2))
-    t1 = min(_timed_min([(cfg, fn1)], x1, rounds).values())
-    t2 = min(_timed_min([(cfg, fn2)], x2, rounds).values())
-    return t1 + t2
+    rfft = _time_programs(
+        lambda c: lambda b: _group_row_rffts(b, length, n, c, None),
+        [cfg], (max(n // p, 1), n), dt, rounds)
+    fft = _time_programs(
+        lambda c: lambda b: _group_row_ffts(b, length, n, c, None),
+        [cfg], (max(halfspec_cols(n, p) // p, 1), n),
+        np.result_type(dt, np.complex64), rounds)
+    return rfft[cfg] + fft[cfg]
 
 
 def tune_rfft_dist(n: int, mesh, axis_name: str = "fft", *,
@@ -1323,104 +1156,47 @@ def tune_rfft_dist(n: int, mesh, axis_name: str = "fft", *,
     the full panel/fused space.  ``info["dist"]`` carries both byte
     counts, their ratio, and (measured) the winner's comm sample.
     """
-    if mode not in ("estimate", "measure"):
-        raise ValueError(f"mode must be 'estimate' or 'measure', got {mode!r}")
-    _require_real_dtype(dtype)
-    if pad == "czt":
-        raise ValueError("the real pipeline has no Bluestein form")
-    p = int(mesh.shape[axis_name])
-    if n % p:
-        raise ValueError(f"N={n} must be divisible by mesh axis "
-                         f"{axis_name}={p}")
-    if panels is None:
-        panels = dist_panel_space(n, p)
-    if params is None:
-        params = CostParams.for_backend()
+    _require_real_dtype(dtype, pad)
+    p, complex_cands = _slab_candidates(n, mesh, axis_name, pad, panels)
     comm_complex = dist_comm_bytes(n, p)
     comm_real = dist_comm_bytes(n, p, real=True)
-
-    complex_cands = [c for c in candidate_configs(n, pad=pad, d=None,
-                                                  panels=panels) if c.batched]
     real_cands = [c for c in _real_candidates(complex_cands, n)
                   if not c.fused and c.pipeline_panels == 1]
-    ranked = sorted(
-        ((cfg, estimate_cost(cfg, n=n, fpms=fpms, params=params,
-                             comm_bytes=comm_real if cfg.real
-                             else comm_complex))
-         for cfg in real_cands + complex_cands),
-        key=lambda kv: kv[1])
-    info: dict = {
-        "mode": mode,
-        "ranked": [(cfg.to_dict(), float(c)) for cfg, c in ranked],
-        "dist": {
-            "devices": p,
-            "axis_name": axis_name,
-            "comm_bytes_complex": float(comm_complex),
-            "comm_bytes_real": float(comm_real),
-            "comm_ratio_real": (float(comm_real / comm_complex)
-                                if comm_complex else 0.0),
-        },
-    }
 
-    def finish(winner: PlanConfig) -> tuple[SegmentSchedule, dict]:
-        info["chosen_path"] = "real" if winner.real else "complex"
-        info["dist"]["comm_bytes"] = float(comm_real if winner.real
-                                           else comm_complex)
-        d = np.full(p, n // p, dtype=np.int64) if p > 0 else None
-        schedule = SegmentSchedule.homogeneous(winner, n, d)
-        info["schedule"] = schedule.to_dict()
-        return schedule, info
-
-    if mode == "estimate":
-        return finish(ranked[0][0])
-    if p <= 1:
-        info["measure_fallback"] = "1-device mesh: measure == estimate"
-        return finish(ranked[0][0])
-
-    finalists = _family_finalists(ranked, n, None, None, top_k)
-    try:
-        measured = _measure_with_retry(
-            lambda: measure_rfft_dist_configs(finalists, n, mesh, axis_name,
-                                              pad_len=pad_len, dtype=dtype,
-                                              rounds=reps),
-            measure_retries)
-    except Exception as err:
-        if measure_retries <= 0:
-            raise
-        info["measure_fallback"] = (
-            f"measurement failed after {measure_retries} retries: {err!r}")
-        return finish(ranked[0][0])
-    winner = min(measured, key=measured.get)
-    info["measured"] = [(cfg.to_dict(), float(t))
-                        for cfg, t in measured.items()]
-    info["time_s"] = float(measured[winner])
-
-    eff_len = pad_len
-    if eff_len is None:
-        from repro.core.pfft_dist import default_dist_pad_len
-        eff_len = default_dist_pad_len(n, winner.dist_padded)
-    try:
+    def local_s(winner: PlanConfig) -> float:
         if winner.real:
-            local_s = _measure_with_retry(
-                lambda: _measure_local_real_phases(winner, n, p, eff_len,
-                                                   dtype, reps),
-                measure_retries)
-        else:
-            ctype = (np.complex64 if np.dtype(dtype) == np.dtype(np.float32)
-                     else np.complex128)
-            local_s = 2.0 * _measure_with_retry(
-                lambda: _measure_local_phase(winner, n, p, eff_len, ctype,
-                                             reps),
-                measure_retries)
-    except Exception as err:
-        if measure_retries <= 0:
-            raise
-        info["dist"]["comm_sample_error"] = repr(err)
-        return finish(winner)
-    info["dist"]["local_phase_s"] = float(local_s)
-    info["dist"]["comm_time_meas_s"] = float(
-        max(measured[winner] - local_s, 0.0))
-    return finish(winner)
+            return _measure_local_real_phases(winner, n, p, pad_len, dtype,
+                                              reps)
+        return 2.0 * _measure_local_phase(
+            winner, n, n // p, pad_len,
+            np.result_type(np.dtype(dtype), np.complex64), reps)
+
+    stats = {
+        "devices": p,
+        "axis_name": axis_name,
+        "comm_bytes_complex": float(comm_complex),
+        "comm_bytes_real": float(comm_real),
+        "comm_ratio_real": (float(comm_real / comm_complex)
+                            if comm_complex else 0.0),
+    }
+    info: dict = {"dist": stats}
+    winner = _rank_and_race(
+        info, real_cands + complex_cands,
+        lambda c, prm: estimate_cost(c, n=n, fpms=fpms, params=prm,
+                                     comm_bytes=comm_real if c.real
+                                     else comm_complex),
+        mode=mode, params=params, top_k=top_k, retries=measure_retries, family=_is_real,
+        key=lambda c: _behavior_key(c, n, None, None),
+        measure=None if p <= 1 else lambda fins: measure_rfft_dist_configs(
+            fins, n, mesh, axis_name, pad_len=pad_len, dtype=dtype,
+            rounds=reps),
+        probe=_CommProbe(stats, "local_phase_s", 1.0, local_s))
+    info["chosen_path"] = "real" if winner.real else "complex"
+    stats["comm_bytes"] = float(comm_real if winner.real else comm_complex)
+    d = np.full(p, n // p, dtype=np.int64) if p > 0 else None
+    schedule = SegmentSchedule.homogeneous(winner, n, d)
+    info["schedule"] = schedule.to_dict()
+    return schedule, info
 
 
 def grouped_dist_schedule(n: int, p: int, *, pad_lengths=None,
@@ -1451,22 +1227,14 @@ def grouped_dist_schedule(n: int, p: int, *, pad_lengths=None,
         fpms = None  # one abstract processor per device or no FPM at all
     n_loc = n // p
     d = np.full(p, n_loc, dtype=np.int64)
-
-    def seg_time(i: int, cfg: PlanConfig, length: int) -> float:
-        if fpms is not None:
-            t = fpms[i].time_at(n_loc, length)
-        else:
-            from repro.core.fpm import fft_flops
-            t = float(fft_flops(n_loc, length)) / params.nominal_flops
-        return t * _compute_multiplier(cfg, length, params)
-
     cfgs = []
     for i in range(p):
         length = n
         if pad_lengths is not None and int(pad_lengths[i]) > n:
             length = int(pad_lengths[i])
         cands = segment_candidate_configs(length, pad=pad)
-        cfgs.append(min(cands, key=lambda c: seg_time(i, c, length)))
+        cfgs.append(min(cands, key=lambda c: _segment_time(
+            c, fpms, i, n_loc, length, params)))
     schedule = SegmentSchedule.from_parts(n, d, pad_lengths, cfgs)
     return schedule if len(schedule.configs) > 1 else None
 
@@ -1488,7 +1256,8 @@ def tune_dist_schedule(n: int, mesh, axis_name: str = "fft", *,
     assembly of ``grouped_dist_schedule`` — lowered by the executor as a
     device-group program (``repro.plan.groups``) — priced with
     ``estimate_grouped_cost`` (per-group makespan + switch-dispatch
-    overhead) against the homogeneous winner.  ``mode="measure"`` races
+    overhead) against the homogeneous winner; a tie keeps the
+    homogeneous one.  ``mode="measure"`` races
     the grouped finalist against the homogeneous winner end to end
     through the *actual* grouped ``pfft2_distributed`` program on the
     caller's mesh (``info["grouped_measured"]``), so a genuinely
@@ -1511,16 +1280,12 @@ def tune_dist_schedule(n: int, mesh, axis_name: str = "fft", *,
                                  pad_len=pad_len, fpms=fpms, params=params,
                                  top_k=top_k, panels=panels, dtype=dtype,
                                  reps=reps, measure_retries=measure_retries)
-    if params is None:
-        params = CostParams.for_backend()
     d = np.full(p, n // p, dtype=np.int64) if p > 0 else None
     homo = SegmentSchedule.homogeneous(cfg, n, d, pad_lengths)
     hetero = grouped_dist_schedule(n, p, pad_lengths=pad_lengths, fpms=fpms,
                                    pad=pad, params=params)
     if hetero is None:
-        info["chosen"] = "homogeneous"
-        info["schedule"] = homo.to_dict()
-        return homo, info
+        return _settle(info, homo)
 
     fpms_dev = fpms if fpms is not None and fpms.p == p else None
     comm_bytes = dist_comm_bytes(n, p)
@@ -1528,32 +1293,9 @@ def tune_dist_schedule(n: int, mesh, axis_name: str = "fft", *,
                                        comm_bytes=comm_bytes)
     est_homo = estimate_grouped_cost(homo, fpms=fpms_dev, params=params,
                                      comm_bytes=comm_bytes)
-    info["heterogeneous"] = {"schedule": hetero.to_dict(),
-                             "est_s": float(est_hetero)}
-    info["homogeneous"] = {"config": cfg.to_dict(), "est_s": float(est_homo)}
-
-    if mode == "estimate" or "measure_fallback" in info:
-        winner = hetero if est_hetero < est_homo else homo
-    else:
-        try:
-            raced = _measure_with_retry(
-                lambda: measure_dist_configs([homo, hetero], n, mesh,
-                                             axis_name, dtype=dtype,
-                                             rounds=reps),
-                measure_retries)
-        except Exception as err:
-            if measure_retries <= 0:
-                raise
-            info["measure_fallback"] = (
-                f"grouped race failed after {measure_retries} retries: "
-                f"{err!r}")
-            winner = hetero if est_hetero < est_homo else homo
-        else:
-            winner = min(raced, key=raced.get)
-            info["grouped_measured"] = [(s.describe(), float(t))
-                                        for s, t in raced.items()]
-            info["time_s"] = float(raced[winner])
-    info["chosen"] = ("heterogeneous" if len(winner.configs) > 1
-                      else "homogeneous")
-    info["schedule"] = winner.to_dict()
-    return winner, info
+    return _race_schedules(
+        info, hetero, est_hetero, homo, est_homo, cfg, tie=homo,
+        mode="estimate" if "measure_fallback" in info else mode,
+        retries=measure_retries, measured_as="grouped_measured",
+        measure=lambda fins: measure_dist_configs(
+            fins, n, mesh, axis_name, dtype=dtype, rounds=reps))

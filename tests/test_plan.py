@@ -3,6 +3,8 @@ plan_pfft tune/wisdom lifecycle (including equivalence with the
 pre-refactor flag paths and batched execute)."""
 
 import json
+import os
+import shutil
 
 import numpy as np
 import pytest
@@ -18,6 +20,7 @@ from repro.plan import (WISDOM_VERSION, CostParams, candidate_configs,
                         tune_config, wisdom_key)
 from repro.plan.cost import V5E_KIND
 from repro.core.padding import determine_pad_length, smooth_candidates
+import plan_cases
 
 
 def fpms_for(n, p=3, hetero=True):
@@ -212,9 +215,17 @@ def test_measure_mode_times_finalists():
     assert info["time_s"] > 0
 
 
-def test_tune_rejects_bad_mode():
-    with pytest.raises(ValueError):
-        tune_config(32, mode="exhaustive")
+@pytest.mark.parametrize("tuner", ["tune_config", "tune_schedule",
+                                   "tune_dist_config", "tune_dist_schedule",
+                                   "tune_pfft3", "tune_pfft1_large",
+                                   "tune_rfft", "tune_rfft_dist"])
+def test_tune_rejects_bad_mode(tuner):
+    import repro.plan.tune as tune_mod
+    on_mesh = tuner in ("tune_dist_config", "tune_dist_schedule",
+                        "tune_rfft_dist")
+    args = (jax.make_mesh((1,), ("fft",)),) if on_mesh else ()
+    with pytest.raises(ValueError, match="mode must be"):
+        getattr(tune_mod, tuner)(32, *args, mode="exhaustive")
 
 
 def test_measure_mode_without_partition():
@@ -223,6 +234,113 @@ def test_measure_mode_without_partition():
     chosen, info = tune_config(16, mode="measure", top_k=1, reps=1)
     assert chosen in candidate_configs(16)
     assert info["time_s"] > 0
+
+
+# ------------------------------------------------- one loop, every family
+
+@pytest.fixture(scope="module")
+def tuner_runs(dist_subprocess, tmp_path_factory):
+    """The golden and policy results of this tree (``plan_cases``), from
+    one run on eight forced CPU devices."""
+    out = tmp_path_factory.mktemp("tuners") / "runs.json"
+    dist_subprocess(f"import sys; sys.path.insert(0, {plan_cases.HERE!r})\n"
+                    f"import plan_cases; plan_cases.run_all({str(out)!r})\n"
+                    "print('TUNERS_OK')", devices=8, sentinel="TUNERS_OK")
+    with open(out) as f:
+        return json.load(f)
+
+
+# What the benchmark cells run on the chip (PERF.md): (radix, fused,
+# pipeline_panels) of the one config the schedule carries.
+CELL_PICKS = {"cell-1chip-n8192": (4, True, 1),
+              "cell-4chip-n32768": (4, False, 8)}
+
+
+@pytest.mark.parametrize("case", plan_cases.golden_names())
+def test_tuner_choices_match_golden(tuner_runs, case):
+    """Under the v5e constants every family tuner picks what it picked
+    before the tuners shared one loop, with the same ``info`` (wisdom
+    stores and calibration read it)."""
+    with open(plan_cases.GOLDEN) as f:
+        want = json.load(f)[case]
+    got = tuner_runs["golden"][case]
+    assert got["winner"] == want["winner"]
+    assert got["info"] == want["info"]
+    if case in CELL_PICKS:
+        (entry,) = {json.dumps(e["config"], sort_keys=True)
+                    for e in got["winner"]["entries"]}
+        cfg = json.loads(entry)
+        assert (cfg["radix"], cfg["fused"],
+                cfg["pipeline_panels"]) == CELL_PICKS[case]
+
+
+@pytest.mark.parametrize("family", list(plan_cases.POLICY))
+def test_measure_policy(tuner_runs, family):
+    """Measure mode under a fake timer: the finalists are distinct
+    programs, at most ``top_k`` (a real pot adds the best of the other
+    family); the fastest wins; the comm sample is end to end minus the
+    local phases, clamped at 0; a failing measurement raises, or with
+    retries falls back to the estimate winner."""
+    runs = tuner_runs["policy"]
+    facts = runs["clamped"][family]
+    top_k = plan_cases.POLICY[family]
+    programs = [json.dumps(m[:-1], sort_keys=True) for m in facts["measured"]]
+    assert len(set(programs)) == len(programs)
+    if family in plan_cases.REAL_POTS:
+        assert {m[0]["real"] for m in facts["measured"]} == {True, False}
+        assert len(programs) == top_k + 1
+    else:
+        assert len(programs) <= top_k
+    if family == "config":
+        # Every padded length is non-pow2: the radices are one program.
+        assert len(programs) == 2
+
+    times = [m[-1] for m in facts["measured"]]
+    assert facts["time_s"] == min(times)
+    winner = facts["winner"] if isinstance(facts["winner"], list) \
+        else [facts["winner"]]
+    assert [m[:-1] for m in facts["measured"]
+            if m[-1] == min(times)][0] == winner
+
+    phases = plan_cases.COMM_PHASES.get(family)
+    if phases is not None:
+        local = "local_pass_s" if "pfft3" in family else "local_phase_s"
+        assert facts["stats"]["comm_time_meas_s"] == 0.0   # clamped
+        small = runs["small"][family]
+        assert small["stats"]["comm_time_meas_s"] == pytest.approx(
+            small["time_s"] - phases * small["stats"][local])
+        assert small["stats"]["comm_time_meas_s"] > 0
+
+    assert facts["raised"] == "device lost"
+    if "fallback" in facts:
+        assert facts["fallback"].startswith(
+            "measurement failed after 1 retries")
+        assert facts["fallback_winner"] == facts["estimate"]
+
+
+@pytest.mark.parametrize("plan", ["lb", "rfft-lb", "pfft3", "pfft1-large"])
+def test_wisdom_written_before_the_fold_is_served(tmp_path, monkeypatch,
+                                                  plan):
+    """A store the planner wrote before the tuners shared one loop still
+    serves every family, with no measurement."""
+    import repro.plan.tune as tune_mod
+    from repro.core.api import plan_pfft1_large, plan_pfft3
+
+    def no_measure(*a, **k):
+        raise AssertionError("re-measured a wisdom-served plan")
+    monkeypatch.setattr(tune_mod, "_time_programs", no_measure)
+    path = str(tmp_path / "wisdom.json")
+    shutil.copy(os.path.join(plan_cases.HERE, "plan_wisdom_parent.json"),
+                path)
+    built = {"lb": lambda: plan_pfft(32, p=2, method="lb", tune="measure",
+                                     wisdom=path),
+             "rfft-lb": lambda: plan_pfft(32, p=2, method="rfft-lb",
+                                          tune="measure", wisdom=path,
+                                          dtype=np.float32),
+             "pfft3": lambda: plan_pfft3(8, tune="measure", wisdom=path),
+             "pfft1-large": lambda: plan_pfft1_large(360, tune="measure",
+                                                     wisdom=path)}[plan]()
+    assert built.tuning["source"] == "wisdom"
 
 
 # ------------------------------------------------------------------- wisdom
