@@ -160,7 +160,8 @@ class _Scoped:
             scopes = obs.scope_map(text)
             found = self._analysis = (
                 scopes, {**obs.exchange_counts(text),
-                         **obs.sparse_tile_counts(text, scopes)})
+                         **obs.sparse_tile_counts(text, scopes),
+                         **obs.rowfft_counts(text)})
         return found
 
     def scope_map(self) -> dict[str, str | None]:
@@ -172,8 +173,9 @@ class _Scoped:
 
     def counters(self) -> dict[str, int]:
         """The counters of one transform of the plan's executable:
-        ``repro.obs.exchange_counts`` (none on one chip) and
-        ``repro.obs.sparse_tile_counts`` (none on the CPU)."""
+        ``repro.obs.exchange_counts`` (none on one chip),
+        ``repro.obs.sparse_tile_counts`` and ``repro.obs.rowfft_counts``
+        (none on the CPU)."""
         return self._compiled()[1]
 
 

@@ -3,7 +3,11 @@
 TPU adaptation of the paper's 1D_ROW_FFTS_LOCAL hot loop.  Design notes:
 
 * Complex data is carried as two f32 planes (re, im) — TPU Pallas has no
-  complex dtype; a complex product is four real matrix products.
+  complex dtype.  A complex product by a DFT matrix ``F = C + iS`` is
+  three real matrix products, not four: the tables hold ``C``, ``C + S``
+  and ``S - C`` (``dft_tables``), and for data ``a + ib``
+  ``t1 = C (a + b)``, ``t2 = (C + S) b``, ``t3 = (S - C) a`` give
+  ``Re = t1 - t2`` and ``Im = t1 + t3`` (``CMUL_PRODUCTS``).
 * Rows of length ``n <= 512`` are transformed directly: ``X = x @ F`` with
   the ``n x n`` DFT matrix ``F``.
 * Longer rows use the four-step split ``n = n1 * n2`` with ``n2 = 128``
@@ -38,11 +42,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 __all__ = [
+    "CMUL_PRODUCTS",
     "LANES",
     "bmm_left",
     "compiler_params",
     "dft_planes",
     "dft_digits",
+    "dft_macs",
     "dft_tables",
     "fft_rows_pallas",
     "frozen_f32",
@@ -56,6 +62,7 @@ __all__ = [
 LANES = 128  # TPU vreg lane width; the four-step inner length n2
 _DIRECT_MAX = 512  # longest row transformed by one DFT product
 _HI = jax.lax.Precision.HIGHEST
+CMUL_PRODUCTS = 3  # real matrix products per complex product by a table
 
 
 def split_length(n: int) -> tuple[int, int]:
@@ -83,25 +90,42 @@ def frozen_f32(*arrays: np.ndarray) -> tuple[np.ndarray, ...]:
     return tuple(out)
 
 
+def _three_product(f: np.ndarray) -> tuple[np.ndarray, ...]:
+    """``(C, C + S, S - C)`` of a complex table ``F = C + iS``, formed in
+    float64 before the one rounding to f32."""
+    return f.real, f.real + f.imag, f.imag - f.real
+
+
 @functools.lru_cache(maxsize=None)
 def dft_tables(n: int, inverse: bool = False) -> tuple[np.ndarray, ...]:
     """Read-only f32 tables ``dft_planes`` multiplies by.
 
-    ``n <= 512``: ``(Fr, Fi)``, the ``n x n`` DFT matrix.  Longer: ``(F1r,
-    F1i, Tr, Ti, F2r, F2i)`` — the ``n1 x n1`` column DFT, the ``n1 x n2``
-    twiddle and the ``n2 x n2`` row DFT.  The inverse conjugates every
-    table and folds ``1/n`` into the last one.
+    Each DFT matrix ``F = C + iS`` is held as ``(C, C + S, S - C)``, the
+    three-product form ``_cmul`` takes.  ``n <= 512``: the ``n x n`` DFT
+    matrix's three.  Longer: ``(F1c, F1p, F1m, Tr, Ti, F2c, F2p, F2m)`` —
+    the ``n1 x n1`` column DFT, the ``n1 x n2`` twiddle (re, im: a
+    pointwise product) and the ``n2 x n2`` row DFT.  The inverse
+    conjugates every table and folds ``1/n`` into the last one.
     """
     n1, n2 = split_length(n)
     sign = 1.0 if inverse else -1.0
     i2 = np.arange(n2)
     f2 = _cis(np.outer(i2, i2), n2, sign) / (n if inverse else 1)
     if n1 == 1:
-        return frozen_f32(f2.real, f2.imag)
+        return frozen_f32(*_three_product(f2))
     i1 = np.arange(n1)
     f1 = _cis(np.outer(i1, i1), n1, sign)
     tw = _cis(np.outer(i1, i2), n, sign)
-    return frozen_f32(f1.real, f1.imag, tw.real, tw.imag, f2.real, f2.imag)
+    return frozen_f32(*_three_product(f1), tw.real, tw.imag,
+                      *_three_product(f2))
+
+
+def dft_macs(rows: int, n: int) -> int:
+    """Real multiply-accumulates the DFT products of ``dft_digits`` perform
+    on ``(rows, n)`` planes, before the precision passes: ``n`` complex
+    MACs an element for a direct DFT, ``n1 + n2`` for the four-step."""
+    n1, n2 = split_length(n)
+    return CMUL_PRODUCTS * rows * n * (n if n1 == 1 else n1 + n2)
 
 
 def hi_dot(a, b):
@@ -109,21 +133,23 @@ def hi_dot(a, b):
     return jnp.dot(a, b, precision=_HI, preferred_element_type=jnp.float32)
 
 
-def _cdot(xr, xi, fr, fi):
-    """Complex ``(xr + i xi) @ (fr + i fi)`` as four real products."""
-    return hi_dot(xr, fr) - hi_dot(xi, fi), hi_dot(xr, fi) + hi_dot(xi, fr)
+def _cmul(dot, xr, xi, c, cps, smc):
+    """Complex product of data ``xr + i xi`` by a table held as ``(C, C +
+    S, S - C)`` in three real products; ``dot(table, x)`` is the product
+    in the caller's order."""
+    t1 = dot(c, xr + xi)
+    return t1 - dot(cps, xi), t1 + dot(smc, xr)
+
+
+def _cdot(xr, xi, *table):
+    """Complex ``(xr + i xi) @ F``."""
+    return _cmul(lambda f, x: hi_dot(x, f), xr, xi, *table)
 
 
 def bmm_left(f, x):
     """``f @ x[r]`` for every ``r`` of a ``(rows, m, k)`` batch."""
     return jnp.einsum("ka,rab->rkb", f, x, precision=_HI,
                       preferred_element_type=jnp.float32)
-
-
-def _cdot_left(fr, fi, xr, xi):
-    """Complex ``F @ x_r`` for every row ``r`` of a ``(rows, n1, n2)`` batch."""
-    return (bmm_left(fr, xr) - bmm_left(fi, xi),
-            bmm_left(fr, xi) + bmm_left(fi, xr))
 
 
 def dft_digits(re: jnp.ndarray, im: jnp.ndarray,
@@ -139,21 +165,21 @@ def dft_digits(re: jnp.ndarray, im: jnp.ndarray,
     n1, n2 = split_length(n)
     if n1 == 1:
         return _cdot(re, im, *tables)
-    f1r, f1i, twr, twi, f2r, f2i = tables
+    f1, (twr, twi), f2 = tables[:3], tables[3:5], tables[5:]
     if k1_major:
         def view(x):  # [a, (r, b)]
             x = jnp.swapaxes(x.reshape(rows, n1, n2), 0, 1)
             return x.reshape(n1, rows * n2)
-        yr, yi = _cdot(f1r, f1i, view(re), view(im))          # step 1
+        yr, yi = _cmul(hi_dot, view(re), view(im), *f1)       # step 1
         yr, yi = yr.reshape(n1, rows, n2), yi.reshape(n1, rows, n2)
         twr, twi = twr[:, None, :], twi[:, None, :]
     else:
-        yr, yi = _cdot_left(f1r, f1i, re.reshape(rows, n1, n2),
-                            im.reshape(rows, n1, n2))        # step 1
+        yr, yi = _cmul(bmm_left, re.reshape(rows, n1, n2),
+                       im.reshape(rows, n1, n2), *f1)        # step 1
     zr = yr * twr - yi * twi                                  # step 2
     zi = yr * twi + yi * twr
     return _cdot(zr.reshape(rows * n1, n2), zi.reshape(rows * n1, n2),
-                 f2r, f2i)                                    # step 3
+                 *f2)                                         # step 3
 
 
 def to_natural(x: jnp.ndarray, rows: int) -> jnp.ndarray:

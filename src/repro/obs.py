@@ -54,12 +54,18 @@ those of every live plan into the table.
   scope whose result, 1 MiB or more, is laid out in tiles one row high
   (``T(1,...)``): on a TPU such an array fills one sublane of the eight
   of each vreg, so moving it costs up to eight times its bytes
+* ``pfft.rowfft.mxu_macs``  the real multiply-accumulates the DFT
+  products of the row-FFT Pallas kernels (``tpu_custom_call``s named
+  after their op wrappers) perform, before the precision passes:
+  ``repro.kernels.fft.kernel.dft_macs`` of each kernel's ``(rows, n)``
+  input plane (the real kernels' 0/1 permutation products not counted)
 
-All three count instructions, not executions: they are per transform
-only while every exchange runs once a call, as in the slab pipeline,
-whose panel loop is unrolled.  A plan whose executable holds no
-``all-to-all`` (one chip) has no exchange counters; an executable whose
-text carries no tiled layouts (the CPU's) has no ``sparse_tiles``.
+All four count instructions, not executions: they are per transform
+only while every exchange and kernel runs once a call, as in the slab
+pipeline, whose panel loop is unrolled.  A plan whose executable holds
+no ``all-to-all`` (one chip) has no exchange counters; an executable
+whose text carries no tiled layouts or Pallas calls (the CPU's, where
+the kernels are interpreted) has no ``sparse_tiles`` or ``mxu_macs``.
 """
 
 from __future__ import annotations
@@ -74,10 +80,11 @@ import jax
 from jax.profiler import TraceAnnotation
 
 __all__ = ["COLLECTIVES", "EXCHANGE", "EXCHANGE_BYTES", "JOIN", "ROWFFT",
-           "SCOPES", "SPARSE_TILES", "SPLIT", "TRANSPOSE", "compiled_text",
-           "count", "counters", "exchange_counts", "live_counters",
-           "live_scope_map", "register", "reset", "scope", "scope_map",
-           "snapshot", "span", "sparse_tile_counts"]
+           "ROWFFT_MACS", "SCOPES", "SPARSE_TILES", "SPLIT", "TRANSPOSE",
+           "compiled_text", "count", "counters", "exchange_counts",
+           "live_counters", "live_scope_map", "register", "reset",
+           "rowfft_counts", "scope", "scope_map", "snapshot", "span",
+           "sparse_tile_counts"]
 
 SPLIT, ROWFFT, JOIN, TRANSPOSE, EXCHANGE = SCOPES = (
     "pfft.split", "pfft.rowfft", "pfft.join", "pfft.transpose",
@@ -105,6 +112,11 @@ _RESULT = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*?) [\w\-]+\(")
 _SPARSE_MIN_BYTES = 1 << 20
 _GROUPS = re.compile(r"replica_groups=(?:\{\{([\d,]*)\}|\[\d+,(\d+)\])")
 _PARTITIONS = re.compile(r"\bnum_partitions=(\d+)")
+ROWFFT_MACS = "pfft.rowfft.mxu_macs"
+_ROWFFT_CALL = re.compile(
+    r"^\s*(?:ROOT\s+)?%?r?fft_rows(?:_transpose)?_op(?:\.\d+)? = .*"
+    r'custom_call_target="tpu_custom_call".*?'
+    r"operand_layout_constraints=\{[a-z]+\d*\[(\d+),(\d+)\]")
 
 
 def scope(name: str):
@@ -213,6 +225,17 @@ def sparse_tile_counts(hlo_text: str,
                 and _nbytes(m.group(2)) >= _SPARSE_MIN_BYTES):
             k += 1
     return {SPARSE_TILES: k}
+
+
+def rowfft_counts(hlo_text: str) -> dict[str, int]:
+    """``{ROWFFT_MACS: m}`` of the row-FFT Pallas kernels in compiled HLO
+    text (see the module docstring), or ``{}`` where it has none."""
+    planes = [tuple(map(int, m.groups()))
+              for m in map(_ROWFFT_CALL.match, hlo_text.splitlines()) if m]
+    if not planes:
+        return {}
+    from repro.kernels.fft.kernel import dft_macs
+    return {ROWFFT_MACS: sum(dft_macs(rows, n) for rows, n in planes)}
 
 
 def _nbytes(result: str) -> int:

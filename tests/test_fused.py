@@ -62,8 +62,8 @@ def test_split_length():
     with pytest.raises(ValueError):
         split_length(12)
     t = dft_tables(1024)
-    assert [a.shape for a in t] == [(8, 8)] * 2 + [(8, 128)] * 2 + \
-        [(128, 128)] * 2
+    assert [a.shape for a in t] == [(8, 8)] * 3 + [(8, 128)] * 2 + \
+        [(128, 128)] * 3
     assert all(a.dtype == np.float32 and not a.flags.writeable for a in t)
 
 

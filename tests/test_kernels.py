@@ -7,9 +7,11 @@ import jax
 import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st  # hypothesis or fallback
 
-from repro.kernels.fft.kernel import dft_planes, dft_tables, fft_rows_pallas
+from repro.kernels.fft.kernel import (CMUL_PRODUCTS, bmm_left, dft_macs,
+                                      dft_planes, dft_tables,
+                                      fft_rows_pallas, hi_dot, split_length)
 from repro.kernels.fft.ops import (KERNEL_KINDS, fft_rows_op, pick_block_rows,
-                                   vmem_bytes)
+                                   tpu_unsupported, vmem_bytes)
 from repro.kernels.fft.ref import fft_rows_ref
 from repro.kernels.transpose.kernel import transpose_pallas
 from repro.kernels.transpose.ops import transpose_op
@@ -45,6 +47,101 @@ def test_fft_kernel_pallas_call(rng, inverse, block_rows):
     rre, rim = fft_rows_ref(re, im, inverse=inverse)
     np.testing.assert_allclose(np.asarray(ore), np.asarray(rre), atol=1e-3)
     np.testing.assert_allclose(np.asarray(oim), np.asarray(rim), atol=1e-3)
+
+
+def _dft_matrices(n, inverse):
+    """The complex DFT matrices ``dft_tables`` holds, in float64: the
+    direct one, or the four-step's (F1, twiddle, F2)."""
+    n1, n2 = split_length(n)
+    sign = 1.0 if inverse else -1.0
+
+    def cis(num, den):
+        return np.exp(sign * 2j * np.pi * (num % den) / den)
+
+    i1, i2 = np.arange(n1), np.arange(n2)
+    f2 = cis(np.outer(i2, i2), n2) / (n if inverse else 1)
+    if n1 == 1:
+        return (f2,)
+    return cis(np.outer(i1, i1), n1), cis(np.outer(i1, i2), n), f2
+
+
+def _four_product_dft(re, im, n, inverse):
+    """The row DFT with every complex product as four real products on
+    f32 ``(re, im)`` tables rounded from float64: the oracle the
+    three-product kernel's error is held to."""
+    mats = [(jnp.asarray(m.real, jnp.float32), jnp.asarray(m.imag, jnp.float32))
+            for m in _dft_matrices(n, inverse)]
+
+    def cdot(dot, xr, xi, fr, fi):
+        return dot(xr, fr) - dot(xi, fi), dot(xr, fi) + dot(xi, fr)
+
+    def right(x, f):
+        return hi_dot(x, f)
+
+    if len(mats) == 1:
+        return cdot(right, re, im, *mats[0])
+    (f1r, f1i), (twr, twi), (f2r, f2i) = mats
+    rows = re.shape[0]
+    n1, n2 = split_length(n)
+    yr, yi = cdot(lambda x, f: bmm_left(f, x), re.reshape(rows, n1, n2),
+                  im.reshape(rows, n1, n2), f1r, f1i)
+    zr, zi = yr * twr - yi * twi, yr * twi + yi * twr
+    xr, xi = cdot(right, zr.reshape(-1, n2), zi.reshape(-1, n2), f2r, f2i)
+    return tuple(jnp.swapaxes(x.reshape(rows, n1, n2), 1, 2).reshape(rows, n)
+                 for x in (xr, xi))
+
+
+@pytest.mark.parametrize("inverse", [False, True])
+@pytest.mark.parametrize("n", [128, 512, 1024, 8192, 32768])
+def test_three_product_error_vs_four(rng, n, inverse):
+    """Three real products per complex product stay within 1.6x the rms
+    error of four, against a float64 DFT (over at least 2**15 elements,
+    so that the ratio is not one draw's)."""
+    re, im = cplanes(rng, max(4, 32768 // n), n)
+    x = np.asarray(re, np.float64) + 1j * np.asarray(im, np.float64)
+    ref = (np.fft.ifft if inverse else np.fft.fft)(x, axis=-1)
+
+    def rms_err(planes):
+        out = np.asarray(planes[0], np.float64) + 1j * np.asarray(planes[1])
+        return np.linalg.norm(out - ref) / np.linalg.norm(ref)
+
+    three = rms_err(dft_planes(re, im, dft_tables(n, inverse)))
+    four = rms_err(_four_product_dft(re, im, n, inverse))
+    assert three <= 1.6 * four, (three, four)
+    assert three < 1e-5
+
+
+@pytest.mark.parametrize("inverse", [False, True])
+@pytest.mark.parametrize("n", [256, 1024, 32768])
+def test_tables_rebuild_the_dft_matrices(n, inverse):
+    """Each DFT matrix ``C + iS`` is held as ``(C, C + S, S - C)``, each
+    rounded once to f32: ``(C + S) - C`` and ``(S - C) + C`` give ``S``
+    back within f32 rounding; the twiddle stays ``(re, im)``."""
+    tables = [t.astype(np.float64) for t in dft_tables(n, inverse)]
+    mats = _dft_matrices(n, inverse)
+    held = ([tables[:3]] if len(mats) == 1
+            else [tables[:3], tables[3:5], tables[5:]])
+    assert len(held) == len(mats) and sum(map(len, held)) == len(tables)
+    for m, parts in zip(mats, held):
+        tol = 2.0 ** -22 * np.abs(m).max()
+        if len(parts) == 2:
+            np.testing.assert_allclose(parts[0] + 1j * parts[1], m, rtol=0,
+                                       atol=tol)
+            continue
+        c, cps, smc = parts
+        np.testing.assert_allclose(c, m.real, rtol=0, atol=tol)
+        np.testing.assert_allclose(cps - c, m.imag, rtol=0, atol=2 * tol)
+        np.testing.assert_allclose(smc + c, m.imag, rtol=0, atol=2 * tol)
+
+
+@pytest.mark.parametrize("n", [256, 1024, 32768])
+def test_dft_macs_counts_three_products(n):
+    """Real MACs of the DFT products: n complex MACs an element direct,
+    n1 + n2 four-step, each CMUL_PRODUCTS = 3 real products."""
+    n1, n2 = split_length(n)
+    per_element = n if n1 == 1 else n1 + n2
+    assert CMUL_PRODUCTS == 3
+    assert dft_macs(8, n) == 3 * 8 * n * per_element
 
 
 def test_fft_kernel_rejects_bad_rows(rng):
@@ -93,6 +190,27 @@ def test_pick_block_rows_vmem_budget(kind):
             assert b == 128
         else:
             assert vmem_bytes(kind, n, b) <= 16 * 1024 * 1024
+
+
+_PINNED_BLOCKS = {  # n = 128 .. 16384
+    "fft": (256, 256, 128, 16, 16, 16, 8, 8),
+    "rfft": (256, 256, 64, 8, 8, 8, 8, 8),
+    "fused": (128,) * 8,
+    "rfused": (128,) * 8,
+}
+_PINNED_REFUSED = {"fft": (), "rfft": (), "fused": (16384,),
+                   "rfused": (8192, 16384)}
+
+
+@pytest.mark.parametrize("kind", KERNEL_KINDS)
+def test_block_rows_and_refusals_pinned(kind):
+    """Every kernel's block and every length it refuses, pinned: the
+    three-product tables, half as large again, move none of them."""
+    lengths = [1 << k for k in range(7, 15)]
+    assert tuple(pick_block_rows(n, kind) for n in lengths) == \
+        _PINNED_BLOCKS[kind]
+    assert tuple(n for n in lengths if tpu_unsupported(kind, n)) == \
+        _PINNED_REFUSED[kind]
 
 
 @given(n=st.sampled_from([8, 16, 64, 256]), rows=st.integers(1, 6),

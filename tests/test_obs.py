@@ -105,6 +105,36 @@ def test_scope_map_rule():
     assert found["constant.3"] == obs.ROWFFT
 
 
+_KERNELS = """\
+ENTRY %main.9 (param_0: c64[256,4096]) -> c64[256,4096] {
+  %fft_rows_op.1 = (f32[256,4096]{1,0:T(8,128)}, f32[256,4096]{1,0:T(8,128)}) custom-call(%custom-call.1, %custom-call, %constant.0), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[256,4096]{1,0}, f32[256,4096]{1,0}, f32[32,32]{1,0}}
+  %fft_rows_transpose_op.2 = (f32[1024,512]{1,0:T(8,128)}, f32[1024,512]{1,0:T(8,128)}) custom-call(%a, %b, %constant.1), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[512,1024]{1,0}, f32[512,1024]{1,0}, f32[8,8]{1,0}}
+  %rfft_rows_op.3 = (f32[128,256]{1,0:T(8,128)}) custom-call(%c, %d), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[128,256]{1,0}, f32[128,256]{1,0}}
+  %transpose_op.4 = f32[4096,256]{1,0:T(8,128)} custom-call(%e), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[256,4096]{1,0}}
+}
+"""
+
+
+def test_rowfft_mxu_macs_from_kernel_input_planes():
+    """Each row-FFT kernel counts the real MACs of its DFT products on its
+    ``(rows, n)`` input plane (the fused one's output is transposed);
+    other Pallas calls and a text without kernels count none."""
+    from repro.kernels.fft.kernel import dft_macs
+    want = dft_macs(256, 4096) + dft_macs(512, 1024) + dft_macs(128, 256)
+    assert obs.rowfft_counts(_KERNELS) == {obs.ROWFFT_MACS: want}
+    assert want == 3 * (256 * 4096 * (32 + 128) + 512 * 1024 * (8 + 128)
+                        + 128 * 256 * 256)
+    assert obs.rowfft_counts(HLO) == {}
+
+
+def test_interpreted_kernels_count_no_mxu_macs():
+    """On the CPU the kernels are interpreted, not Pallas calls: a plan
+    there has no ``mxu_macs`` counter."""
+    plan = plan_pfft(N, method="lb", p=2,
+                     config=PlanConfig(radix=4, fused=True, batched=True))
+    assert obs.ROWFFT_MACS not in plan.counters()
+
+
 class _FakePlan:
     def __init__(self, found):
         self.found = found

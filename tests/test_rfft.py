@@ -77,6 +77,34 @@ def test_reverse_digits_is_exact_bin_reversal(n, k1_major):
     np.testing.assert_array_equal(np.asarray(rz), np.asarray(z)[:, (-k) % n])
 
 
+@pytest.mark.parametrize("transposed", [False, True])
+@pytest.mark.parametrize("n", [256, 1024])
+def test_packed_spectra_slices_dft_then_reverse_tables(n, transposed):
+    """The real kernels' tables are the three-product DFT tables, then the
+    reverse tables: ``packed_spectra`` cuts them at ``len(dft_tables(n))``
+    and so feeds ``reverse_digits`` its own, whatever the DFT form."""
+    from repro.kernels.fft.kernel import dft_tables
+    from repro.kernels.fft.real import (packed_spectra, real_tables,
+                                        reverse_tables)
+    tables = real_tables(n)
+    k = len(dft_tables(n))
+    assert k == (3 if n <= 512 else 8)
+    for got, want in zip(tables, dft_tables(n) + reverse_tables(n),
+                         strict=True):
+        assert got is want
+    rows = 8
+    rng = np.random.default_rng(n)
+    a, b = (jnp.asarray(rng.standard_normal((rows, n)).astype(np.float32))
+            for _ in range(2))
+    out = packed_spectra(a, b, tuple(map(jnp.asarray, tables)),
+                         transposed=transposed)
+    for (re, im), x in zip((out[:2], out[2:]), (a, b)):
+        ref = np.fft.fft(np.asarray(x, np.float64), axis=-1)
+        got = np.asarray(re) + 1j * np.asarray(im)
+        np.testing.assert_allclose(got.T if transposed else got, ref,
+                                   atol=1e-5 * np.sqrt(n))
+
+
 @pytest.mark.parametrize("n", [1024, 4096])
 def test_packed_rfft_four_step_matches_oracle(n):
     from repro.kernels.fused.real import rfft_rows_transpose_op

@@ -3,7 +3,8 @@
 Each test lowers one public op at a real row length with the blocks
 ``pick_block_rows`` chooses and compiles it with the TPU compiler that
 ships with jax; the program must hold the Pallas kernel
-(``tpu_custom_call``).  Lengths whose smallest legal block outgrows VMEM
+(``tpu_custom_call``).  Among them are the two cells' kernels: fused at
+8192 (at the 100 MiB VMEM cap) and plain at 32768.  Lengths whose smallest legal block outgrows VMEM
 must refuse with ``KernelUnsupported`` instead.  Nothing runs: these are
 compile checks, not chip runs.
 """
@@ -60,8 +61,9 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("n", [256, 4096, 16384])
-@pytest.mark.parametrize("kind", sorted(OPS))
+@pytest.mark.parametrize(
+    "kind,n", [(kind, n) for n in (256, 4096, 16384) for kind in sorted(OPS)]
+    + [("fused", 8192), ("fft", 32768)])     # the two cells' kernels
 def test_kernel_compiles_for_v5e(one_chip, no_persistent_cache, kind, n):
     op, dtype = OPS[kind]
     x = jax.ShapeDtypeStruct((ROWS, n), dtype, sharding=one_chip)
@@ -123,6 +125,41 @@ def test_slab_exchange_counts_for_v5e_2x2(slab_program):
     counts, _ = slab_program
     assert {k: counts[k] for k in (obs.COLLECTIVES, obs.EXCHANGE_BYTES)} \
         == {obs.COLLECTIVES: 32, obs.EXCHANGE_BYTES: 2 * 3 * 8 * n * n // 16}
+
+
+def test_slab_rowfft_mxu_macs_for_v5e_2x2(slab_program):
+    """Each device runs 2 phases x 8 panels of plain kernels over its
+    2**28 elements a phase, n1 + n2 = 256 + 128 complex MACs an element,
+    each three real products: 3/4 of what four products would take."""
+    from repro import obs
+    counts, _ = slab_program
+    complex_macs = 2 * 2 ** 28 * (256 + 128)
+    assert counts[obs.ROWFFT_MACS] == 3 * complex_macs == 618475290624
+    assert counts[obs.ROWFFT_MACS] * 4 == 3 * (4 * complex_macs)
+
+
+def test_one_chip_rowfft_mxu_macs_for_v5e(one_chip, no_persistent_cache,
+                                          monkeypatch):
+    """The one-chip cell's plan at N = 8192 (its committed FPMs, the
+    config the chip picks) compiled for a described v5e: two fused
+    kernels over the 2**26 elements, n1 + n2 = 64 + 128 complex MACs an
+    element, three real products each."""
+    from pathlib import Path
+    import repro.kernels.fft.ops as fft_ops
+    from repro import obs
+    from repro.core import plan_pfft
+    from repro.core.fpm import load_fpms
+    from repro.plan.config import PlanConfig
+    monkeypatch.setattr(fft_ops, "_on_cpu", lambda: False)
+    fpms = Path(__file__).resolve().parents[1] / "bench" / "configs" / \
+        "pfft2-c64-1chip.fpm.npz"
+    n = 8192
+    plan = plan_pfft(n, method="fpm", p=4, fpms=load_fpms(str(fpms)),
+                     config=PlanConfig(radix=4, fused=True, batched=True))
+    x = jax.ShapeDtypeStruct((n, n), jnp.complex64, sharding=one_chip)
+    counts = obs.rowfft_counts(obs.compiled_text(plan._fn, x))
+    assert counts == {obs.ROWFFT_MACS: 2 * 2 ** 26 * (64 + 128) * 3}
+    assert counts[obs.ROWFFT_MACS] == 77309411328
 
 
 _INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+) = (\S+) ([\w-]+)\(")
